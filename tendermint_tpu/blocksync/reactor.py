@@ -290,8 +290,14 @@ class BlocksyncReactor:
                     await self._disconnect_banned()
                     self.pool.blocks_available.clear()
                     continue
-                # ONE device call for the whole window's signatures
-                batch_verify_commits(jobs)
+                # ONE device call for the whole window's signatures — on
+                # a worker thread: the first flush at a new rung pays a
+                # compile (seconds to minutes), and an event loop blocked
+                # that long serves no peer, answers no RPC, and wakes to
+                # find its in-flight block requests past REQUEST_TIMEOUT_S
+                # (the status ticker then bans the honest peers that
+                # could not answer a loop that was not running)
+                await asyncio.to_thread(batch_verify_commits, jobs)
             except ValueError as e:
                 self.logger.info("bad window, refetching", err=str(e))
                 self._redo_per_block(window)

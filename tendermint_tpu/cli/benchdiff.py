@@ -55,16 +55,16 @@ DEFAULT_THRESHOLDS = {
 META_KEYS = {
     "metric", "unit", "backend", "n", "stage", "error", "elapsed_s",
     "baseline_sampling", "production_path", "field_impl", "cmd", "rc",
-    "tail", "note", "warmstart_rung", "async_streams",
+    "tail", "note", "async_streams",
     "async_stream_rounds", "simnet_nodes", "simnet_validator_slots",
     "benchdiff_base", "benchdiff_regressions", "benchdiff_missing",
     "benchdiff_ok", "shootout_rung", "shootout_n", "shootout_runs",
     "gateway_clients", "fleet_nodes",
     "simnet_virtual_nodes", "simnet_virtual_slots",
     "simnet_virtual_heights",
-    # mesh topology is run context, not a measurement: a different
-    # device count between rounds must read as context, not regression
-    "multichip_mesh_sizes", "n_devices",
+    # the device is run context, not a measurement: a different device
+    # kind or count between rounds must read as context, not regression
+    "device_kind", "n_devices",
     # sampling rate is run context: comparing a 19 Hz round against a
     # 97 Hz round must not read the rate change itself as a regression
     "prof_hz",
@@ -76,10 +76,6 @@ META_KEYS = {
 # Ordered (pattern, class, direction) — first match wins.  direction
 # "higher" means a DROP is the regression; "lower" means a RISE is.
 _CLASS_RULES = (
-    # MULTICHIP stage: per-mesh-size dispatcher throughput rides the
-    # generic _sigs_per_sec rule below; the scaling-efficiency summary
-    # (rate_meshN / (rate_mesh1 * N)) is a higher-is-better ratio
-    (re.compile(r"^multichip_scaling_efficiency$"), "ratio", "higher"),
     (re.compile(r"(_sigs_per_sec|_per_sec|_per_s|_per_min|_blocks_per_s"
                 r"|_speedup|heights_per_min)$"), "throughput", "higher"),
     # efficiency ratios where higher is better: the gateway's
@@ -94,8 +90,7 @@ _CLASS_RULES = (
     # wall second — the whole point of the discrete-event scheduler, so
     # a drop is a straight throughput regression
     (re.compile(r"_time_compression$"), "throughput", "higher"),
-    (re.compile(r"(_ok|_within_budget|_warmed|plan_warmed"
-                r"|_deterministic)$"),
+    (re.compile(r"(_ok|_within_budget|_deterministic)$"),
      "boolean", "higher"),
     (re.compile(r"(_p50_ms|_ms)$"), "latency", "lower"),
     (re.compile(r"(_bytes_per_row|_flops_per_row|_bytes_per_hour)$"),
@@ -107,7 +102,7 @@ _CLASS_RULES = (
                 r"|_ns_per_transition|_us_per_transition)$"),
      "latency", "lower"),
     (re.compile(r"(_seconds|_s)$"), "timing", "lower"),
-    (re.compile(r"(cold_compiles|recompiles|_findings|frames_dropped"
+    (re.compile(r"(recompiles|_findings|frames_dropped"
                 r"|padding_rows_total|wal_replays|_violations"
                 r"|_soak_criticals)$"),
      "count", "lower"),

@@ -56,6 +56,9 @@ class E2ENode:
 
     # -- lifecycle -------------------------------------------------------
     def start(self, extra_env: dict | None = None) -> None:
+        # a chip belongs to ONE process: several node processes on one
+        # host cannot share it (the second to touch JAX fails or hangs),
+        # so every child of the runner is pinned to the host verifier
         env = dict(os.environ, JAX_PLATFORMS="cpu", TM_TPU_CRYPTO_BACKEND="cpu")
         env.update(extra_env or {})
         self._log = open(os.path.join(self.home, "node.log"), "a")

@@ -215,6 +215,12 @@ class NodeMetrics:
             "Service flushes dispatched to the device path",
             namespace=ns, subsystem="crypto", fn=_svc("device_batches"),
         ))
+        self.verify_device_errors = reg.register(CallbackCounter(
+            "verify_device_errors_total",
+            "Device flushes that failed at enqueue or readback and were "
+            "resolved with host verdicts instead",
+            namespace=ns, subsystem="crypto", fn=_svc("device_errors"),
+        ))
         self.verify_mesh_pinned = reg.register(CallbackCounter(
             "verify_mesh_pinned_batches_total",
             "Dispatcher flushes routed to the pinned single chip "

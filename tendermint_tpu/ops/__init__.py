@@ -12,7 +12,4 @@ jax.config.update("jax_enable_x64", True)
 
 # Persistent XLA compilation cache: the verifier's scalar-mul loop is a large
 # program; caching its binary makes test sessions and bench reruns cheap.
-try:
-    jaxcache.enable(jax)
-except Exception:  # older jax without the knobs: cache is an optimization only
-    pass
+jaxcache.enable(jax)

@@ -73,9 +73,9 @@ def sharded_verify_fn(mesh: Mesh):
     batch2 = NamedSharding(mesh, P("batch", None))
     # (pub_rows, r_rows, s_rows, k_rows, valid) — packed [N,32] u8 + bool[N].
     # The field impl inside _verify_core resolves per trace via
-    # default_impl() — TM_TPU_FIELD_IMPL=auto (round 9) lands the
-    # golden-validated impl (f32+MXU / packed / int64) here too, and the
-    # devmon label below records which one this mesh program traced.
+    # default_impl() — whatever TM_TPU_FIELD_IMPL=auto resolved to on
+    # this backend lands here too, and the devmon label below records
+    # which one this mesh program traced.
     in_sh = (batch2, batch2, batch2, batch2, batch)
     # donated row buffers, same policy as the single-chip entry points
     # (ops.ed25519_jax.donate_rows — off on XLA-CPU so cache keys and
@@ -98,10 +98,7 @@ def sharded_rlc_fn(mesh: Mesh, impl: str, reduce_lanes: int = 2048):
     ~61 KB, folded on host by ops.ed25519_jax.finalize_rlc).  out_specs
     concatenate the per-device accumulator lanes along axis 0.
     reduce_lanes is baked into the trace, hence part of the cache key."""
-    try:
-        from jax import shard_map
-    except ImportError:  # jax < 0.5 keeps it in the experimental namespace
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     _raw = _dev._core(impl)
 

@@ -136,13 +136,21 @@ def _verify_service_status() -> dict:
     backend = "unstarted"
     if svc is not None:
         backend = "jax" if svc._jax_bv is not None else "host"
+    # what "ready" refers to: XLA-CPU also sets the readiness gate, so
+    # the block names the platform/device kind that became ready (None
+    # until it has) — a node whose TPU did not come up must not read as
+    # healthy
+    diag = _cbatch.threshold_diagnostics()
     return {
         "enabled": _av.service_enabled(),
         "backend": backend,
         "device_ready": _cbatch.device_ready(),
+        "platform": diag.get("platform"),
+        "device_kind": diag.get("device_kind"),
         "queue_depth": enc.i64(st["queue_depth"]),
         "submitted": enc.i64(st["submitted"]),
         "device_batches": enc.i64(st["device_batches"]),
+        "device_errors": enc.i64(st["device_errors"]),
         "cache_hit_ratio": round(st["cache_hits"] / lookups, 4)
         if lookups else 0.0,
     }

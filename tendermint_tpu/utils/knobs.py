@@ -71,7 +71,7 @@ KNOBS: tuple[Knob, ...] = (
     Knob("TM_TPU_FE_MXU", "auto",
          "f32 field-element MXU mode: auto/1/0", "ops"),
     Knob("TM_TPU_FIELD_IMPL", "auto",
-         "field arithmetic implementation: auto/f32/u32", "ops"),
+         "field arithmetic implementation: auto/int64/packed/f32", "ops"),
     Knob("TM_TPU_RUNGS", "",
          "explicit shape-plan rung ladder (comma ints)", "ops"),
     Knob("TM_TPU_SHAPE_PLAN", "",

@@ -218,6 +218,61 @@ def test_device_pipelining_enqueues_chunks(monkeypatch):
         av.reset_service()
 
 
+class _Unreadable:
+    """A pending device value whose readback raises (the device died
+    between enqueue and drain)."""
+
+    def __array__(self, *a, **kw):
+        raise RuntimeError("simulated readback failure")
+
+
+@pytest.mark.parametrize("site", ["enqueue", "readback"])
+def test_device_failure_is_counted_logged_once_and_resolved_on_host(
+        monkeypatch, caplog, site):
+    """A device program that raises at enqueue, and one whose verdict
+    readback raises, each bump `device_errors`, log their traceback once
+    per site, and still resolve every future with host verdicts — the
+    liveness contract kept, the degradation no longer silent.  A
+    readback failure leaves `device_batches` incremented (it counts
+    enqueues), which is why it is never read alone."""
+    import logging
+
+    from tendermint_tpu.ops import ed25519_jax as dev
+
+    ev = threading.Event()
+    ev.set()
+    monkeypatch.setattr(cbatch, "_DEVICE_READY", ev)
+
+    def broken_program(*_key):
+        def run(*_rows):
+            if site == "enqueue":
+                raise RuntimeError("simulated enqueue failure")
+            return _Unreadable()
+        return run
+
+    monkeypatch.setattr(dev, "_compiled", broken_program)
+    s = av.reset_service(linger_ms=1.0, cpu_threshold=8)
+    s._jax_bv._n_devices = 1  # the pipelined route, not the mesh
+    try:
+        with caplog.at_level(logging.WARNING,
+                             logger="tendermint_tpu.crypto.async_verify"):
+            for rnd in range(2):
+                items, want = _triples(12, bad=(2, 7),
+                                       tag=b"deverr-%s-%d" % (site.encode(), rnd))
+                assert s.verify_many(items) == want  # host verdicts
+        st = av.service_stats()
+        assert st["device_errors"] == 2, st
+        assert st["device_batches"] == (0 if site == "enqueue" else 2), st
+        logged = [r for r in caplog.records if "device verify failed" in r.message]
+        assert len(logged) == 1, [r.message for r in logged]  # once per site
+        assert f"at {site}" in logged[0].getMessage()
+        assert "simulated" in str(logged[0].exc_info[1])
+        assert s.last_route == (("host", "device_error") if site == "enqueue"
+                                else ("device", "pipelined"))
+    finally:
+        av.reset_service()
+
+
 def test_service_batch_verifier_adapter(svc):
     bv = av.ServiceBatchVerifier(svc)
     assert bv.verify() == (False, [])  # empty matches CPUBatchVerifier

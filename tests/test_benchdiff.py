@@ -1,8 +1,9 @@
 """`tendermint-tpu benchdiff` (ISSUE 8): artifact-shape normalization
 (driver wrapper vs flat vs results-list, including the parsed:null crash
 shape), direction-aware classification, the threshold/exit-code matrix,
-thresholds-file overrides, and the regression test over the checked-in
-BENCH_r0*.json artifacts — the r04→r05 sigs/s regression must exit 1.
+thresholds-file overrides, and the regression test over a synthetic
+artifact pair shaped like two driver rounds (tests/data/benchdiff/) — a
+-5% sigs/s drop with lost tail stages must exit 1.
 """
 
 import json
@@ -21,10 +22,18 @@ from tendermint_tpu.cli.benchdiff import (
 from tendermint_tpu.cli.main import main as cli_main
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+DATA = os.path.join(REPO, "tests", "data", "benchdiff")
+# synthetic fixtures with the shapes bench.py and its driver wrapper
+# emit: a run that crashed before emitting (parsed: null), a CPU-fallback
+# round, and a device pair whose B side regressed and lost its tail
+CRASHED = os.path.join(DATA, "crashed.json")
+CPU_FALLBACK = os.path.join(DATA, "cpu_fallback.json")
+DEVICE_A = os.path.join(DATA, "device_a.json")
+DEVICE_B = os.path.join(DATA, "device_b_regressed.json")
 
 
 def _artifact(path):
-    with open(os.path.join(REPO, path)) as fh:
+    with open(path) as fh:
         return json.load(fh)
 
 
@@ -60,20 +69,16 @@ def test_normalize_results_list_shape():
     assert meta["shape"] == "results-list"
 
 
-def test_normalize_checked_in_artifacts_all_shapes():
-    # every checked-in round (and the baseline) normalizes without error
-    for name in ("BENCH_BASELINE.json", "BENCH_r01.json", "BENCH_r02.json",
-                 "BENCH_r03.json", "BENCH_r04.json", "BENCH_r05.json",
-                 "BENCH_r06.json"):
-        metrics, _meta = normalize(_artifact(name))
-        assert isinstance(metrics, dict), name
-    # r01 crashed pre-emit; r02+ carry a headline value
-    assert normalize(_artifact("BENCH_r01.json"))[0] == {}
-    assert normalize(_artifact("BENCH_r05.json"))[0]["value"] == 36877.4
-    # r06 (the round-9 representation round) carries the shootout keys
-    r06 = normalize(_artifact("BENCH_r06.json"))[0]
-    assert r06["shootout_packed_hlo_bytes_per_row"] < \
-        r06["shootout_int64_hlo_bytes_per_row"]
+def test_normalize_artifact_files_all_shapes():
+    # every fixture shape (and the checked-in baseline's results list)
+    # normalizes without error
+    for path in (os.path.join(REPO, "BENCH_BASELINE.json"), CRASHED,
+                 CPU_FALLBACK, DEVICE_A, DEVICE_B):
+        metrics, _meta = normalize(_artifact(path))
+        assert isinstance(metrics, dict), path
+    # a pre-emit crash carries nothing; the others a headline value
+    assert normalize(_artifact(CRASHED))[0] == {}
+    assert normalize(_artifact(DEVICE_B))[0]["value"] == 38000.0
 
 
 # ---------------------------------------------------------------------------
@@ -99,9 +104,7 @@ def test_normalize_checked_in_artifacts_all_shapes():
     ("txlife_enabled_us_per_stamp", "latency", "lower"),
     ("tx_latency_accepted_tx_per_s", "throughput", "higher"),
     ("tx_latency_ok", "boolean", "higher"),
-    ("warmstart_cold_s", "timing", "lower"),
     ("lint_seconds", "timing", "lower"),
-    ("warmstart_cold_compiles", "count", "lower"),
     ("jit_recompiles", "count", "lower"),
     ("lint_findings", "count", "lower"),
     ("simnet_ok", "boolean", "higher"),
@@ -117,12 +120,6 @@ def test_normalize_checked_in_artifacts_all_shapes():
     ("shootout_packed_hlo_bytes_per_row", "resource", "lower"),
     ("shootout_int64_flops_per_row", "resource", "lower"),
     ("shootout_packed_wall_p50_ms", "latency", "lower"),
-    # MULTICHIP stage (ISSUE 16): per-mesh-size dispatcher throughput
-    # in the 3% gate; scaling efficiency is a higher-is-better ratio;
-    # mesh topology is run metadata, never a regression
-    ("multichip_mesh1_sigs_per_sec", "throughput", "higher"),
-    ("multichip_mesh8_sigs_per_sec", "throughput", "higher"),
-    ("multichip_scaling_efficiency", "ratio", "higher"),
 ])
 def test_classify_matrix(key, cls, direction):
     assert classify(key) == (cls, direction)
@@ -239,39 +236,35 @@ def test_load_thresholds_rejects_garbage(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# the checked-in r04→r05 regression + CLI exit codes
+# the regression pair + CLI exit codes
 # ---------------------------------------------------------------------------
 
-def test_r04_to_r05_flags_the_sigs_regression(capsys):
-    rc = run_cli(os.path.join(REPO, "BENCH_r04.json"),
-                 os.path.join(REPO, "BENCH_r05.json"), as_json=True)
+def test_regressed_pair_flags_the_sigs_regression(capsys):
+    rc = run_cli(DEVICE_A, DEVICE_B, as_json=True)
     rep = json.loads(capsys.readouterr().out)
     assert rc == 1
-    assert "value" in rep["regressions"]                      # -4.7% sigs/s
+    assert "value" in rep["regressions"]                      # -5% sigs/s
     assert "field_impl_int64_sigs_per_sec" in rep["regressions"]
-    assert "vs_baseline" in rep["regressions"]                # 4.657 → 0
+    assert "vs_baseline" in rep["regressions"]                # 4.5 → 0
     # the lost tail stages are named, not silently dropped
     assert "rlc_sigs_per_sec" in rep["missing_in_b"]
     assert "commit10k_p50_ms" in rep["missing_in_b"]
 
 
-def test_r03_to_r04_is_clean(capsys):
-    rc = run_cli(os.path.join(REPO, "BENCH_r03.json"),
-                 os.path.join(REPO, "BENCH_r04.json"), as_json=True)
+def test_cpu_fallback_to_device_is_clean(capsys):
+    rc = run_cli(CPU_FALLBACK, DEVICE_A, as_json=True)
     rep = json.loads(capsys.readouterr().out)
     assert rc == 0 and rep["ok"] is True
 
 
-def test_r01_crash_shape_diffs_without_error(capsys):
-    rc = run_cli(os.path.join(REPO, "BENCH_r01.json"),
-                 os.path.join(REPO, "BENCH_r02.json"))
+def test_crash_shape_diffs_without_error(capsys):
+    rc = run_cli(CRASHED, CPU_FALLBACK)
     capsys.readouterr()
     assert rc == 0  # nothing shared → nothing regressed
 
 
 def test_cli_subcommand_wiring_and_text_mode(capsys):
-    rc = cli_main(["benchdiff", os.path.join(REPO, "BENCH_r04.json"),
-                   os.path.join(REPO, "BENCH_r05.json")])
+    rc = cli_main(["benchdiff", DEVICE_A, DEVICE_B])
     out = capsys.readouterr().out
     assert rc == 1
     assert "REGRESSION" in out and "value" in out
@@ -281,19 +274,17 @@ def test_cli_subcommand_wiring_and_text_mode(capsys):
 def test_cli_threshold_file_loosens_to_exit_zero(tmp_path, capsys):
     thr = tmp_path / "thr.json"
     thr.write_text(json.dumps({"defaults": {"throughput": 2.0}}))
-    rc = cli_main(["benchdiff", os.path.join(REPO, "BENCH_r04.json"),
-                   os.path.join(REPO, "BENCH_r05.json"),
+    rc = cli_main(["benchdiff", DEVICE_A, DEVICE_B,
                    "--thresholds", str(thr), "--json"])
     rep = json.loads(capsys.readouterr().out)
     assert rc == 0 and rep["regressions"] == []
 
 
 def test_cli_fail_on_missing(capsys):
-    rc = cli_main(["benchdiff", os.path.join(REPO, "BENCH_r03.json"),
-                   os.path.join(REPO, "BENCH_r04.json"),
+    rc = cli_main(["benchdiff", CPU_FALLBACK, DEVICE_A,
                    "--fail-on-missing"])
     capsys.readouterr()
-    assert rc == 1  # xla_cpu_device_sigs_per_sec vanished in r04
+    assert rc == 1  # xla_cpu_device_sigs_per_sec vanished in the B side
 
 
 def test_cli_usage_errors(tmp_path, capsys):
@@ -314,5 +305,3 @@ def test_latest_artifact_picks_highest_round(tmp_path):
         (tmp_path / name).write_text("{}")
     assert latest_artifact(str(tmp_path)).endswith("BENCH_r10.json")
     assert latest_artifact(str(tmp_path / "missing-dir")) is None
-    # the real repo: r06 is the newest checked-in round
-    assert latest_artifact(REPO).endswith("BENCH_r06.json")

@@ -79,14 +79,6 @@ def test_parse_cost_analysis_dict_and_aliases():
     assert parse_cost_analysis({"bytes_accessed": 5})["bytes_accessed"] == 5.0
 
 
-def test_parse_cost_analysis_list_of_dicts_sums_per_computation():
-    # XLA-CPU Compiled.cost_analysis() returns a LIST of dicts
-    out = parse_cost_analysis([{"flops": 10.0}, {"flops": 6.0,
-                                                 "bytes accessed": 4.0}])
-    assert out["flops"] == 16.0
-    assert out["bytes_accessed"] == 4.0
-
-
 def test_parse_cost_analysis_sparse_missing_and_garbage():
     assert parse_cost_analysis({})["flops"] is None
     assert parse_cost_analysis(None)["flops"] is None
@@ -312,7 +304,7 @@ def test_env_gate_resolved_at_construction(monkeypatch):
 def test_warm_entry_harvests_compiled_costs(monkeypatch, tmp_path):
     from tendermint_tpu.ops import shape_plan
 
-    monkeypatch.setenv("TM_BENCH_CACHE", str(tmp_path / "cache"))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
     stub = StubCompiled(cost={"flops": 9.0, "bytes accessed": 18.0}, mem=MEM)
     monkeypatch.setattr(shape_plan, "_aot_compile",
                         lambda kind, rung, impl, flags: (stub, 0.01))
@@ -381,3 +373,24 @@ def test_roofline_infinite_and_zero_guards():
     assert "arithmetic_intensity" not in roof
     assert "flops_per_row" not in roof
     assert math.isfinite(roof.get("transfer_bytes", 0))
+
+
+def test_peak_table_is_keyed_by_exact_device_kind(monkeypatch):
+    """The chip's own device_kind string ("TPU v5 lite" — no "v5e" in
+    it) keys the table exactly; any other device is unknown (None),
+    never a substring match and never a default."""
+    from tendermint_tpu.utils import devmon
+
+    monkeypatch.delenv("TM_TPU_PEAK_FLOPS", raising=False)
+
+    def on(kind):
+        monkeypatch.setattr(devmon, "device_memory", lambda: [
+            {"id": 0, "platform": "tpu", "device_kind": kind}])
+        return costmodel.peak_flops_per_s()
+
+    assert on("TPU v5 lite") == 197e12
+    assert on("TPU v5e") is None          # not what the chip calls itself
+    assert on("TPU v5 lite pod") is None  # exact, not substring
+    assert on("cpu") is None
+    monkeypatch.setattr(devmon, "device_memory", lambda: [])
+    assert costmodel.peak_flops_per_s() is None  # no backend yet

@@ -9,6 +9,7 @@ series and the /debug/pprof/device dump).
 import asyncio
 import json
 import logging
+import os
 import urllib.request
 
 import pytest
@@ -218,31 +219,91 @@ def test_json_log_format(monkeypatch, capture_logger):
     assert cap.lines[-1] == "hello module=consensus height=3"
 
 
-def test_jaxcache_enable_logs_dir_and_preexistence(
+class _FakeJax:
+    """Stand-in for the jax module: a config that records update()s and
+    carries what JAX itself would have read from the environment."""
+
+    def __init__(self, cache_dir=None):
+        outer = self
+        self.updates = []
+
+        class _Config:
+            jax_compilation_cache_dir = cache_dir
+
+            def update(self, k, v):
+                outer.updates.append((k, v))
+                setattr(self, k, v)
+
+        self.config = _Config()
+
+
+def test_jaxcache_honours_standard_env_and_sets_nothing_else(
         monkeypatch, tmp_path, capture_logger):
+    """JAX_COMPILATION_CACHE_DIR places the cache (and the plan/AOT
+    artifacts riding on it); with it set before start-up jax.config
+    already carries the directory and enable() updates nothing."""
     from tendermint_tpu.utils import jaxcache
 
     cap = capture_logger("tendermint_tpu.utils.jaxcache")
-    updates = []
-
-    class FakeConfig:
-        def update(self, k, v):
-            updates.append((k, v))
-
-    class FakeJax:
-        config = FakeConfig()
-
     cache = tmp_path / "jcache"
-    monkeypatch.setenv("TM_BENCH_CACHE", str(cache))
-    jaxcache.enable(FakeJax())
-    assert ("jax_compilation_cache_dir", str(cache)) in updates
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(cache))
+    assert jaxcache.cache_dir() == str(cache)
+    assert jaxcache.plan_path() == str(cache / "shape_plan.json")
+    assert jaxcache.aot_dir() == str(cache / "aot")
+
+    fake = _FakeJax(cache_dir=str(cache))  # as jax reads it at import
+    info = jaxcache.enable(fake)
+    assert fake.updates == []
+    assert info == {"dir": str(cache), "pre_existed": False, "entries": 0,
+                    "from_env": True}
     assert "pre_existed=False" in cap.lines[-1]
 
     cache.mkdir()
     (cache / "prog_abc").write_bytes(b"x")
-    jaxcache.enable(FakeJax())
-    assert "pre_existed=True" in cap.lines[-1]
+    info = jaxcache.enable(_FakeJax(cache_dir=str(cache)))
+    assert info["pre_existed"] is True and info["entries"] == 1
     assert "entries=1" in cap.lines[-1]
+
+    # the two private names this repo used to read are gone
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    monkeypatch.setenv("TM_BENCH_CACHE", str(tmp_path / "a"))
+    monkeypatch.setenv("TENDERMINT_TPU_JAX_CACHE", str(tmp_path / "b"))
+    assert jaxcache.cache_dir() == os.path.join(jaxcache._REPO_ROOT,
+                                                ".jax_cache")
+
+
+def test_jaxcache_default_is_checkout_dir_without_git(
+        monkeypatch, tmp_path):
+    """A copied tree has no .git: the cache still resolves to
+    <checkout>/.jax_cache from the package's own location — never a
+    home directory or a temporary name."""
+    import shutil
+    import subprocess
+    import sys
+
+    from tendermint_tpu.utils import jaxcache
+
+    pkg = tmp_path / "copy" / "tendermint_tpu" / "utils"
+    pkg.mkdir(parents=True)
+    (pkg.parent / "__init__.py").write_text("")
+    (pkg / "__init__.py").write_text("")
+    shutil.copy(jaxcache.__file__, pkg / "jaxcache.py")
+    assert not (tmp_path / "copy" / ".git").exists()
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "from tendermint_tpu.utils import jaxcache; "
+         "print(jaxcache.cache_dir())"],
+        cwd=tmp_path / "copy", env=env, capture_output=True, text=True,
+        timeout=60, check=True)
+    assert out.stdout.strip() == str(tmp_path / "copy" / ".jax_cache")
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    fake = _FakeJax()
+    jaxcache.enable(fake)
+    assert fake.updates == [("jax_compilation_cache_dir",
+                             os.path.join(jaxcache._REPO_ROOT, ".jax_cache"))]
 
 
 def test_top_roofline_fold_and_render():
