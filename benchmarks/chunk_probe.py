@@ -9,9 +9,8 @@ policies on the real device:
   chunk2k  TM_TPU_CHUNK=2048   deeper pipeline (5x2048)
 
 For each: end-to-end wall time (host prep + transfer + device + verdict
-readback — what a tunneled deployment sees) and device-only time (rows
-pre-placed, only compiled programs + verdict-bit readback — what a
-locally-attached deployment sees).  Chunk programs are enqueued before
+readback — what a submitter sees) and device-only time (rows
+pre-placed, only compiled programs + verdict-bit readback).  Chunk programs are enqueued before
 any verdict is read, so chunked device-only also measures whether the
 runtime overlaps queued executions.
 

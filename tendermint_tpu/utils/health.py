@@ -311,7 +311,7 @@ class CompileStormDetector(Detector):
     post-warm zero-cold invariant as a live alarm.  A node legitimately
     cold-compiles while warming (grace_s); after that, ANY new cold
     compile inside the sliding window is a warn, `crit_growth`+ is a
-    storm (the ~100s-per-program relay term eating the node)."""
+    storm (cold compiles, seconds to minutes each, eating the node)."""
 
     name = "compile_storm"
 

@@ -26,7 +26,7 @@ idiom as the journal) and drives four concrete actions:
   rewarm   `compile_storm` critical -> rate-limited
            `shape_plan.start_background_warm(reason="remediation",
            force=True)` — re-warm the saved plan live instead of paying
-           the ~100 s/program relay inline, at most once per
+           each program's cold compile inline, at most once per
            `rewarm_min_s`.
   retune   with TM_TPU_REMEDIATE_RETUNE=1, a rewarm first folds devmon
            occupancy histograms into `consolidated_plan(device_stats)`

@@ -169,7 +169,7 @@ def test_mesh_0_disables_dispatcher(monkeypatch):
 def test_dispatcher_2_device_smoke(monkeypatch):
     """Tier-1 multichip smoke (ISSUE 16 satellite): a 2-device mesh on
     the simulated slice, floor sharding rung only — the 2-device rung-64
-    program is persistent-cache warm, so no relay compile in budget."""
+    program is persistent-cache warm, so no cold compile in budget."""
     s = _svc(monkeypatch)
     monkeypatch.setenv("TM_TPU_MESH", "2")
     items, want = _triples(64, bad=(1, 62), tag=b"mesh-two")
