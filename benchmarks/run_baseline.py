@@ -178,13 +178,13 @@ def main() -> None:
     ap.add_argument("--skip-localnet", action="store_true")
     args = ap.parse_args()
 
-    import jax
-
+    # this process never touches JAX: the chip belongs to one process at
+    # a time, and it is the baseline_suite.py children that need it (one
+    # after the other).  What they ran on is in their own
+    # `dispatch_threshold` record (platform, device_kind).
     doc = {
         "generated_unix": int(time.time()),
         "backend_requested": args.backend,
-        "jax_default_backend": jax.default_backend()
-        if args.backend != "cpu" else "cpu (forced)",
         "config4_blocks": args.blocks,
         "results": [],
     }

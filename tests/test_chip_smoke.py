@@ -47,8 +47,16 @@ def test_dry_run_cpu_passes_every_stage_and_says_what_it_is():
     the suite compiles); about a minute more on a cold one."""
     out = _run(["--dry-run-cpu"], timeout=900.0)
     assert out.returncode == 0, (out.stdout[-2000:], out.stderr[-2000:])
-    last = out.stdout.strip().splitlines()[-1]
-    doc = json.loads(last)
+    lines = out.stdout.strip().splitlines()
+    # the result line: last on stdout, these keys and no others
+    result = json.loads(lines[-1])
+    assert set(result) == {"ok", "device"} and result["ok"] is True
+    assert set(result["device"]) == {"platform", "kind", "count"}
+    assert _result_lines(out.stdout) == [lines[-1]]
+    # the full summary: the line before it
+    assert lines[-2].startswith("summary: ")
+    doc = json.loads(lines[-2][len("summary: "):])
+    assert doc["device"] == result["device"]
     assert doc["ok"] is True and doc["checks_failed"] == []
     assert doc["dry_run"] is True
     assert doc["device"]["platform"] == "cpu"
