@@ -56,10 +56,12 @@ Env knobs:
                         synchronous multi-device routing.
   TM_TPU_MESH_MIN_SHARD flush size at/above which a flush shards
                         (default 64 rows per device).
-  TM_TPU_TRACE          1 additionally records submit/coalesce/flush/
-                        host-prep/device-execute spans into the
-                        utils.trace ring (docs/observability.md); the
-                        latency histograms below are always on.
+  TM_TPU_TRACE          1 additionally records submit/wait (caller) and
+                        coalesce/account/flush/host-prep/device-execute/
+                        resolve (worker) spans into the utils.trace ring,
+                        the worker's tied by a `flush` number
+                        (docs/observability.md); the latency histograms
+                        below are always on.
 """
 
 from __future__ import annotations
@@ -238,6 +240,11 @@ class VerifyService:
         # sites ("enqueue", "enqueue_sharded", "readback", "sync") whose
         # first device error was already logged with its traceback
         self._logged_error_sites: set[str] = set()
+        # sequence number of the flush the worker is working on: assigned
+        # in _collect, kept with the batch in `inflight`, and rides every
+        # worker span as `flush` so that one flush's spans can be paired
+        # by name and number instead of by order.  Worker-thread-owned.
+        self._flush_no = 0  # tmsan: shared=written by the worker thread only (ctor aside); read by its own span sites
         # last (path, reason) the router chose — tests assert the
         # routing DECISION (pinned vs sharded), not just the verdicts
         self.last_route: tuple[str, str] | None = None  # tmsan: shared=atomic tuple rebind, last-write-wins diagnostic
@@ -319,7 +326,11 @@ class VerifyService:
         never on device warmup (the worker routes around a cold or
         wedged device)."""
         futs = self.submit_many(items)
-        return [bool(f.result()) for f in futs]
+        # the caller wakes at the first resolved future and then collects
+        # the rest while the worker is still resolving: what of this span
+        # lies past the flush's `verify.resolve` is the caller alone
+        with _trace.span("verify.wait", n=len(futs)):
+            return [bool(f.result()) for f in futs]
 
     def close(self) -> None:
         with self._cv:
@@ -379,13 +390,21 @@ class VerifyService:
             self.stats["flushes"] += 1
             self.stats["coalesced_max"] = max(self.stats["coalesced_max"],
                                               len(batch))
+            flush = self._flush_no = self.stats["flushes"]  # tmsan: shared=written by the worker thread only
         now = time.perf_counter()
-        VERIFY_LINGER_SECONDS.observe(now - t_linger0)
-        for r in batch:
-            VERIFY_QUEUE_WAIT_SECONDS.observe(now - r.t_submit)
         if _trace.enabled():
+            # oldest_submit_ns == the t0_ns of the `verify.submit` span
+            # that queued the batch's first request (same float, same
+            # rounding): the tie between a caller's spans and a flush
             _trace.record("verify.coalesce", t_linger0, now - t_linger0,
-                          n=len(batch))
+                          n=len(batch), flush=flush,
+                          oldest_submit_ns=int(batch[0].t_submit * 1e9))
+        # per-request accounting on the worker, the batch already taken
+        # and not yet routed: the device idles under this span
+        with _trace.span("verify.account", n=len(batch), flush=flush):
+            VERIFY_LINGER_SECONDS.observe(now - t_linger0)
+            for r in batch:
+                VERIFY_QUEUE_WAIT_SECONDS.observe(now - r.t_submit)
         return batch
 
     def _run(self) -> None:
@@ -421,7 +440,8 @@ class VerifyService:
         self.last_route = (path, reason)  # tmsan: shared=atomic tuple rebind, last-write-wins diagnostic
         if _trace.enabled():
             _trace.record("verify.flush", t0, time.perf_counter() - t0,
-                          path=path, reason=reason, n=len(reqs))
+                          path=path, reason=reason, n=len(reqs),
+                          flush=self._flush_no)
 
     def _route(self, reqs: list[_Request], inflight: deque) -> tuple[str, str]:
         n = len(reqs)
@@ -499,6 +519,7 @@ class VerifyService:
         from tendermint_tpu.ops import ed25519_jax as dev
 
         n = len(reqs)
+        flush = self._flush_no
         impl = dev.default_impl()
         base_mxu = dev._resolve_optin(impl)
         chunk = dev._chunk_size()
@@ -515,7 +536,7 @@ class VerifyService:
             VERIFY_HOST_PREP_SECONDS.observe(prep_dt)
             if _trace.enabled():
                 _trace.record("verify.host_prep", t_prep, prep_dt,
-                              n=end - start, rung=b)
+                              n=end - start, rung=b, flush=flush)
             if _devmon.STATS.enabled:
                 _mesh.record_pinned_flush(
                     end - start, b, nbytes=sum(a.nbytes for a in padded))
@@ -523,7 +544,7 @@ class VerifyService:
                 self._drain_one(inflight)
             t_enq = time.perf_counter()
             pending = dev._compiled(b, impl, base_mxu)(*padded)
-            inflight.append((pending, sub, t_enq, b))
+            inflight.append((pending, sub, t_enq, b, flush))
             with self._cv:
                 self.stats["device_batches"] += 1
 
@@ -539,6 +560,7 @@ class VerifyService:
 
         mesh = _mesh.mesh_for(m)
         n = len(reqs)
+        flush = self._flush_no
         b = _sh.sharded_bucket(n, m)
         t_prep = time.perf_counter()
         rows = dev.prepare_batch([r.pub for r in reqs],
@@ -549,7 +571,7 @@ class VerifyService:
         VERIFY_HOST_PREP_SECONDS.observe(prep_dt)
         if _trace.enabled():
             _trace.record("verify.host_prep", t_prep, prep_dt,
-                          n=n, rung=b)
+                          n=n, rung=b, flush=flush)
         if _devmon.STATS.enabled:
             _mesh.record_sharded_flush(
                 n, b, mesh, nbytes=sum(a.nbytes for a in padded))
@@ -560,7 +582,7 @@ class VerifyService:
         self.last_shard_layout = tuple(  # tmsan: shared=atomic tuple rebind, last-write-wins diagnostic
             (int(s.device.id), int(s.data.shape[0]))
             for s in pending.addressable_shards)
-        inflight.append((pending, reqs, t_enq, b))
+        inflight.append((pending, reqs, t_enq, b, flush))
         with self._cv:
             self.stats["device_batches"] += 1
             self.stats["mesh_sharded_batches"] += 1
@@ -568,7 +590,10 @@ class VerifyService:
     def _drain_one(self, inflight: deque) -> None:
         import numpy as np
 
-        pending, reqs, t_enq, rung = inflight.popleft()
+        pending, reqs, t_enq, rung, flush = inflight.popleft()
+        # the worker turns to an older flush, possibly in the middle of
+        # enqueueing a newer one: spans below carry the drained number
+        routing, self._flush_no = self._flush_no, flush  # tmsan: shared=written by the worker thread only
         with self._cv:
             self.stats["pipelined_drains"] += 1
         try:
@@ -576,15 +601,17 @@ class VerifyService:
         except Exception:  # noqa: BLE001 — readback failed: host verdicts
             self._device_error("readback", len(reqs))
             self._host_verify(reqs, count_flush=False)
-            return
-        dt = time.perf_counter() - t_enq
-        VERIFY_DEVICE_EXECUTE_SECONDS.observe(dt, rung=rung)
-        if _trace.enabled():
-            # enqueue-to-readback: includes time queued behind the other
-            # in-flight batch, i.e. what a submitter actually experiences
-            _trace.record("verify.device_execute", t_enq, dt,
-                          n=len(reqs), rung=rung)
-        self._resolve(reqs, oks, path="device")
+        else:
+            dt = time.perf_counter() - t_enq
+            VERIFY_DEVICE_EXECUTE_SECONDS.observe(dt, rung=rung)
+            if _trace.enabled():
+                # enqueue-to-readback: includes time queued behind the
+                # other in-flight batch, i.e. what a submitter actually
+                # experiences
+                _trace.record("verify.device_execute", t_enq, dt,
+                              n=len(reqs), rung=rung, flush=flush)
+            self._resolve(reqs, oks, path="device")
+        self._flush_no = routing  # tmsan: shared=written by the worker thread only
 
     def _sync_device_verify(self, reqs: list[_Request], bv) -> None:
         t0 = time.perf_counter()
@@ -602,7 +629,7 @@ class VerifyService:
         VERIFY_DEVICE_EXECUTE_SECONDS.observe(dt, rung="sync")
         if _trace.enabled():
             _trace.record("verify.device_execute", t0, dt,
-                          n=len(reqs), rung="sync")
+                          n=len(reqs), rung="sync", flush=self._flush_no)
         self._resolve(reqs, oks, path="device")
 
     def _host_verify(self, reqs: list[_Request], count_flush: bool = True) -> None:
@@ -620,17 +647,20 @@ class VerifyService:
             return
         if _trace.enabled():
             _trace.record("verify.host_verify", t0,
-                          time.perf_counter() - t0, n=len(reqs))
+                          time.perf_counter() - t0, n=len(reqs),
+                          flush=self._flush_no)
         self._resolve(reqs, oks, path="host")
 
     def _resolve(self, reqs: list[_Request], oks, path: str = "host") -> None:
         now = time.perf_counter()
-        for req, ok in zip(reqs, oks):
-            ok = bool(ok)
-            if ok:
-                self.cache.put(req.key)
-            VERIFY_E2E_SECONDS.observe(now - req.t_submit, path=path)
-            req.future.set_result(ok)
+        with _trace.span("verify.resolve", n=len(reqs), path=path,
+                         flush=self._flush_no):
+            for req, ok in zip(reqs, oks):
+                ok = bool(ok)
+                if ok:
+                    self.cache.put(req.key)
+                VERIFY_E2E_SECONDS.observe(now - req.t_submit, path=path)
+                req.future.set_result(ok)
 
     def _resolve_failed(self, reqs: list[_Request], err: BaseException) -> None:
         """Catastrophic path: even the batched host verify raised.  Fall

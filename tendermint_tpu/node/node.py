@@ -434,9 +434,7 @@ class Node:
         from tendermint_tpu.utils import profiler as _profiler
 
         self.prof = _profiler.from_env(
-            node=config.base.moniker or self.node_key.node_id[:8],
-            root=config.home,
-        )
+            node=config.base.moniker or self.node_key.node_id[:8])
         if self.health.enabled and self.prof.enabled:
             self.health.prof = self.prof
 

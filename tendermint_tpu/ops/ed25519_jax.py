@@ -539,20 +539,31 @@ class _Core:
         a trace-time constant, so compiled-program caches must key on it
         (_compiled does)."""
         fe = self.fe
-        pub_bits = self._bits_of(pub_rows)
-        r_bits = self._bits_of(r_rows)
-        y_a, sign_a = self._limbs_of(pub_bits[..., :255]), pub_bits[..., 255]
-        y_r, sign_r = self._limbs_of(r_bits[..., :255]), r_bits[..., 255]
-        s_digits = self._nibbles_of(s_rows)
-        k_digits = self._nibbles_of(k_rows)
-        a_pt, ok_a = self.decompress(y_a, sign_a)
-        r_pt, ok_r = self.decompress(y_r, sign_r)
-        sb = (self._scalarmul_base_mxu(s_rows) if base_mxu
-              else self._scalarmul_base(s_digits))
-        w = fe.pt_add(sb, self._scalarmul_var(k_digits, fe.pt_neg(a_pt)))
-        q = fe.pt_add(w, fe.pt_neg(r_pt))
-        q8 = fe.pt_dbl_n(q, 3)
-        return valid & ok_a & ok_r & fe.pt_is_identity(q8)
+        # one named scope per phase: metadata only — the scope
+        # lands in each HLO op's op_name, which a profiler trace keeps
+        # per device op, and JAX's persistent-cache key strips it, so
+        # the lowered computation and every cached program are unchanged
+        with jax.named_scope("ed25519.unpack"):
+            pub_bits = self._bits_of(pub_rows)
+            r_bits = self._bits_of(r_rows)
+            y_a, sign_a = self._limbs_of(pub_bits[..., :255]), pub_bits[..., 255]
+            y_r, sign_r = self._limbs_of(r_bits[..., :255]), r_bits[..., 255]
+            s_digits = self._nibbles_of(s_rows)
+            k_digits = self._nibbles_of(k_rows)
+        with jax.named_scope("ed25519.decompress_a"):
+            a_pt, ok_a = self.decompress(y_a, sign_a)
+        with jax.named_scope("ed25519.decompress_r"):
+            r_pt, ok_r = self.decompress(y_r, sign_r)
+        with jax.named_scope("ed25519.scalarmul_base"):
+            sb = (self._scalarmul_base_mxu(s_rows) if base_mxu
+                  else self._scalarmul_base(s_digits))
+        with jax.named_scope("ed25519.scalarmul_var"):
+            ka = self._scalarmul_var(k_digits, fe.pt_neg(a_pt))
+        with jax.named_scope("ed25519.finish"):
+            w = fe.pt_add(sb, ka)
+            q = fe.pt_add(w, fe.pt_neg(r_pt))
+            q8 = fe.pt_dbl_n(q, 3)
+            return valid & ok_a & ok_r & fe.pt_is_identity(q8)
 
 
 @functools.cache

@@ -262,7 +262,7 @@ class SimNode:
         # sampling a wall cadence against a virtual timeline.  Window
         # boundaries ride the node clock so wall-mode folds line up
         # with the journal.
-        self.prof = tmprof.from_env(node=self.name, root=home,
+        self.prof = tmprof.from_env(node=self.name,
                                     clock=self.clock.monotonic)
         if self.health.enabled and self.prof.enabled:
             self.health.prof = self.prof

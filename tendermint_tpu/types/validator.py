@@ -23,6 +23,7 @@ from fractions import Fraction
 
 from tendermint_tpu.crypto import merkle
 from tendermint_tpu.crypto.keys import PubKey
+from tendermint_tpu.utils import trace as _trace
 from tendermint_tpu.wire.proto import (
     ProtoWriter,
     encode_uvarint,
@@ -383,35 +384,42 @@ class ValidatorSet:
         entries = []
         seen: dict[int, int] = {}
         running = 0
-        for idx, cs in enumerate(commit.signatures):
-            if not cs.for_block():
-                continue
-            val_idx, val = self.get_by_address(cs.validator_address)
-            if val is None:
-                continue
-            if val_idx in seen:
-                raise ValueError(
-                    f"double vote from validator {val_idx} ({seen[val_idx]} and {idx})"
-                )
-            seen[val_idx] = idx
-            entries.append((idx, val, val.voting_power))
-            running += val.voting_power
-            if running > needed:
-                break
+        # the same commit.* spans as batch_verify_commits, one per phase
+        with _trace.span("commit.select", mode="trusting") as sp:
+            for idx, cs in enumerate(commit.signatures):
+                if not cs.for_block():
+                    continue
+                val_idx, val = self.get_by_address(cs.validator_address)
+                if val is None:
+                    continue
+                if val_idx in seen:
+                    raise ValueError(
+                        f"double vote from validator {val_idx} ({seen[val_idx]} and {idx})"
+                    )
+                seen[val_idx] = idx
+                entries.append((idx, val, val.voting_power))
+                running += val.voting_power
+                if running > needed:
+                    break
+            sp.set(n_sigs=len(commit.signatures), selected=len(entries))
         # assemble all selected sign-bytes in one (native) call, same as
         # batch_verify_commits
-        msgs = commit.vote_sign_bytes_batch(chain_id, [e[0] for e in entries])
-        for (idx, val, _power), msg in zip(entries, msgs):
-            bv.add(val.pub_key, msg, commit.signatures[idx].signature)
-        _, oks = bv.verify()
-        tallied = 0
-        for ok, (idx, _val, power) in zip(oks, entries):
-            if not ok:
-                raise ValueError(f"wrong signature (#{idx})")
-            tallied += power
-            if tallied > needed:
-                return
-        raise ValueError(f"insufficient voting power: got {tallied}, needed >{needed}")
+        with _trace.span("commit.sign_bytes", n=len(entries)):
+            msgs = commit.vote_sign_bytes_batch(chain_id, [e[0] for e in entries])
+        with _trace.span("commit.add", n=len(entries)):
+            for (idx, val, _power), msg in zip(entries, msgs):
+                bv.add(val.pub_key, msg, commit.signatures[idx].signature)
+        with _trace.span("commit.verify", n=len(entries)):
+            _, oks = bv.verify()
+        with _trace.span("commit.tally", n=len(entries)):
+            tallied = 0
+            for ok, (idx, _val, power) in zip(oks, entries):
+                if not ok:
+                    raise ValueError(f"wrong signature (#{idx})")
+                tallied += power
+                if tallied > needed:
+                    return
+            raise ValueError(f"insufficient voting power: got {tallied}, needed >{needed}")
 
     def _check_commit_basics(self, chain_id: str, block_id: BlockID, height: int, commit) -> None:
         if commit is None:
@@ -516,48 +524,58 @@ def batch_verify_commits(jobs: list[CommitVerifyJob]) -> None:
     bv = new_service_batch_verifier()
     plans = []  # (job, entries=[(sig_batch_idx, val_idx, power)], needed)
     n = 0
+    # spans (utils/trace): one per phase per job, never inside a per-row
+    # loop — where a call's host time goes around the service's own
+    # verify.* spans (docs/observability.md)
     for job in jobs:
         vs, commit = job.val_set, job.commit
-        vs._check_commit_basics(job.chain_id, job.block_id, job.height, commit)
-        needed = vs.total_voting_power() * 2 // 3
-        # select indices first, then assemble all sign-bytes in one
-        # native call (the per-row Python path is ~4 µs — 40 ms on a 10k
-        # commit, 20x the BASELINE end-to-end budget)
-        sel = []
-        running = 0
-        for idx, cs in enumerate(commit.signatures):
-            if job.mode == "light":
-                if not cs.for_block():
+        with _trace.span("commit.select", mode=job.mode) as sp:
+            vs._check_commit_basics(job.chain_id, job.block_id, job.height, commit)
+            needed = vs.total_voting_power() * 2 // 3
+            # select indices first, then assemble all sign-bytes in one
+            # native call (the per-row Python path is ~4 µs — 40 ms on a
+            # 10k commit, 20x the BASELINE end-to-end budget)
+            sel = []
+            running = 0
+            for idx, cs in enumerate(commit.signatures):
+                if job.mode == "light":
+                    if not cs.for_block():
+                        continue
+                elif cs.absent():
                     continue
-            elif cs.absent():
-                continue
-            sel.append(idx)
-            if job.mode == "light":
-                running += vs.validators[idx].voting_power
-                if running > needed:
-                    break
-        msgs = commit.vote_sign_bytes_batch(job.chain_id, sel)
+                sel.append(idx)
+                if job.mode == "light":
+                    running += vs.validators[idx].voting_power
+                    if running > needed:
+                        break
+            sp.set(n_sigs=len(commit.signatures), selected=len(sel))
+        with _trace.span("commit.sign_bytes", n=len(sel)):
+            msgs = commit.vote_sign_bytes_batch(job.chain_id, sel)
         entries = []
-        for idx, msg in zip(sel, msgs):
-            val = vs.validators[idx]
-            bv.add(val.pub_key, msg, commit.signatures[idx].signature)
-            entries.append((n, idx, val.voting_power))
-            n += 1
+        with _trace.span("commit.add", n=len(sel)):
+            for idx, msg in zip(sel, msgs):
+                val = vs.validators[idx]
+                bv.add(val.pub_key, msg, commit.signatures[idx].signature)
+                entries.append((n, idx, val.voting_power))
+                n += 1
         plans.append((job, entries, needed))
-    _, oks = bv.verify() if n else (True, [])
+    with _trace.span("commit.verify", n=n):
+        _, oks = bv.verify() if n else (True, [])
     for job, entries, needed in plans:
-        tallied = 0
-        for sig_i, idx, power in entries:
-            if not oks[sig_i]:
+        with _trace.span("commit.tally", n=len(entries)):
+            tallied = 0
+            for sig_i, idx, power in entries:
+                if not oks[sig_i]:
+                    raise ValueError(
+                        f"wrong signature (#{idx}) in commit for height {job.height}"
+                    )
+                # light entries stop at the +2/3 cutoff by construction,
+                # so every collected signature counts; full mode tallies
+                # ForBlock
+                if job.mode == "light" or job.commit.signatures[idx].for_block():
+                    tallied += power
+            if tallied <= needed:
                 raise ValueError(
-                    f"wrong signature (#{idx}) in commit for height {job.height}"
+                    f"insufficient voting power for height {job.height}: "
+                    f"got {tallied}, needed >{needed}"
                 )
-            # light entries stop at the +2/3 cutoff by construction, so
-            # every collected signature counts; full mode tallies ForBlock
-            if job.mode == "light" or job.commit.signatures[idx].for_block():
-                tallied += power
-        if tallied <= needed:
-            raise ValueError(
-                f"insufficient voting power for height {job.height}: "
-                f"got {tallied}, needed >{needed}"
-            )

@@ -160,8 +160,6 @@ KNOBS: tuple[Knob, ...] = (
          "profile aggregation window (s)", "profiler"),
     Knob("TM_TPU_PROF_TRIGGER_MIN_S", "30.0",
          "minimum seconds between trigger-driven captures", "profiler"),
-    Knob("TM_TPU_PROF_DEVICE", "0",
-         "trigger-driven device (XLA) capture", "profiler"),
     # -- metric history -------------------------------------------------
     Knob("TM_TPU_HISTORY", "1",
          "embedded metric time-series recorder", "history"),

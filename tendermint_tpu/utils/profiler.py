@@ -19,9 +19,7 @@ Surfaces:
   ``/debug/pprof/profile?seconds=N`` route; ``export_chrome()`` renders
   a capture as trace-event JSON for Perfetto, the trace.py idiom),
 - rate-limited trigger captures (``trigger()`` — health critical
-  transitions and fleet ``slo_burn`` records arm it; with
-  ``TM_TPU_PROF_DEVICE=1`` on a non-CPU backend it also arms one
-  bounded ``jax.profiler.trace`` device capture),
+  transitions and fleet ``slo_burn`` records arm it),
 - metric feeds (``subsystem_samples()`` / ``overhead_samples()``) and
   function tables (``function_table()`` / ``diff_folded()`` — the
   ``tendermint-tpu prof`` CLI and its ``--diff`` regression gate).
@@ -58,7 +56,6 @@ DEFAULT_HZ = 19.0
 DEFAULT_WINDOW_S = 10.0
 DEFAULT_RING = 12          # ~2 minutes of pre-critical history
 DEFAULT_TRIGGER_MIN_S = 30.0
-DEFAULT_DEVICE_CAPTURE_S = 2.0
 MAX_STACK_DEPTH = 64
 MAX_STACKS_PER_WINDOW = 512
 MAX_CUMULATIVE_STACKS = 4096
@@ -308,17 +305,12 @@ class Profiler:
     def __init__(self, node: str = "", hz: float = DEFAULT_HZ,
                  window_s: float = DEFAULT_WINDOW_S, ring: int = DEFAULT_RING,
                  trigger_min_s: float = DEFAULT_TRIGGER_MIN_S,
-                 device_capture: bool = False, device_dir: str = "",
-                 device_capture_s: float = DEFAULT_DEVICE_CAPTURE_S,
                  max_stacks: int = MAX_STACKS_PER_WINDOW,
                  clock=time.monotonic):
         self.node = node
         self.hz = min(200.0, max(0.1, hz))
         self.window_s = max(0.1, window_s)
         self.trigger_min_s = max(0.0, trigger_min_s)
-        self.device_capture = device_capture
-        self.device_dir = device_dir
-        self.device_capture_s = min(10.0, max(0.1, device_capture_s))
         self.max_stacks = max(16, max_stacks)
         self._clock = clock
         self._lock = threading.Lock()
@@ -331,7 +323,6 @@ class Profiler:
         self.overhead_s = 0.0
         self.triggers = 0
         self.trigger_suppressed = 0
-        self.device_captures = 0
         self._last_trigger: float | None = None
         self._last_trigger_reason = ""
         self._thread: threading.Thread | None = None
@@ -417,10 +408,9 @@ class Profiler:
     def trigger(self, reason: str = "") -> bool:
         """A degradation event wants a profile.  Rate-limited
         (``trigger_min_s`` between accepts — escalation storms must
-        not turn the profiler into the load); on accept, optionally
-        arms one bounded device capture.  The host-side profile itself
-        rides the flight-recorder bundle (``folded_recent``), so
-        accepting is just bookkeeping + the device arm."""
+        not turn the profiler into the load).  The host-side profile
+        itself rides the flight-recorder bundle (``folded_recent``), so
+        accepting is just bookkeeping."""
         now = self._clock()
         with self._lock:
             if (self._last_trigger is not None
@@ -430,38 +420,7 @@ class Profiler:
             self._last_trigger = now
             self.triggers += 1
             self._last_trigger_reason = reason
-        self._maybe_device_capture(reason)
         return True
-
-    def _maybe_device_capture(self, reason: str) -> None:
-        """Arm one bounded ``jax.profiler.trace`` on a non-CPU backend
-        (opt-in, ``TM_TPU_PROF_DEVICE=1``).  Never on CPU — tier-1's
-        path must not import or start the device profiler."""
-        if not self.device_capture or not self.device_dir:
-            return
-        try:
-            import jax
-
-            if jax.default_backend() == "cpu":
-                return
-        except Exception:  # noqa: BLE001 — no jax, no device capture
-            return
-
-        def _run():
-            try:
-                import jax
-
-                os.makedirs(self.device_dir, exist_ok=True)
-                with jax.profiler.trace(self.device_dir):
-                    time.sleep(self.device_capture_s)
-                self.device_captures += 1  # tmsan: shared=diagnostic counter; captures serialized by the trigger min-interval
-                _log.info("device capture (%s) -> %s", reason,
-                          self.device_dir)
-            except Exception as e:  # noqa: BLE001 — forensics never fatal
-                _log.warning("device capture failed: %r", e)
-
-        threading.Thread(target=_run, daemon=True,
-                         name=f"prof-device-{self.node or 'node'}").start()
 
     # -- lifecycle ------------------------------------------------------
 
@@ -536,7 +495,6 @@ class Profiler:
                 "windows": len(self._ring) + 1,
                 "triggers": self.triggers,
                 "trigger_suppressed": self.trigger_suppressed,
-                "device_captures": self.device_captures,
             }
 
     def report(self) -> dict:
@@ -610,13 +568,10 @@ class _NopProfiler:
 NOP = _NopProfiler()
 
 
-def from_env(node: str = "", root: str = "",
-             clock=None) -> "Profiler | _NopProfiler":
+def from_env(node: str = "", clock=None) -> "Profiler | _NopProfiler":
     """Build a sampler per TM_TPU_PROF (default ON), or return the NOP
-    singleton when disabled.  ``root`` hosts device captures
-    (``<root>/prof/``); no root = no device capture directory.
-    ``clock`` overrides the monotonic clock (simnet wall-time scenarios
-    pass theirs; default wall)."""
+    singleton when disabled.  ``clock`` overrides the monotonic clock
+    (simnet wall-time scenarios pass theirs; default wall)."""
     raw = os.environ.get(ENV_FLAG, "1").lower()
     if raw in ("0", "false", "off"):
         return NOP
@@ -634,14 +589,10 @@ def from_env(node: str = "", root: str = "",
                                         DEFAULT_WINDOW_S))
     except ValueError:
         window_s = DEFAULT_WINDOW_S
-    device = os.environ.get("TM_TPU_PROF_DEVICE", "0").lower() in (
-        "1", "true", "on")
     return Profiler(
         node=node,
         hz=hz,
         window_s=window_s,
         trigger_min_s=trigger_min_s,
-        device_capture=device,
-        device_dir=os.path.join(root, "prof") if root else "",
         clock=clock if clock is not None else time.monotonic,
     )

@@ -31,6 +31,14 @@ All timestamps come from time.perf_counter_ns() — perf_counter() floats
 handed to record() share the same clock origin, so externally measured
 durations (cross-thread device drains, blocksync round trips) land on
 the same timeline as context-manager spans.
+
+Parent links: a `span()` is parented under the calling thread's
+innermost open span, and so is a `record()` — the span open on the
+thread that CALLS record(), whatever thread the measured work ran on
+(`verify.submit` hangs under the caller's `commit.verify`; the worker
+thread holds no open span, so its records stay roots).  Spans of one
+flush on different threads are tied by attrs instead (`flush`,
+`oldest_submit_ns`; docs/observability.md).
 """
 
 from __future__ import annotations
@@ -146,6 +154,10 @@ class _SpanCtx:
         self.name = name
         self.attrs = attrs
 
+    def set(self, **attrs) -> None:
+        """Attrs only known once the block ran (a count it produced)."""
+        self.attrs.update(attrs)
+
     def __enter__(self) -> "_SpanCtx":
         stack = _stack()
         self.parent_id = stack[-1] if stack else None
@@ -166,6 +178,9 @@ class _SpanCtx:
 
 class _NopSpan:
     __slots__ = ()
+
+    def set(self, **attrs) -> None:
+        pass
 
     def __enter__(self) -> "_NopSpan":
         return self
@@ -190,12 +205,13 @@ def record(name: str, t0: float, dur: float, **attrs) -> None:
     """A complete span with externally measured timing — t0/dur in
     seconds on the time.perf_counter() clock.  For work whose start and
     end live on different threads (device enqueue → verdict drain) or
-    whose duration was measured on another monotonic clock."""
+    whose duration was measured on another monotonic clock.  Parented
+    under the calling thread's innermost open span, like `span()`."""
     en = _enabled
     if not (en if en is not None else _resolve_enabled()):
         return
-    _append(name, next(_ids), None, int(t0 * 1e9), max(0, int(dur * 1e9)),
-            attrs)
+    _append(name, next(_ids), current_span_id(), int(t0 * 1e9),
+            max(0, int(dur * 1e9)), attrs)
 
 
 def instant(name: str, **attrs) -> None:

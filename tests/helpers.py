@@ -14,7 +14,7 @@ from tendermint_tpu.crypto.keys import priv_key_from_seed
 from tendermint_tpu.state import BlockExecutor, StateStore, make_genesis_state
 from tendermint_tpu.store import BlockStore, MemDB
 from tendermint_tpu.types import GenesisDoc, GenesisValidator
-from tendermint_tpu.types.basic import BlockID
+from tendermint_tpu.types.basic import BlockID, PartSetHeader
 from tendermint_tpu.types.commit import BlockIDFlag, Commit, CommitSig
 from tendermint_tpu.types.vote import SignedMsgType, vote_sign_bytes_raw
 
@@ -106,3 +106,20 @@ class ChainBuilder:
             txs = tx_fn(h) if tx_fn else [b"k%d=v%d" % (h, h)]
             self.step(txs)
         return self
+
+
+def small_commit(n_vals=24, height=3, corrupt=()):
+    """A validator set under the device threshold with one fully signed
+    commit: (chain_id, val_set, block_id, commit).  `corrupt` flips a bit
+    in those rows' signatures."""
+    keys, genesis = make_keys(n_vals)
+    val_set = make_genesis_state(genesis).validators
+    key_by_addr = {k.pub_key().address(): k for k in keys}
+    block_id = BlockID(hash=b"\xbb" * 32,
+                       part_set_header=PartSetHeader(total=1, hash=b"\xcc" * 32))
+    commit = sign_commit(genesis.chain_id, height, 0, block_id, val_set,
+                         key_by_addr, 1_700_000_123 * 10**9)
+    for idx in corrupt:
+        sig = commit.signatures[idx].signature
+        commit.signatures[idx].signature = sig[:-1] + bytes([sig[-1] ^ 1])
+    return genesis.chain_id, val_set, block_id, commit

@@ -210,8 +210,6 @@ def test_trigger_rate_limited_on_injected_clock():
     assert p.trigger("slo_burn") is True
     assert p.triggers == 2 and p.trigger_suppressed == 2
     assert p.report()["last_trigger"] == "slo_burn"
-    # no device dir + cpu backend: never arms a device capture
-    assert p.device_captures == 0
 
 
 # ---------------------------------------------------------------------------
@@ -232,7 +230,7 @@ def test_nop_contract():
     nop.stop()
 
 
-def test_from_env_gate_and_knobs(monkeypatch, tmp_path):
+def test_from_env_gate_and_knobs(monkeypatch):
     monkeypatch.setenv("TM_TPU_PROF", "0")
     assert pf.from_env(node="x") is pf.NOP
     monkeypatch.setenv("TM_TPU_PROF", "off")
@@ -241,16 +239,13 @@ def test_from_env_gate_and_knobs(monkeypatch, tmp_path):
     monkeypatch.setenv("TM_TPU_PROF", "1")
     monkeypatch.setenv("TM_TPU_PROF_HZ", "97")
     monkeypatch.setenv("TM_TPU_PROF_TRIGGER_MIN_S", "5")
-    monkeypatch.setenv("TM_TPU_PROF_DEVICE", "1")
-    p = pf.from_env(node="x", root=str(tmp_path))
+    p = pf.from_env(node="x")
     assert p.enabled and p.hz == 97.0 and p.trigger_min_s == 5.0
-    assert p.device_capture and p.device_dir == str(tmp_path / "prof")
 
     # malformed knob falls back to the default instead of crashing
     monkeypatch.setenv("TM_TPU_PROF_HZ", "fast")
-    monkeypatch.delenv("TM_TPU_PROF_DEVICE", raising=False)
     p = pf.from_env(node="x")
-    assert p.hz == pf.DEFAULT_HZ and not p.device_capture
+    assert p.hz == pf.DEFAULT_HZ
 
 
 # ---------------------------------------------------------------------------

@@ -21,19 +21,54 @@ def fresh_tracer():
     trace.clear()
 
 
-def test_disabled_path_is_zero_cost_and_records_nothing():
-    trace.set_enabled(False)
+def _bare_sites():
     # one branch per site: the disabled span() returns a shared no-op
     # singleton, no allocation, and nothing reaches the ring
     s1 = trace.span("a", k=1)
     s2 = trace.span("b")
     assert s1 is s2
-    with s1:
-        pass
+    with s1 as sp:
+        sp.set(late=2)
     trace.record("x", 0.0, 1.0)
     trace.instant("y")
+
+
+def _commit_call(entry):
+    """The verify surfaces' and the service's span sites (commit.*,
+    verify.*), driven through a whole call on the host path."""
+    from helpers import small_commit
+    from tendermint_tpu.crypto import async_verify as av
+
+    av.reset_service(linger_ms=1.0)
+    try:
+        chain, val_set, bid, commit = small_commit(24)
+        getattr(val_set, entry)(chain, bid, 3, commit)
+    finally:
+        av.reset_service()
+
+
+@pytest.mark.parametrize("drive", [
+    _bare_sites,
+    lambda: _commit_call("verify_commit"),
+    lambda: _commit_call("verify_commit_light"),
+], ids=["sites", "verify_commit", "verify_commit_light"])
+def test_disabled_path_is_zero_cost_and_records_nothing(drive):
+    trace.set_enabled(False)
+    drive()
     assert trace.spans() == []
     assert trace.summary() == {}
+
+
+def test_record_parents_under_the_callers_open_span():
+    trace.set_enabled(True)
+    with trace.span("outer") as sp:
+        sp.set(found=3)
+        trace.record("timed.elsewhere", 1.0, 0.5)
+    trace.record("root", 2.0, 0.5)
+    by = {s["name"]: s for s in trace.spans()}
+    assert by["timed.elsewhere"]["parent"] == by["outer"]["id"]
+    assert by["root"]["parent"] is None
+    assert by["outer"]["attrs"] == {"found": 3}
 
 
 def test_span_nesting_and_parent_links():
