@@ -11,12 +11,18 @@ This module is the continuous-batching answer (the Orca-style
 iteration-level scheduling of inference serving, applied to signature
 verification; PAPERS.md):
 
-  * `submit(pub, msg, sig) -> Future[bool]` never blocks.  Requests
-    from independent callers land in ONE submission queue; a daemon
-    worker coalesces them into a single batch and dispatches when the
-    queue reaches a size rung from the `_bucket` ladder or when a
-    linger deadline (`TM_TPU_LINGER_MS`) expires.  Below-threshold
-    flushes route to the host path exactly as today.
+  * `submit_many(items) -> Future[list[bool]]` (and `submit(pub, msg,
+    sig) -> Future[bool]`, a submit of one row) never blocks.  The unit
+    the service queues, accounts and resolves is the SUBMIT — a group of
+    rows with one future — not the row: a 10,000-row commit costs one
+    future, one queue entry, one cache pass each way and a handful of
+    histogram observes with a count.  Groups from independent callers
+    land in ONE submission queue; a daemon worker coalesces them into a
+    single batch (cutting a group that is wider than what a flush has
+    left) and dispatches when the queued rows reach a size rung from
+    the `_bucket` ladder or when a linger deadline (`TM_TPU_LINGER_MS`)
+    expires.  Below-threshold flushes route to the host path exactly as
+    today.
   * Double-buffered host/device pipelining: the worker ENQUEUES the
     compiled device program for batch i (JAX dispatch is async) and
     immediately starts host prep (sign-bytes SHA-512, s<L) for batch
@@ -24,10 +30,11 @@ verification; PAPERS.md):
     queue runs dry.  Batches over TM_TPU_CHUNK reuse the r5 chunk
     machinery (ops.ed25519_jax.chunks_of).
   * A bounded verified-signature LRU cache keyed by
-    (pub, sha256(msg), sig) is consulted before enqueue and populated
-    ONLY on success — gossip duplicates and replay re-verification
-    never reach the device (and a corrupted signature can never be
-    cached as valid, by construction).
+    (pub, sha256(msg), sig) is consulted before enqueue — one bulk
+    probe per submit, under one lock acquisition — and populated ONLY
+    on success, one bulk put per flush — gossip duplicates and replay
+    re-verification never reach the device (and a corrupted signature
+    can never be cached as valid, by construction).
 
 Degradation contract (the `_DEVICE_READY` guarantee, one level up): the
 worker only dispatches to the device after crypto.batch's warmup has
@@ -56,12 +63,14 @@ Env knobs:
                         synchronous multi-device routing.
   TM_TPU_MESH_MIN_SHARD flush size at/above which a flush shards
                         (default 64 rows per device).
-  TM_TPU_TRACE          1 additionally records submit/wait (caller) and
+  TM_TPU_TRACE          1 additionally records submit (with one child
+                        per bulk pass: keys, probe) and wait (caller) and
                         coalesce/account/flush/host-prep/device-execute/
                         resolve (worker) spans into the utils.trace ring,
                         the worker's tied by a `flush` number
                         (docs/observability.md); the latency histograms
-                        below are always on.
+                        below are always on, observed once per flush
+                        segment with the segment's row count.
 """
 
 from __future__ import annotations
@@ -73,6 +82,7 @@ import threading
 import time
 from collections import OrderedDict, deque
 from concurrent.futures import Future
+from itertools import chain, compress
 
 from tendermint_tpu.utils import devmon as _devmon
 from tendermint_tpu.utils import trace as _trace
@@ -144,24 +154,93 @@ def _env_int(name: str, default: int) -> int:
         return default
 
 
-class _Request:
-    __slots__ = ("pub", "msg", "sig", "key", "future", "t_submit")
+class _Group:
+    """One submit: the unit the service queues, accounts and resolves.
+    `pubs` / `msgs` / `sigs` / `keys` are its FRESH rows (the cache
+    misses; the submit's own lists when nothing hit), `pos` their
+    places in `results` (None: every row is fresh, in place).
+    `results` starts as the cache probe's answer — True at the hits,
+    False at every row no verify path has answered yet — and `future`
+    resolves to it once the last fresh row has landed (`single`: to its
+    only element).  `taken` counts the rows handed to a flush (moved
+    under the service lock), `left` those not yet resolved (the
+    worker's alone)."""
 
-    def __init__(self, pub: bytes, msg: bytes, sig: bytes, key, future: Future,
-                 t_submit: float):
-        self.pub = pub
-        self.msg = msg
-        self.sig = sig
-        self.key = key
-        self.future = future
+    __slots__ = ("pubs", "msgs", "sigs", "keys", "pos", "results", "single",
+                 "t_submit", "future", "taken", "left")
+
+    def __init__(self, pubs, msgs, sigs, keys, pos, results, single,
+                 t_submit):
+        self.pubs, self.msgs, self.sigs, self.keys = pubs, msgs, sigs, keys
+        self.pos = pos
+        self.results = results
+        self.single = single
         self.t_submit = t_submit
+        self.future: Future = Future()
+        self.taken = 0
+        self.left = len(keys)
+
+    def land(self, start: int, oks: list) -> None:
+        """Verdicts of the fresh rows from `start` on; the last to land
+        resolves the future.  A group whose future already failed
+        ignores what lands later."""
+        if self.pos is None:
+            self.results[start:start + len(oks)] = oks
+        else:
+            for i, ok in zip(self.pos[start:start + len(oks)], oks):
+                self.results[i] = ok
+        self.left -= len(oks)
+        if self.left <= 0 and not self.future.done():
+            self.future.set_result(self.results[0] if self.single
+                                   else self.results)
+
+    def fail(self, err: BaseException) -> None:
+        if not self.future.done():
+            self.future.set_exception(err)
+
+
+class _Batch:
+    """The rows of one flush, or of one chunk of it: the segments
+    `(group, start, end)` in row order, and the three lists a verify
+    path takes — the group's own when the batch is one whole group (a
+    commit's case: no copy), else the segments' slices concatenated."""
+
+    __slots__ = ("segs", "pubs", "msgs", "sigs", "enqueued")
+
+    def __init__(self, segs: list):
+        self.segs = segs
+        self.enqueued = 0  # rows already in flight on the device
+        g, a, b = segs[0]
+        if len(segs) == 1 and a == 0 and b == len(g.keys):
+            self.pubs, self.msgs, self.sigs = g.pubs, g.msgs, g.sigs
+        else:
+            self.pubs = [p for g, a, b in segs for p in g.pubs[a:b]]
+            self.msgs = [m for g, a, b in segs for m in g.msgs[a:b]]
+            self.sigs = [s for g, a, b in segs for s in g.sigs[a:b]]
+
+    def __len__(self) -> int:
+        return len(self.pubs)
+
+    def cut(self, start: int, end: int) -> "_Batch":
+        """Rows [start, end) as a batch of their own."""
+        if start == 0 and end == len(self):
+            return self
+        segs, at = [], 0
+        for g, a, b in self.segs:
+            lo, hi = max(a, a + start - at), min(b, a + end - at)
+            if lo < hi:
+                segs.append((g, lo, hi))
+            at += b - a
+        return _Batch(segs)
 
 
 class VerifiedSigCache:
     """Bounded thread-safe LRU of (pub, sha256(msg), sig) triples proven
     VALID.  Only True verdicts are ever stored: a rejected signature is
     re-verified on every appearance, so a corrupted signature cannot be
-    cached as valid no matter what races occur."""
+    cached as valid no matter what races occur.  Probes and puts are
+    bulk: one lock acquisition and one read of the capacity for a whole
+    submit or flush."""
 
     def __init__(self, maxsize: int | None = None):
         # None = resolve TM_TPU_VERIFY_CACHE at every probe, so a value
@@ -182,29 +261,51 @@ class VerifiedSigCache:
         return _env_int("TM_TPU_VERIFY_CACHE", DEFAULT_CACHE_SIZE)
 
     @staticmethod
+    def keys(pubs, msgs, sigs) -> list[tuple]:
+        sha256 = hashlib.sha256
+        return [(p, sha256(m).digest(), s)
+                for p, m, s in zip(pubs, msgs, sigs)]
+
+    @staticmethod
     def key(pub: bytes, msg: bytes, sig: bytes) -> tuple:
         return (pub, hashlib.sha256(msg).digest(), sig)
 
-    def get(self, key) -> bool:
+    def get_many(self, keys) -> list[bool]:
+        """Which of `keys` are cached (each hit becomes the most
+        recently used)."""
         if self.maxsize <= 0:
-            self.misses += 1  # tmsan: shared=diagnostic counter on the disabled-cache path; tolerates lost updates
-            return False
+            self.misses += len(keys)  # tmsan: shared=diagnostic counter on the disabled-cache path; tolerates lost updates
+            return [False] * len(keys)
         with self._lock:
-            if key in self._d:
-                self._d.move_to_end(key)
-                self.hits += 1
-                return True
-            self.misses += 1
-            return False
+            d = self._d
+            found = [k in d for k in keys]
+            n_hit = sum(found)
+            if n_hit:
+                for k in compress(keys, found):
+                    d.move_to_end(k)
+            self.hits += n_hit
+            self.misses += len(keys) - n_hit
+        return found
 
-    def put(self, key) -> None:
-        if self.maxsize <= 0:
+    def get(self, key) -> bool:
+        return self.get_many((key,))[0]
+
+    def put_many(self, keys) -> None:
+        """Remember `keys` — of rows PROVEN valid, the caller's duty —
+        and evict the least recently used beyond the capacity."""
+        cap = self.maxsize
+        if cap <= 0 or not keys:
             return
         with self._lock:
-            self._d[key] = True
-            self._d.move_to_end(key)
-            while len(self._d) > self.maxsize:
-                self._d.popitem(last=False)
+            d = self._d
+            for k in keys:
+                d[k] = True
+                d.move_to_end(k)
+            while len(d) > cap:
+                d.popitem(last=False)
+
+    def put(self, key) -> None:
+        self.put_many((key,))
 
     def __len__(self) -> int:
         return len(self._d)
@@ -223,11 +324,12 @@ class VerifyService:
         self._pinned_linger_ms = linger_ms
         self.cache = VerifiedSigCache(cache_size)
         self._cv = threading.Condition()
-        self._queue: deque[_Request] = deque()
+        self._queue: deque[_Group] = deque()
         self._worker: threading.Thread | None = None
         self._closed = False
         self.stats = {
-            "submitted": 0,
+            "submits": 0,    # groups queued
+            "submitted": 0,  # their fresh rows
             "flushes": 0,
             "host_flushes": 0,
             "device_batches": 0,
@@ -236,6 +338,7 @@ class VerifyService:
             "mesh_pinned_batches": 0,
             "mesh_sharded_batches": 0,
             "device_errors": 0,
+            "queue_depth": 0,  # rows of the queued groups not yet taken
         }
         # sites ("enqueue", "enqueue_sharded", "readback", "sync") whose
         # first device error was already logged with its traceback
@@ -282,55 +385,73 @@ class VerifyService:
     # -- submission (caller side; never blocks) -----------------------
 
     def submit(self, pub, msg: bytes, sig: bytes) -> Future:
-        """Queue one verification; resolves to bool.  Cache hits resolve
-        immediately without queueing."""
-        return self.submit_many([(pub, msg, sig)])[0]
+        """Queue one verification; resolves to bool — a submit of one
+        row, its result unwrapped.  A cache hit resolves immediately
+        without queueing."""
+        return self._submit([(pub, msg, sig)], single=True).future
 
-    def submit_many(self, items) -> list[Future]:
-        """Bulk submit: one cache pass + one queue append under a single
-        lock acquisition — the large-batch path (a 10k commit) must not
-        pay per-item lock traffic."""
-        t_sub = time.perf_counter()  # one stamp per bulk submit, not per item
-        futures: list[Future] = []
-        fresh: list[_Request] = []
-        for pub, msg, sig in items:
-            pub_b = _pub_bytes(pub)
-            msg_b = bytes(msg)
-            sig_b = bytes(sig)
-            key = VerifiedSigCache.key(pub_b, msg_b, sig_b)
-            fut: Future = Future()
-            futures.append(fut)
-            if self.cache.get(key):
-                fut.set_result(True)
-                VERIFY_E2E_SECONDS.observe(time.perf_counter() - t_sub,
-                                           path="cache")
-            else:
-                fresh.append(_Request(pub_b, msg_b, sig_b, key, fut, t_sub))
-        if fresh:
+    def submit_many(self, items) -> Future:
+        """Bulk submit: ONE future for the whole submit, resolving to
+        the list of verdicts in input order (immediately if every row
+        hit the cache)."""
+        return self._submit(items).future
+
+    def _submit(self, items, single: bool = False) -> _Group:
+        """Build and queue the group of one submit: every pass is bulk —
+        the key hashes in one comprehension, the cache probe and the
+        queue append under one lock acquisition each — so a 10k commit
+        pays nothing per row but the hashing itself."""
+        t_sub = time.perf_counter()  # one stamp per submit
+        cols = tuple(zip(*items)) or ((), (), ())
+        pubs = _bytes_column(cols[0], _pub_bytes)
+        msgs = _bytes_column(cols[1])
+        sigs = _bytes_column(cols[2])
+        t_keys = time.perf_counter()
+        keys = VerifiedSigCache.keys(pubs, msgs, sigs)
+        t_probe = time.perf_counter()
+        found = self.cache.get_many(keys)
+        t_probed = time.perf_counter()
+        n, hits, pos = len(keys), sum(found), None
+        if hits:
+            VERIFY_E2E_SECONDS.observe_n(t_probed - t_sub, hits, path="cache")
+            pos = [i for i, hit in enumerate(found) if not hit]
+            pubs, msgs, sigs, keys = ([col[i] for i in pos]
+                                      for col in (pubs, msgs, sigs, keys))
+        group = _Group(pubs, msgs, sigs, keys, pos, found, single, t_sub)
+        if keys:
             with self._cv:
                 if self._closed:
                     raise RuntimeError("verify service is closed")
-                self.stats["submitted"] += len(fresh)
-                self._queue.extend(fresh)
+                self.stats["submits"] += 1
+                self.stats["submitted"] += len(keys)
+                self._queue.append(group)
+                self.stats["queue_depth"] += len(keys)
                 self._ensure_worker_locked()
                 self._cv.notify()
+        else:
+            group.land(0, [])  # every row hit (or none was given): resolved
         if _trace.enabled():
-            _trace.record("verify.submit", t_sub,
-                          time.perf_counter() - t_sub,
-                          n=len(futures), fresh=len(fresh))
-        return futures
+            sid = _trace.record("verify.submit", t_sub,
+                                time.perf_counter() - t_sub,
+                                n=n, fresh=n - hits, hits=hits)
+            # one child per bulk pass, never per row
+            _trace.record("verify.submit.keys", t_keys, t_probe - t_keys,
+                          parent=sid, n=n)
+            _trace.record("verify.submit.probe", t_probe, t_probed - t_probe,
+                          parent=sid, n=n, hits=hits)
+        return group
 
     def verify_many(self, items) -> list[bool]:
-        """Sync convenience wrapper: submit all, wait for all.  Blocks
-        only on verification work the host path could also perform —
-        never on device warmup (the worker routes around a cold or
-        wedged device)."""
-        futs = self.submit_many(items)
-        # the caller wakes at the first resolved future and then collects
-        # the rest while the worker is still resolving: what of this span
-        # lies past the flush's `verify.resolve` is the caller alone
-        with _trace.span("verify.wait", n=len(futs)):
-            return [bool(f.result()) for f in futs]
+        """Sync convenience wrapper: submit, wait for the submit's one
+        future.  Blocks only on verification work the host path could
+        also perform — never on device warmup (the worker routes around
+        a cold or wedged device)."""
+        group = self._submit(items)
+        # the caller sleeps until the flush that lands the group's last
+        # row has resolved it: what of this span lies past that flush's
+        # `verify.resolve` is the caller's wake-up alone
+        with _trace.span("verify.wait", n=len(group.results)):
+            return group.future.result()
 
     def close(self) -> None:
         with self._cv:
@@ -364,54 +485,68 @@ class VerifyService:
         except Exception:  # noqa: BLE001
             return target
 
-    def _collect(self, block: bool) -> list[_Request]:
+    def _collect(self, block: bool) -> "_Batch | None":
         """Take the next coalesced batch off the queue: wait (if `block`)
-        for the first request, then linger until the rung fills or the
-        deadline passes."""
+        for the first group, then linger until the queued rows fill the
+        rung or the deadline passes.  Groups are taken whole while they
+        fit; one wider than what the flush has left is cut and stays at
+        the head of the queue for the next flush."""
         with self._cv:
             if block:
                 while not self._queue and not self._closed:
                     self._cv.wait()
             if not self._queue:
-                return []
+                return None
             t_linger0 = time.perf_counter()
             if self.linger_s > 0:
                 rung = self._flush_rung()
                 deadline = time.monotonic() + self.linger_s
-                while (len(self._queue) < rung and not self._closed):
+                while (self.stats["queue_depth"] < rung
+                       and not self._closed):
                     remaining = deadline - time.monotonic()
                     if remaining <= 0:
                         break
                     self._cv.wait(remaining)
-            batch = [self._queue.popleft()
-                     for _ in range(min(len(self._queue), MAX_COALESCE))]
+            segs, room = [], MAX_COALESCE
+            while self._queue and room:
+                group = self._queue[0]
+                start = group.taken
+                end = group.taken = min(len(group.keys), start + room)
+                segs.append((group, start, end))
+                room -= end - start
+                if end == len(group.keys):
+                    self._queue.popleft()
+            n = MAX_COALESCE - room
+            self.stats["queue_depth"] -= n
             # counter updates stay inside the lock so service_stats()
             # snapshots are never torn across a flush boundary
             self.stats["flushes"] += 1
-            self.stats["coalesced_max"] = max(self.stats["coalesced_max"],
-                                              len(batch))
+            self.stats["coalesced_max"] = max(self.stats["coalesced_max"], n)
             flush = self._flush_no = self.stats["flushes"]  # tmsan: shared=written by the worker thread only
         now = time.perf_counter()
         if _trace.enabled():
             # oldest_submit_ns == the t0_ns of the `verify.submit` span
-            # that queued the batch's first request (same float, same
+            # that queued the batch's first group (same float, same
             # rounding): the tie between a caller's spans and a flush
             _trace.record("verify.coalesce", t_linger0, now - t_linger0,
-                          n=len(batch), flush=flush,
-                          oldest_submit_ns=int(batch[0].t_submit * 1e9))
-        # per-request accounting on the worker, the batch already taken
-        # and not yet routed: the device idles under this span
-        with _trace.span("verify.account", n=len(batch), flush=flush):
+                          n=n, groups=len(segs), flush=flush,
+                          oldest_submit_ns=int(segs[0][0].t_submit * 1e9))
+        # accounting on the worker, the batch already taken and not yet
+        # routed (the device idles under this span): one observe per
+        # segment, counted once per row — every row of a submit waited
+        # the same time
+        with _trace.span("verify.account", n=n, groups=len(segs), flush=flush):
             VERIFY_LINGER_SECONDS.observe(now - t_linger0)
-            for r in batch:
-                VERIFY_QUEUE_WAIT_SECONDS.observe(now - r.t_submit)
-        return batch
+            for group, start, end in segs:
+                VERIFY_QUEUE_WAIT_SECONDS.observe_n(now - group.t_submit,
+                                                    end - start)
+        return _Batch(segs)
 
     def _run(self) -> None:
         # in-flight device batches awaiting verdict readback:
-        # (pending_device_value, reqs).  Depth 2 = double buffering —
-        # batch i executes on device while batch i+1 is host-prepped and
-        # enqueued behind it.
+        # (pending_device_value, batch, ...).  Depth 2 = double buffering
+        # — batch i executes on device while batch i+1 is host-prepped
+        # and enqueued behind it.
         inflight: deque = deque()
         while True:
             with self._cv:
@@ -421,70 +556,70 @@ class VerifyService:
             if inflight and queue_empty:
                 self._drain_one(inflight)
                 continue
-            reqs = self._collect(block=not inflight)
-            if reqs:
+            batch = self._collect(block=not inflight)
+            if batch is not None:
                 try:
-                    self._flush(reqs, inflight)
+                    self._flush(batch, inflight)
                 except BaseException as e:  # noqa: BLE001
-                    self._resolve_failed(reqs, e)
+                    self._resolve_failed(batch, e)
             while len(inflight) >= 2:
                 self._drain_one(inflight)
 
-    def _flush(self, reqs: list[_Request], inflight: deque) -> None:
+    def _flush(self, batch: _Batch, inflight: deque) -> None:
         """Route one coalesced batch: host below threshold / before
         device readiness; async device enqueue otherwise.  The flush
         span records which path won and WHY (the question the raw
         counters could never answer)."""
         t0 = time.perf_counter()
-        path, reason = self._route(reqs, inflight)
+        path, reason = self._route(batch, inflight)
         self.last_route = (path, reason)  # tmsan: shared=atomic tuple rebind, last-write-wins diagnostic
         if _trace.enabled():
             _trace.record("verify.flush", t0, time.perf_counter() - t0,
-                          path=path, reason=reason, n=len(reqs),
+                          path=path, reason=reason, n=len(batch),
                           flush=self._flush_no)
 
-    def _route(self, reqs: list[_Request], inflight: deque) -> tuple[str, str]:
-        n = len(reqs)
+    def _route(self, batch: _Batch, inflight: deque) -> tuple[str, str]:
+        n = len(batch)
         bv = self._jax_bv
         if bv is None:
-            self._host_verify(reqs)
+            self._host_verify(batch)
             return "host", "no_jax"
         thr = bv._resolved_threshold(n)
         if n < thr:
-            self._host_verify(reqs)
+            self._host_verify(batch)
             return "host", "below_threshold"
         if not _batch._DEVICE_READY.is_set():
             # identical degradation to JAXBatchVerifier._ed_batch: kick
             # the warmup worker, verify on host meanwhile — device init
             # must never block a submitter
             _batch.start_device_warmup()
-            self._host_verify(reqs)
+            self._host_verify(batch)
             return "host", "device_not_ready"
-        mixed = any(len(r.pub) != 32 for r in reqs)
+        mixed = any(len(p) != 32 for p in batch.pubs)
         if mixed or os.environ.get("TM_TPU_RLC", "0") == "1":
             # rarer shapes (secp-mixed batches, RLC) run the existing
             # synchronous routing — bit-identical verdicts, no pipelining
-            self._sync_device_verify(reqs, bv)
+            self._sync_device_verify(batch, bv)
             return "device", "sync_routing"
         ndev = bv._device_count()
         if ndev > 1:
             if not _mesh.dispatcher_enabled():
                 # TM_TPU_MESH=0: legacy synchronous mesh routing
-                self._sync_device_verify(reqs, bv)
+                self._sync_device_verify(batch, bv)
                 return "device", "sync_routing"
             route, m = _mesh.decide(n, ndev)
             if route == "sharded":
                 try:
-                    self._enqueue_sharded(reqs, inflight, m)
+                    self._enqueue_sharded(batch, inflight, m)
                     return "device", "mesh_sharded"
                 except Exception:  # noqa: BLE001 — mesh failure: host
                     self._device_error("enqueue_sharded", n)
-                    self._host_verify(reqs)
+                    self._host_verify(batch)
                     return "host", "device_error"
             # pinned: fall through to the single-chip pipelined enqueue
             # below — identical programs/cache keys to a 1-device node
         try:
-            self._enqueue_device(reqs, inflight)
+            self._enqueue_device(batch, inflight)
             if ndev > 1:
                 with self._cv:
                     self.stats["mesh_pinned_batches"] += 1
@@ -492,7 +627,8 @@ class VerifyService:
             return "device", "pipelined"
         except Exception:  # noqa: BLE001 — device failure: host fallback
             self._device_error("enqueue", n)
-            self._host_verify(reqs)
+            # the chunks already in flight keep their device verdicts
+            self._host_verify(batch.cut(batch.enqueued, n))
             return "host", "device_error"
 
     def _device_error(self, site: str, n: int) -> None:
@@ -511,14 +647,15 @@ class VerifyService:
                          "at this site are counted in device_errors "
                          "only", site, n, exc_info=True)
 
-    def _enqueue_device(self, reqs: list[_Request], inflight: deque) -> None:
+    def _enqueue_device(self, batch: _Batch, inflight: deque) -> None:
         """Host prep + async enqueue of the per-row device program,
         chunked via the r5 machinery when TM_TPU_CHUNK is set.  Verdict
         readback happens in _drain_one — by then the worker has already
-        host-prepped the NEXT batch behind the executing one."""
+        host-prepped the NEXT batch behind the executing one.
+        `batch.enqueued` says how far it got if a chunk raises."""
         from tendermint_tpu.ops import ed25519_jax as dev
 
-        n = len(reqs)
+        n = len(batch)
         flush = self._flush_no
         impl = dev.default_impl()
         base_mxu = dev._resolve_optin(impl)
@@ -526,11 +663,9 @@ class VerifyService:
         plan = (dev.chunks_of(n, chunk) if chunk and n > chunk
                 else [(0, n, dev._bucket(n))])
         for start, end, b in plan:
-            sub = reqs[start:end]
+            sub = batch.cut(start, end)
             t_prep = time.perf_counter()
-            rows = dev.prepare_batch([r.pub for r in sub],
-                                     [r.msg for r in sub],
-                                     [r.sig for r in sub])
+            rows = dev.prepare_batch(sub.pubs, sub.msgs, sub.sigs)
             padded = dev._pad_rows(end - start, b, *rows)
             prep_dt = time.perf_counter() - t_prep
             VERIFY_HOST_PREP_SECONDS.observe(prep_dt)
@@ -545,10 +680,11 @@ class VerifyService:
             t_enq = time.perf_counter()
             pending = dev._compiled(b, impl, base_mxu)(*padded)
             inflight.append((pending, sub, t_enq, b, flush))
+            batch.enqueued = end
             with self._cv:
                 self.stats["device_batches"] += 1
 
-    def _enqueue_sharded(self, reqs: list[_Request], inflight: deque,
+    def _enqueue_sharded(self, batch: _Batch, inflight: deque,
                          m: int) -> None:
         """Host prep + async enqueue of the SHARDED per-row program over
         an m-device mesh: rows are padded to a device-multiple rung and
@@ -559,13 +695,11 @@ class VerifyService:
         from tendermint_tpu.parallel import sharding as _sh
 
         mesh = _mesh.mesh_for(m)
-        n = len(reqs)
+        n = len(batch)
         flush = self._flush_no
         b = _sh.sharded_bucket(n, m)
         t_prep = time.perf_counter()
-        rows = dev.prepare_batch([r.pub for r in reqs],
-                                 [r.msg for r in reqs],
-                                 [r.sig for r in reqs])
+        rows = dev.prepare_batch(batch.pubs, batch.msgs, batch.sigs)
         padded = dev._pad_rows(n, b, *rows)
         prep_dt = time.perf_counter() - t_prep
         VERIFY_HOST_PREP_SECONDS.observe(prep_dt)
@@ -582,7 +716,7 @@ class VerifyService:
         self.last_shard_layout = tuple(  # tmsan: shared=atomic tuple rebind, last-write-wins diagnostic
             (int(s.device.id), int(s.data.shape[0]))
             for s in pending.addressable_shards)
-        inflight.append((pending, reqs, t_enq, b, flush))
+        inflight.append((pending, batch, t_enq, b, flush))
         with self._cv:
             self.stats["device_batches"] += 1
             self.stats["mesh_sharded_batches"] += 1
@@ -590,17 +724,17 @@ class VerifyService:
     def _drain_one(self, inflight: deque) -> None:
         import numpy as np
 
-        pending, reqs, t_enq, rung, flush = inflight.popleft()
+        pending, batch, t_enq, rung, flush = inflight.popleft()
         # the worker turns to an older flush, possibly in the middle of
         # enqueueing a newer one: spans below carry the drained number
         routing, self._flush_no = self._flush_no, flush  # tmsan: shared=written by the worker thread only
         with self._cv:
             self.stats["pipelined_drains"] += 1
         try:
-            oks = np.asarray(pending)[:len(reqs)]
+            oks = np.asarray(pending)[:len(batch)].tolist()
         except Exception:  # noqa: BLE001 — readback failed: host verdicts
-            self._device_error("readback", len(reqs))
-            self._host_verify(reqs, count_flush=False)
+            self._device_error("readback", len(batch))
+            self._host_verify(batch, count_flush=False)
         else:
             dt = time.perf_counter() - t_enq
             VERIFY_DEVICE_EXECUTE_SECONDS.observe(dt, rung=rung)
@@ -609,73 +743,96 @@ class VerifyService:
                 # other in-flight batch, i.e. what a submitter actually
                 # experiences
                 _trace.record("verify.device_execute", t_enq, dt,
-                              n=len(reqs), rung=rung, flush=flush)
-            self._resolve(reqs, oks, path="device")
+                              n=len(batch), rung=rung, flush=flush)
+            self._resolve(batch, oks, path="device")
         self._flush_no = routing  # tmsan: shared=written by the worker thread only
 
-    def _sync_device_verify(self, reqs: list[_Request], bv) -> None:
+    def _sync_device_verify(self, batch: _Batch, bv) -> None:
         t0 = time.perf_counter()
         try:
-            oks = _split_verify([r.pub for r in reqs],
-                                [r.msg for r in reqs],
-                                [r.sig for r in reqs], bv._ed_batch)
+            oks = _verify_rows(batch, bv._ed_batch)
             with self._cv:
                 self.stats["device_batches"] += 1
         except Exception:  # noqa: BLE001 — device failure: host verdicts
-            self._device_error("sync", len(reqs))
-            self._host_verify(reqs)
+            self._device_error("sync", len(batch))
+            self._host_verify(batch)
             return
         dt = time.perf_counter() - t0
         VERIFY_DEVICE_EXECUTE_SECONDS.observe(dt, rung="sync")
         if _trace.enabled():
             _trace.record("verify.device_execute", t0, dt,
-                          n=len(reqs), rung="sync", flush=self._flush_no)
-        self._resolve(reqs, oks, path="device")
+                          n=len(batch), rung="sync", flush=self._flush_no)
+        self._resolve(batch, oks, path="device")
 
-    def _host_verify(self, reqs: list[_Request], count_flush: bool = True) -> None:
+    def _host_verify(self, batch: _Batch, count_flush: bool = True) -> None:
         if count_flush:
             with self._cv:
                 self.stats["host_flushes"] += 1
         t0 = time.perf_counter()
         try:
-            oks = _split_verify([r.pub for r in reqs],
-                                [r.msg for r in reqs],
-                                [r.sig for r in reqs],
-                                _ed.verify_batch_fast)
+            oks = _verify_rows(batch, _ed.verify_batch_fast)
         except BaseException as e:  # noqa: BLE001
-            self._resolve_failed(reqs, e)
+            self._resolve_failed(batch, e)
             return
         if _trace.enabled():
             _trace.record("verify.host_verify", t0,
-                          time.perf_counter() - t0, n=len(reqs),
+                          time.perf_counter() - t0, n=len(batch),
                           flush=self._flush_no)
-        self._resolve(reqs, oks, path="host")
+        self._resolve(batch, oks, path="host")
 
-    def _resolve(self, reqs: list[_Request], oks, path: str = "host") -> None:
+    def _resolve(self, batch: _Batch, oks: list, path: str = "host") -> None:
+        """Account and land one batch's verdicts (`oks`: one bool per
+        row): the end-to-end observe once per segment with its row
+        count, then the bulk part in `_land`."""
         now = time.perf_counter()
-        with _trace.span("verify.resolve", n=len(reqs), path=path,
+        with _trace.span("verify.resolve", n=len(batch),
+                         groups=len(batch.segs), path=path,
                          flush=self._flush_no):
-            for req, ok in zip(reqs, oks):
-                ok = bool(ok)
-                if ok:
-                    self.cache.put(req.key)
-                VERIFY_E2E_SECONDS.observe(now - req.t_submit, path=path)
-                req.future.set_result(ok)
+            for group, start, end in batch.segs:
+                VERIFY_E2E_SECONDS.observe_n(now - group.t_submit,
+                                             end - start, path=path)
+            self._land(batch.segs, oks)
 
-    def _resolve_failed(self, reqs: list[_Request], err: BaseException) -> None:
+    def _land(self, segs: list, oks: list) -> None:
+        """Put the valid rows' keys with ONE bulk cache put (only True
+        rows, as the cache promises; before any future resolves, so a
+        caller that wakes finds its rows cached), then hand each segment
+        its slice of the verdicts; a group whose last row lands
+        resolves."""
+        keys = chain.from_iterable(g.keys[a:b] for g, a, b in segs)
+        self.cache.put_many(list(compress(keys, oks)))
+        at = 0
+        for group, start, end in segs:
+            group.land(start, oks[at:at + end - start])
+            at += end - start
+
+    def _resolve_failed(self, batch: _Batch, err: BaseException) -> None:
         """Catastrophic path: even the batched host verify raised.  Fall
-        back to per-item verification so one poisoned row cannot take
-        the whole flush down; anything still failing propagates the
-        error to its submitter (same contract as the sync path, which
-        would have raised to the caller)."""
-        for req in reqs:
+        back to per-item verification, a segment at a time, so one
+        poisoned row cannot take the whole flush down; a segment with a
+        row that still fails propagates the error to its group's
+        submitter (same contract as the sync path, which would have
+        raised to the caller)."""
+        for seg in batch.segs:
+            group, start, end = seg
             try:
-                ok = bool(_ed.verify_fast(req.pub, req.msg, req.sig))
-                if ok:
-                    self.cache.put(req.key)
-                req.future.set_result(ok)
+                oks = [bool(_ed.verify_fast(p, m, s)) for p, m, s in zip(
+                    group.pubs[start:end], group.msgs[start:end],
+                    group.sigs[start:end])]
             except BaseException:  # noqa: BLE001
-                req.future.set_exception(err)
+                group.fail(err)
+            else:
+                self._land([seg], oks)
+
+
+def _bytes_column(col, convert=bytes) -> list[bytes]:
+    # a type test per row, not a call: the surfaces hand in bytes already
+    return [x if type(x) is bytes else convert(x) for x in col]
+
+
+def _verify_rows(batch: _Batch, ed_batch_fn) -> list[bool]:
+    return list(map(bool, _split_verify(batch.pubs, batch.msgs, batch.sigs,
+                                        ed_batch_fn)))
 
 
 class ServiceBatchVerifier:
@@ -803,7 +960,7 @@ def service_stats() -> dict:
     counter set (e.g. a flush counted but its coalesced_max not yet)."""
     svc = _SERVICE
     if svc is None:
-        return {"submitted": 0, "flushes": 0, "host_flushes": 0,
+        return {"submits": 0, "submitted": 0, "flushes": 0, "host_flushes": 0,
                 "device_batches": 0, "coalesced_max": 0,
                 "pipelined_drains": 0, "mesh_pinned_batches": 0,
                 "mesh_sharded_batches": 0, "device_errors": 0,
@@ -811,7 +968,6 @@ def service_stats() -> dict:
                 "cache_misses": 0, "cache_size": 0, "queue_depth": 0}
     with svc._cv:
         out = dict(svc.stats)
-        out["queue_depth"] = len(svc._queue)
     cache = svc.cache
     with cache._lock:
         out["cache_hits"] = cache.hits

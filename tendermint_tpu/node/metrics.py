@@ -190,6 +190,13 @@ class NodeMetrics:
             "Signatures submitted to the async verification service",
             namespace=ns, subsystem="crypto", fn=_svc("submitted"),
         ))
+        self.verify_submits = reg.register(CallbackCounter(
+            "verify_submits_total",
+            "Submits (groups of signatures sharing one future) queued by "
+            "the async verification service; submitted / submits = rows "
+            "per submit",
+            namespace=ns, subsystem="crypto", fn=_svc("submits"),
+        ))
         self.verify_cache_hits = reg.register(CallbackCounter(
             "verify_cache_hits_total",
             "Verifications resolved from the verified-signature cache",

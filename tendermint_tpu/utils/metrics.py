@@ -131,18 +131,23 @@ class Histogram(_Metric):
             self._series[()] = [[0] * (len(self.buckets) + 1), 0.0, 0]
 
     def observe(self, value: float, **labels) -> None:
-        key = tuple(str(labels.get(n, "")) for n in self.label_names)
+        self.observe_n(value, 1, **labels)
+
+    def observe_n(self, value: float, n: int, **labels) -> None:
+        """`n` observations of the same value at once: the state `n`
+        equal observes leave (a flush's rows all waited the same time)."""
+        key = tuple(str(labels.get(name, "")) for name in self.label_names)
         cell = self._series.get(key)
         if cell is None:
             cell = self._series[key] = [[0] * (len(self.buckets) + 1), 0.0, 0]
-        cell[1] += value
-        cell[2] += 1
+        cell[1] += value * n
+        cell[2] += n
         counts = cell[0]
         for i, b in enumerate(self.buckets):
             if value <= b:
-                counts[i] += 1
+                counts[i] += n
                 return
-        counts[-1] += 1
+        counts[-1] += n
 
     def label_stats(self) -> dict:
         """Per-labelset (count, sum) snapshot keyed by the label-value

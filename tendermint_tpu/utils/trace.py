@@ -36,7 +36,9 @@ Parent links: a `span()` is parented under the calling thread's
 innermost open span, and so is a `record()` — the span open on the
 thread that CALLS record(), whatever thread the measured work ran on
 (`verify.submit` hangs under the caller's `commit.verify`; the worker
-thread holds no open span, so its records stay roots).  Spans of one
+thread holds no open span, so its records stay roots); a `record()`
+given `parent=` hangs there instead (`verify.submit.keys` under
+`verify.submit`, both recorded when the submit ends).  Spans of one
 flush on different threads are tied by attrs instead (`flush`,
 `oldest_submit_ns`; docs/observability.md).
 """
@@ -201,17 +203,24 @@ def span(name: str, **attrs) -> "_SpanCtx | _NopSpan":
     return _SpanCtx(name, attrs)
 
 
-def record(name: str, t0: float, dur: float, **attrs) -> None:
+def record(name: str, t0: float, dur: float, parent: int | None = None,
+           **attrs) -> int | None:
     """A complete span with externally measured timing — t0/dur in
     seconds on the time.perf_counter() clock.  For work whose start and
     end live on different threads (device enqueue → verdict drain) or
     whose duration was measured on another monotonic clock.  Parented
-    under the calling thread's innermost open span, like `span()`."""
+    under the calling thread's innermost open span, like `span()`, or
+    under `parent`: the id an earlier record() returned, for the parts
+    of a span that is itself recorded at its end.  Returns the span's
+    id (None with tracing off)."""
     en = _enabled
     if not (en if en is not None else _resolve_enabled()):
-        return
-    _append(name, next(_ids), current_span_id(), int(t0 * 1e9),
-            max(0, int(dur * 1e9)), attrs)
+        return None
+    span_id = next(_ids)
+    _append(name, span_id,
+            parent if parent is not None else current_span_id(),
+            int(t0 * 1e9), max(0, int(dur * 1e9)), attrs)
+    return span_id
 
 
 def instant(name: str, **attrs) -> None:

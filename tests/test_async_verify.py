@@ -180,6 +180,215 @@ def test_coalesces_concurrent_submitters():
         av.reset_service()
 
 
+def test_submit_many_is_one_future_and_submit_unwraps_its_row(svc):
+    """The unit is the submit: `submit_many` hands back ONE future for
+    the list of verdicts, `submit` one for a bool, and the service
+    counts groups (`submits`) beside rows (`submitted`)."""
+    items, want = _triples(6, bad=(4,), tag=b"onefuture")
+    fut = svc.submit_many(items)
+    assert fut.result(timeout=10.0) == want
+    one = svc.submit(*items[4])                # invalid: fresh again
+    assert one.result(timeout=10.0) is False
+    assert svc.submit(*items[0]).result(timeout=1.0) is True   # a hit
+    assert svc.submit_many([]).result(timeout=1.0) == []
+    assert svc.submit_many(iter(items[:2])).result(timeout=1.0) == [True, True]
+    st = av.service_stats()
+    assert (st["submits"], st["submitted"]) == (2, 7), st
+    assert st["cache_hits"] == 3, st
+
+
+@pytest.mark.parametrize("n,cap,flushes", [(20, 8, 3), (16, 8, 2), (9, 8, 2)])
+def test_group_wider_than_a_flush_resolves_across_flushes(
+        monkeypatch, n, cap, flushes):
+    """A submit wider than MAX_COALESCE (ROADMAP Queue 2 (g): a commit
+    over 16,384 rows) is cut into segments: it resolves when its last
+    segment lands, with every verdict in its place."""
+    monkeypatch.setattr(av, "MAX_COALESCE", cap)
+    s = av.reset_service(linger_ms=1.0)
+    try:
+        bad = (0, cap - 1, cap, n - 1)
+        items, want = _triples(n, bad=bad, tag=b"wide%d" % n)
+        assert s.verify_many(items) == want
+        st = av.service_stats()
+        assert (st["submits"], st["submitted"]) == (1, n), st
+        assert st["flushes"] == flushes and st["coalesced_max"] == cap, st
+        assert st["queue_depth"] == 0, st
+        # only the valid rows were cached, whichever segment they rode
+        assert s.cache.get_many(av.VerifiedSigCache.keys(*zip(*items))) == want
+    finally:
+        av.reset_service()
+
+
+def test_two_submits_share_one_flush_and_get_their_own_rows(monkeypatch):
+    """Groups of independent submitters coalesce into one flush (here a
+    third is cut by the flush's end) and each future gets its own rows'
+    verdicts back, in its own order."""
+    monkeypatch.setattr(av, "MAX_COALESCE", 12)
+    s = av.reset_service(linger_ms=150.0)
+    try:
+        a, want_a = _triples(5, bad=(1,), tag=b"share-a")
+        b, want_b = _triples(4, bad=(0, 3), tag=b"share-b")
+        c, want_c = _triples(6, bad=(2, 5), tag=b"share-c")
+        futs = [s.submit_many(x) for x in (a, b, c)]   # inside one linger
+        assert [f.result(timeout=10.0) for f in futs] == [want_a, want_b, want_c]
+        st = av.service_stats()
+        assert (st["submits"], st["submitted"]) == (3, 15), st
+        assert (st["flushes"], st["coalesced_max"]) == (2, 12), st
+    finally:
+        av.reset_service()
+
+
+def test_many_submitters_under_a_short_switch_interval(monkeypatch):
+    """Stress: more submitting threads than cores, the interpreter
+    switching every 10 us, flushes small enough that groups are cut and
+    shared.  Every submit gets its own verdicts, and the row counters —
+    which a lost update under the service lock would break — add up."""
+    import sys
+
+    monkeypatch.setattr(av, "MAX_COALESCE", 10)
+    s = av.reset_service(linger_ms=0.2)
+    threads, rounds, per = 16, 5, 7
+    data = [[_triples(per, bad=((t + r) % per,), tag=b"stress-%d-%d" % (t, r))
+             for r in range(rounds)] for t in range(threads)]
+    wrong: list = []
+
+    def submitter(t):
+        for items, want in data[t]:
+            got = s.verify_many(items)
+            if got != want:
+                wrong.append((t, got, want))
+
+    was = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        ths = [threading.Thread(target=submitter, args=(t,)) for t in range(threads)]
+        for th in ths:
+            th.start()
+        for th in ths:
+            th.join(timeout=60.0)
+        assert not any(th.is_alive() for th in ths), "a submitter never returned"
+    finally:
+        sys.setswitchinterval(was)
+        st = av.service_stats()
+        av.reset_service()
+    assert not wrong, wrong[:3]
+    assert st["submits"] == threads * rounds, st
+    assert st["submitted"] == threads * rounds * per, st
+    assert st["queue_depth"] == 0 and st["coalesced_max"] <= 10, st
+    assert st["cache_size"] == threads * rounds * (per - 1), st
+
+
+def test_part_hits_part_fresh_part_invalid_keeps_input_order(svc):
+    """One submit mixing cached rows, fresh valid rows and invalid rows:
+    verdicts come back in input order, only the fresh rows are queued,
+    and only the valid fresh rows enter the cache."""
+    seen, _ = _triples(4, tag=b"mix-seen")
+    assert svc.verify_many(seen) == [True] * 4
+    fresh, want_fresh = _triples(5, bad=(1, 3), tag=b"mix-fresh")
+    items = [fresh[0], seen[0], fresh[1], seen[1], seen[2], fresh[2],
+             fresh[3], seen[3], fresh[4]]
+    want = [True, True, False, True, True, True, False, True, True]
+    st0 = av.service_stats()
+    e2e0 = dict(av.VERIFY_E2E_SECONDS.label_stats())
+    assert svc.verify_many(items) == want
+    st1 = av.service_stats()
+    e2e1 = av.VERIFY_E2E_SECONDS.label_stats()
+    assert st1["cache_hits"] - st0["cache_hits"] == 4
+    assert st1["submitted"] - st0["submitted"] == 5
+    assert st1["submits"] - st0["submits"] == 1
+    assert st1["cache_size"] - st0["cache_size"] == 3
+    assert e2e1[("cache",)][0] - e2e0.get(("cache",), (0, 0))[0] == 4
+    assert e2e1[("host",)][0] - e2e0[("host",)][0] == 5
+    assert svc.cache.get_many(av.VerifiedSigCache.keys(*zip(*fresh))) == want_fresh
+
+
+def test_a_row_that_still_raises_fails_its_own_submit_only(svc, monkeypatch):
+    """The catastrophic path: the batched host verify raised, and in the
+    per-row fallback one row raises again.  Its group's future carries
+    the batch's error (what `verify_many` raised to the caller anyway);
+    the other group of the same flush resolves."""
+    good, want = _triples(3, bad=(1,), tag=b"poison-good")
+    poisoned, _ = _triples(3, tag=b"poison-bad")
+    real = av._ed.verify_fast
+
+    def batch_raises(*_a):
+        raise RuntimeError("simulated batch failure")
+
+    def row_raises(pub, msg, sig):
+        if msg == poisoned[1][1]:
+            raise ValueError("simulated poisoned row")
+        return real(pub, msg, sig)
+
+    monkeypatch.setattr(av._ed, "verify_batch_fast", batch_raises)
+    monkeypatch.setattr(av._ed, "verify_fast", row_raises)
+    s = av.reset_service(linger_ms=100.0)
+    try:
+        f_good, f_bad = s.submit_many(good), s.submit_many(poisoned)
+        assert f_good.result(timeout=10.0) == want
+        with pytest.raises(RuntimeError, match="simulated batch failure"):
+            f_bad.result(timeout=10.0)
+        assert av.service_stats()["flushes"] == 1
+        # the valid rows of the group that resolved are cached; of the
+        # failed group, none
+        assert s.cache.get_many(av.VerifiedSigCache.keys(*zip(*good))) == want
+        assert not any(s.cache.get_many(
+            av.VerifiedSigCache.keys(*zip(*poisoned))))
+    finally:
+        av.reset_service()
+
+
+@pytest.mark.parametrize("start,end,want", [
+    (0, 9, [("a", 1, 4), ("b", 0, 2), ("c", 2, 6)]),      # whole: itself
+    (0, 3, [("a", 1, 4)]),
+    (2, 6, [("a", 3, 4), ("b", 0, 2), ("c", 2, 3)]),
+    (3, 5, [("b", 0, 2)]),
+    (6, 9, [("c", 3, 6)]),
+])
+def test_batch_cut_keeps_segments_and_rows_aligned(start, end, want):
+    def group(tag, n):
+        rows = [b"%s%d" % (tag, i) for i in range(n)]
+        return av._Group(rows, rows, rows, rows, None, [False] * n, False, 0.0)
+
+    groups = {"a": group(b"a", 4), "b": group(b"b", 2), "c": group(b"c", 6)}
+    batch = av._Batch([(groups["a"], 1, 4), (groups["b"], 0, 2),
+                       (groups["c"], 2, 6)])
+    assert batch.pubs == [b"a1", b"a2", b"a3", b"b0", b"b1",
+                          b"c2", b"c3", b"c4", b"c5"]
+    sub = batch.cut(start, end)
+    assert (sub is batch) == ((start, end) == (0, 9))
+    assert [(g.pubs[0][:1].decode(), a, b) for g, a, b in sub.segs] == want
+    assert sub.pubs == sub.msgs == sub.sigs == batch.pubs[start:end]
+    # one whole group is handed on as its own lists: no copy
+    assert av._Batch([(groups["c"], 0, 6)]).pubs is groups["c"].pubs
+
+
+@pytest.mark.parametrize("labels", [{}, {"path": "device"}])
+def test_observe_n_leaves_what_n_observes_leave(labels):
+    """Histogram.observe_n(v, n) == n x observe(v): count, sum and the
+    one bucket, for a plain and a labelled series, beside other values
+    and in the overflow bucket."""
+    from tendermint_tpu.utils.metrics import Histogram
+
+    def pair():
+        kw = {"label_names": tuple(labels)} if labels else {}
+        return [Histogram("h", "help", buckets=(0.001, 0.01, 0.1), **kw)
+                for _ in range(2)]
+
+    one, many = pair()
+    for value, n in ((0.004, 7), (0.01, 1), (0.25, 10_000), (0.0005, 3)):
+        for _ in range(n):
+            one.observe(value, **labels)
+        many.observe_n(value, n, **labels)
+    a, b = one.samples(), many.samples()
+    assert [x[:2] for x in a] == [x[:2] for x in b]
+    for (suffix, _lbl, want), (_, _, got) in zip(a, b):
+        # n additions of v and one of n*v round differently in the sum
+        assert got == (pytest.approx(want, rel=1e-9) if suffix == "_sum"
+                       else want), suffix
+    key = tuple(labels.values())
+    assert many.label_stats()[key][0] == 7 + 1 + 10_000 + 3
+
+
 def test_mixed_key_types(svc):
     pytest.importorskip("cryptography")
     from tendermint_tpu.crypto.secp256k1 import PrivKeySecp256k1
@@ -214,6 +423,74 @@ def test_device_pipelining_enqueues_chunks(monkeypatch):
         st = av.service_stats()
         assert st["device_batches"] >= 3, st  # 8 + 8 + 4 chunks
         assert st["pipelined_drains"] >= 3, st
+    finally:
+        av.reset_service()
+
+
+def test_device_flush_counts_rows_not_groups(monkeypatch):
+    """Two submits coalesced into one device flush: every pipeline
+    series still counts ROWS — the `path="device"` count of
+    verify_e2e_seconds (what chipbench's `resolved_on_device` reads) and
+    the queue-wait count grow by the rows, `submits` by the groups."""
+    ev = threading.Event()
+    ev.set()
+    monkeypatch.setattr(cbatch, "_DEVICE_READY", ev)
+    s = av.reset_service(linger_ms=100.0, cpu_threshold=8)
+    s._jax_bv._n_devices = 1
+    try:
+        a, want_a = _triples(5, bad=(2,), tag=b"rows-a")
+        b, want_b = _triples(3, bad=(0,), tag=b"rows-b")
+        e2e0 = av.VERIFY_E2E_SECONDS.label_stats().get(("device",), (0, 0.0))
+        qw0 = av.VERIFY_QUEUE_WAIT_SECONDS.label_stats()[()]
+        fa, fb = s.submit_many(a), s.submit_many(b)
+        assert (fa.result(timeout=120.0), fb.result(timeout=10.0)) == (want_a, want_b)
+        e2e1 = av.VERIFY_E2E_SECONDS.label_stats()[("device",)]
+        qw1 = av.VERIFY_QUEUE_WAIT_SECONDS.label_stats()[()]
+        assert e2e1[0] - e2e0[0] == 8
+        assert qw1[0] - qw0[0] == 8
+        # a count-weighted mean: each row waited at most the linger + slack
+        assert 0 < (qw1[1] - qw0[1]) / 8 < 5.0
+        st = av.service_stats()
+        assert (st["submits"], st["submitted"], st["flushes"]) == (2, 8, 1), st
+        assert (st["device_batches"], st["host_flushes"]) == (1, 0), st
+        assert s.last_route == ("device", "pipelined")
+    finally:
+        av.reset_service()
+
+
+def test_chunk_that_fails_to_enqueue_sends_only_the_rest_to_the_host(
+        monkeypatch):
+    """TM_TPU_CHUNK splits a flush; the second chunk's enqueue raises.
+    The first chunk keeps its device verdicts, the rest resolves on the
+    host, every row lands exactly once and in its place."""
+    from tendermint_tpu.ops import ed25519_jax as dev
+
+    ev = threading.Event()
+    ev.set()
+    monkeypatch.setattr(cbatch, "_DEVICE_READY", ev)
+    monkeypatch.setenv("TM_TPU_CHUNK", "8")
+    real, calls = dev._compiled, []
+
+    def second_call_fails(*key):
+        calls.append(key)
+        if len(calls) == 2:
+            raise RuntimeError("simulated enqueue failure")
+        return real(*key)
+
+    monkeypatch.setattr(dev, "_compiled", second_call_fails)
+    s = av.reset_service(linger_ms=1.0, cpu_threshold=8)
+    s._jax_bv._n_devices = 1
+    try:
+        items, want = _triples(16, bad=(3, 8, 15), tag=b"halfway")
+        e2e0 = dict(av.VERIFY_E2E_SECONDS.label_stats())
+        assert s.verify_many(items) == want
+        e2e1 = av.VERIFY_E2E_SECONDS.label_stats()
+        assert e2e1[("device",)][0] - e2e0.get(("device",), (0, 0))[0] == 8
+        assert e2e1[("host",)][0] - e2e0.get(("host",), (0, 0))[0] == 8
+        st = av.service_stats()
+        assert (st["device_errors"], st["device_batches"],
+                st["host_flushes"]) == (1, 1, 1), st
+        assert s.last_route == ("host", "device_error")
     finally:
         av.reset_service()
 

@@ -71,6 +71,22 @@ def test_record_parents_under_the_callers_open_span():
     assert by["outer"]["attrs"] == {"found": 3}
 
 
+def test_record_takes_an_explicit_parent_and_returns_its_id():
+    """The parts of a span that is itself recorded at its end: the
+    parent first (its id returned), the parts under it — whatever span
+    the calling thread holds open."""
+    assert trace.record("off", 1.0, 0.5) is None      # tracing off: no id
+    trace.set_enabled(True)
+    with trace.span("outer"):
+        whole = trace.record("whole", 1.0, 0.5)
+        trace.record("part", 1.1, 0.1, parent=whole, n=3)
+    by = {s["name"]: s for s in trace.spans()}
+    assert by["whole"]["id"] == whole
+    assert by["whole"]["parent"] == by["outer"]["id"]
+    assert by["part"]["parent"] == whole
+    assert by["part"]["attrs"] == {"n": 3}            # `parent` is no attr
+
+
 def test_span_nesting_and_parent_links():
     trace.set_enabled(True)
     with trace.span("outer", height=5):

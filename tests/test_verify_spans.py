@@ -15,8 +15,9 @@ from helpers import small_commit
 from tendermint_tpu.crypto import async_verify as av
 from tendermint_tpu.utils import trace
 
+SUBMIT_PARTS = {"verify.submit.keys", "verify.submit.probe"}
 CALLER = {"commit.select", "commit.sign_bytes", "commit.add", "commit.verify",
-          "verify.submit", "verify.wait", "commit.tally"}
+          "verify.submit", "verify.wait", "commit.tally"} | SUBMIT_PARTS
 WORKER = {"verify.coalesce", "verify.account", "verify.flush",
           "verify.host_verify", "verify.resolve"}
 
@@ -79,17 +80,26 @@ def test_one_call_emits_every_span_with_its_attrs_and_ties(mode, selected):
     for name in ("commit.sign_bytes", "commit.add", "commit.verify",
                  "verify.wait", "commit.tally"):
         assert by[name]["attrs"] == {"n": n}, name
-    assert by["verify.submit"]["attrs"] == {"n": n, "fresh": n}
+    assert by["verify.submit"]["attrs"] == {"n": n, "fresh": n, "hits": 0}
 
     # the caller's side: commit.* are roots on one thread, and what the
     # service records on that thread hangs under commit.verify
     tid = by["commit.verify"]["tid"]
     for name in CALLER:
         assert by[name]["tid"] == tid, name
-    for name in CALLER - {"verify.submit", "verify.wait"}:
+    for name in CALLER - {"verify.submit", "verify.wait"} - SUBMIT_PARTS:
         assert by[name]["parent"] is None, name
     assert by["verify.submit"]["parent"] == by["commit.verify"]["id"]
     assert by["verify.wait"]["parent"] == by["commit.verify"]["id"]
+    # the submit's bulk passes: one child each, inside it, never per row
+    sub = by["verify.submit"]
+    assert by["verify.submit.keys"]["attrs"] == {"n": n}
+    assert by["verify.submit.probe"]["attrs"] == {"n": n, "hits": 0}
+    for name in SUBMIT_PARTS:
+        assert by[name]["parent"] == sub["id"], name
+        assert sub["t0_ns"] <= by[name]["t0_ns"], name
+        assert (by[name]["t0_ns"] + by[name]["dur_ns"]
+                <= sub["t0_ns"] + sub["dur_ns"]), name
     # ... and cover the call's wall time.  The best of three calls: on a
     # busy machine the caller can be descheduled in the few microseconds
     # between two spans, which says nothing about what the spans cover
@@ -113,8 +123,10 @@ def test_one_call_emits_every_span_with_its_attrs_and_ties(mode, selected):
         assert by[name]["tid"] != tid and by[name]["parent"] is None, name
         assert by[name]["attrs"]["flush"] == flush, name
     assert by["verify.coalesce"]["attrs"]["oldest_submit_ns"] == by["verify.submit"]["t0_ns"]
-    assert by["verify.account"]["attrs"]["n"] == n
-    assert by["verify.resolve"]["attrs"] == {"n": n, "path": "host", "flush": flush}
+    assert by["verify.coalesce"]["attrs"]["groups"] == 1
+    assert by["verify.account"]["attrs"] == {"n": n, "groups": 1, "flush": flush}
+    assert by["verify.resolve"]["attrs"] == {"n": n, "groups": 1, "path": "host",
+                                             "flush": flush}
     assert by["verify.flush"]["attrs"]["path"] == "host"
 
     # in the order of a flush, each inside the caller's wait
