@@ -10,10 +10,10 @@ dropping the re-check is faster.  On honest rows the two agree; on the
 small-order rows every commit carries, the control refuses where ZIP-215
 accepts, so a run with the control in place reads `calls_wrong` > 0.
 
-`entry(data)` returns a callable with the entry point's contract (return,
-or raise ValueError naming the first failing row), built from the
-reference's commit rules over the control's row verdicts, at the cell's
-own size: every consulted row of every call is verified.
+`bound(entry, data)` returns a callable in the place of the entry's
+`bind(data)`: it verifies every consulted row of the item with the strict
+verifier (the cell's own size) and answers what the entry's rule gives
+from those verdicts.
 """
 
 from __future__ import annotations
@@ -22,8 +22,6 @@ import functools
 
 from cryptography.exceptions import InvalidSignature
 from cryptography.hazmat.primitives.asymmetric.ed25519 import Ed25519PublicKey
-
-from chipbench.reference.commit_rules import consulted_rows
 
 
 @functools.lru_cache(maxsize=None)
@@ -45,13 +43,9 @@ def strict_verify(pub: bytes, msg: bytes, sig: bytes) -> bool:
         return False
 
 
-def entry(d):
-    n = consulted_rows(d.mode, d.powers)
-
-    def call(pc) -> None:
-        for i in range(n):
-            if not strict_verify(*pc.row(d.pubs, i)):
-                raise ValueError(f"wrong signature (#{i}) in commit for "
-                                 f"height {pc.height}")
+def bound(entry, d):
+    def call(item) -> tuple:
+        oks = [strict_verify(*item.row(i)) for i in range(item.n_rows)]
+        return entry.expected(d, item, lambda i: oks[i])
 
     return call

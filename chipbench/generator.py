@@ -1,10 +1,12 @@
 """The one traffic generator: it reads a mix's parameters
-(chipbench/traffic/<name>.json) and drives the cell's entry point.
+(chipbench/traffic/<name>.json) and drives the cell's entry point, bound
+by its module (chipbench/entries/<entry>.py `bind`), over a pool of items:
+whatever one call takes, each knowing its consulted rows (`n_rows`).
 
     generator   "closed_loop": each caller sends its next call when its
                 last returned (a slow system receives less load)
     callers     threads calling; the pool is dealt out among them so that
-                no two ever walk the same commit
+                no two ever walk the same item
     think_ms    a caller's pause between a return and its next call
     pool        the pool-size rule (see data.pool_size)
 
@@ -26,17 +28,20 @@ _WRONG_SIG = re.compile(r"wrong signature \(#(\d+)\)")
 
 @dataclass
 class Call:
-    commit: int          # index into the pool
+    item: int            # index into the pool
     t_start: float       # time.perf_counter()
     seconds: float
     outcome: tuple       # ("accept", None) | ("wrong_signature", row) | ...
+    rows: int            # rows the call consulted (the item's `n_rows`)
 
 
-def outcome_of(exc: BaseException | None) -> tuple:
-    """What a call said: it returned (accept) or raised ValueError naming
-    the failing row; anything else is an error of the call itself."""
+def outcome_of(exc: BaseException | None, said=None) -> tuple:
+    """What a call said: it returned None (accept) or raised ValueError
+    naming the failing row; anything else it raised is an error of the
+    call itself.  A bound call whose program answers in another shape
+    returns the outcome as a tuple of its own (`said`)."""
     if exc is None:
-        return ("accept", None)
+        return ("accept", None) if said is None else said
     if isinstance(exc, ValueError):
         m = _WRONG_SIG.search(str(exc))
         if m:
@@ -44,6 +49,17 @@ def outcome_of(exc: BaseException | None) -> tuple:
         if "insufficient voting power" in str(exc):
             return ("insufficient_power", None)
     return ("error", f"{type(exc).__name__}: {exc}"[:200])
+
+
+def timed(call, idx: int, item) -> Call:
+    """One call of the bound entry point on `item`, timed and its answer read."""
+    said = exc = None
+    t = time.perf_counter()
+    try:
+        said = call(item)
+    except Exception as e:  # noqa: BLE001 — the answer, or the call's error
+        exc = e
+    return Call(idx, t, time.perf_counter() - t, outcome_of(exc, said), item.n_rows)
 
 
 def _caller(k: int, traffic: dict, pool: list, call, t_end: float,
@@ -54,16 +70,10 @@ def _caller(k: int, traffic: dict, pool: list, call, t_end: float,
     while True:
         if between is not None:
             between(len(out))
-        t = time.perf_counter()
-        if t >= t_end and i >= min_calls:
+        if time.perf_counter() >= t_end and i >= min_calls:
             return
         idx = mine[i % len(mine)]
-        try:
-            call(pool[idx])
-            exc = None
-        except Exception as e:  # noqa: BLE001 — the answer, or the call's error
-            exc = e
-        out.append(Call(idx, t, time.perf_counter() - t, outcome_of(exc)))
+        out.append(timed(call, idx, pool[idx]))
         i += 1
         if think:
             time.sleep(think)
@@ -71,7 +81,7 @@ def _caller(k: int, traffic: dict, pool: list, call, t_end: float,
 
 def run_window(traffic: dict, pool: list, call, seconds: float,
                between=None, min_calls: int = 0) -> tuple[list[Call], float, float]:
-    """Drive `call(pool_commit)`; returns (calls in start order, t0, t1)
+    """Drive `call(item)`; returns (calls in start order, t0, t1)
     on the perf_counter clock.  `between(n_done)` runs on caller 0 between
     its calls (the traced run starts and stops the profiler there).
     `min_calls` keeps each caller going past `seconds` until it made that

@@ -2,10 +2,11 @@
 
 A cell is found by name in the manifest's `workloads`; its configuration
 by name in `configs` (-> the configuration's `file`); its traffic mix at
-`chipbench/traffic/<traffic>.json`; a per-layer metric's reader at
-`chipbench/metrics/<metric>.py`.  Adding a cell, a configuration, a mix or
-a per-layer metric is adding files and manifest entries; no file that is
-there needs an edit.
+`chipbench/traffic/<traffic>.json`; the configuration's entry point at
+`chipbench/entries/<entry>.py`; a per-layer metric's reader at
+`chipbench/metrics/<metric>.py`.  Adding a cell, a configuration, a mix,
+an entry point or a per-layer metric is adding files and manifest
+entries; no file that is there needs an edit.
 """
 
 from __future__ import annotations
@@ -63,15 +64,32 @@ def per_layer(manifest: dict, cell_name: str) -> list[dict]:
     return [m for m in manifest["per_layer"] if _applies(m, cell_name)]
 
 
-def reader(metric_name: str):
-    """The `read(obs)` function of chipbench/metrics/<metric_name>.py."""
-    path = os.path.join(HERE, "metrics", metric_name + ".py")
+def _module(kind: str, name: str, what: str):
+    """The module of chipbench/<kind>/<name>.py, loaded by its path."""
+    path = os.path.join(HERE, kind, name + ".py")
     if not os.path.exists(path):
-        raise ManifestError(f"per-layer metric {metric_name!r} has no reader "
-                            f"at {os.path.relpath(path, ROOT)}")
+        raise ManifestError(f"{what} {name!r} has no file at "
+                            f"{os.path.relpath(path, ROOT)}")
     spec = importlib.util.spec_from_file_location(
-        "chipbench.metrics." + metric_name.replace(".", "_").replace("-", "_"),
-        path)
+        f"chipbench.{kind}." + name.replace(".", "_").replace("-", "_"), path)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
-    return module.read
+    return module
+
+
+def reader(metric_name: str):
+    """The `read(obs)` function of chipbench/metrics/<metric_name>.py."""
+    return _module("metrics", metric_name, "per-layer metric").read
+
+
+ENTRY_FUNCTIONS = ("build", "bind", "expected", "implied", "path")
+
+
+def entry(entry_name: str):
+    """The module chipbench/entries/<entry_name>.py: a configuration's
+    `entry`, with the five functions an entry point brings."""
+    module = _module("entries", entry_name, "entry point")
+    missing = [f for f in ENTRY_FUNCTIONS if not callable(getattr(module, f, None))]
+    if missing:
+        raise ManifestError(f"entry point {entry_name!r} lacks {missing}")
+    return module

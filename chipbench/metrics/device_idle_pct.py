@@ -1,5 +1,7 @@
 """Device: share of the traced slice in which no operation ran on the
-chip."""
+chip.  On several chips: 1 - the mean of the chips' busy time over the
+slice (a chip of the cell that ran nothing in the slice counts as idle
+throughout)."""
 
 
 def read(obs):

@@ -1,6 +1,8 @@
 """Readback / resolve: the `verify.device_execute` span (enqueue to
 readback, host clock) minus the program's device time, means over the
-traced slice: transfer in, launch, readback."""
+traced slice: transfer in, launch, readback.  On several chips the
+program's time is the slowest chip's, and what is left holds one
+`device_put` per chip and the fan-in of the verdicts."""
 
 
 def read(obs):

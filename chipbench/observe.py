@@ -11,9 +11,8 @@ from dataclasses import dataclass
 @dataclass
 class Observation:
     cell: dict
-    device: dict
-    rows_per_call: int
-    calls: list                 # generator.Call, in start order
+    device: dict                # as JAX reports it; `count` = the cell's chips
+    calls: list                 # generator.Call, in start order; each knows its rows
     window_s: float
     before: dict                # system.counters() at the window's ends
     after: dict
@@ -37,14 +36,19 @@ class Observation:
         return [s for s in self.spans if s["name"] == name
                 and s["t0_ns"] >= a and s["t0_ns"] + s["dur_ns"] <= b]
 
+    def rows(self) -> int:
+        """Rows the window's calls consulted, all of them."""
+        return sum(c.rows for c in self.calls)
+
     def kernel_s_per_sig(self) -> float | None:
         """Device seconds of the whole verify program per real (unpadded)
-        signature: mean program event of the traced slice over the mean
+        signature: mean program time of the traced slice's flushes (on
+        several chips a flush's time is its slowest chip's) over the mean
         flush size of the `verify.device_execute` spans in it."""
         if self.trace is None:
             return None
         execs = self.spans_in_slice("verify.device_execute")
         rows = (sum(s["attrs"]["n"] for s in execs) / len(execs) if execs
-                else float(self.rows_per_call))
+                else self.rows() / len(self.calls))
         ev = self.trace.program_events
         return sum(ev) / len(ev) / rows

@@ -38,3 +38,14 @@ def expected_outcome(mode: str, powers: list[int], suspects, row_ok) -> tuple:
     if sum(powers[:n]) <= needed:
         return ("insufficient_power", None)
     return ("accept", None)
+
+
+def implied(outcome: tuple, row: int) -> bool | None:
+    """What a call's answer says of a consulted row: valid, invalid, or
+    nothing (rows after the one it refused)."""
+    kind, at = outcome
+    if kind == "accept":
+        return True
+    if kind == "wrong_signature":
+        return True if row < at else (False if row == at else None)
+    return None

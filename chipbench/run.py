@@ -3,12 +3,14 @@
 
     python3 chipbench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
-One process.  It finds the chip (none: exit 3, a line on stderr, no
-result), builds the cell's data from --seed, starts the verifier and the
-service as `Node.start()` does, waits for the device, warms the cell's own
-rung, measures for --seconds, checks every call against the plain
-reference, prints one JSON object as the last line of stdout and leaves
-through os._exit so that no daemon thread outlives it holding the chip.
+One process.  It finds the chips the cell asks for (fewer: exit 3, a line
+on stderr, no result), builds the cell's data from --seed through the
+configuration's entry point (chipbench/entries/<entry>.py), starts the
+verifier and the service as `Node.start()` does, waits for the device,
+warms the cell's own rung through the bound entry point, measures for
+--seconds, checks every call against the plain reference, prints one JSON
+object as the last line of stdout and leaves through os._exit so that no
+daemon thread outlives it holding the chip.
 
 Exit codes: 0 a result was printed (read its `correct`); 2 usage or
 manifest; 3 no chip; 4 set-up failed; 5 the window failed; 6 the trace
@@ -37,7 +39,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from chipbench import correct, data, generator, manifest, tracing  # noqa: E402
+from chipbench import correct, generator, manifest, tracing  # noqa: E402
 from chipbench.observe import Observation  # noqa: E402
 
 EXIT_USAGE, EXIT_NO_CHIP, EXIT_SETUP, EXIT_WINDOW, EXIT_TRACE = 2, 3, 4, 5, 6
@@ -102,11 +104,11 @@ class StallWatch:
                                         daemon=True)
         self._thread.start()
 
-    def __call__(self, pc):
+    def __call__(self, item):
         me = threading.get_ident()
         self._started[me] = time.perf_counter()
         try:
-            return self._call(pc)
+            return self._call(item)
         finally:
             self._started.pop(me, None)
 
@@ -131,6 +133,7 @@ class Bench:
         try:
             self.manifest = manifest.load()
             self.cell = manifest.cell(self.manifest, workload)
+            self.entry = manifest.entry(self.cell["config_file"]["entry"])
         except (OSError, ValueError, KeyError, manifest.ManifestError) as e:
             raise StageFailed(EXIT_USAGE, "manifest", f"{type(e).__name__}: {e}")
         self.rehearse = rehearse
@@ -166,14 +169,13 @@ class Bench:
 
     def build(self, seed: int):
         t0 = time.monotonic()
-        d = data.build(seed, self.cfg["name"], {"validators": self.sizes["validators"]},
-                       self.cfg["adversarial"], self.cfg["mode"],
-                       self.system["cache_capacity"], self.traffic["pool"],
-                       self.traffic["warmup_commits"])
-        say(f"data from seed {seed}: {len(d.pubs)} validators, {d.consulted} "
-            f"rows consulted per call, pool of {len(d.pool)} commits "
-            f"({len(d.pool) * d.consulted} signatures against a cache of "
-            f"{self.system['cache_capacity']}), built in {time.monotonic() - t0:.1f}s")
+        d = self.entry.build(seed, self.cfg, self.sizes, self.system["cache_capacity"],
+                             self.traffic["pool"], self.traffic["warmup_commits"])
+        rows = [item.n_rows for item in d.pool]
+        say(f"data from seed {seed}: pool of {len(rows)} items, {min(rows)} to "
+            f"{max(rows)} rows consulted per call ({sum(rows)} signatures against "
+            f"a cache of {self.system['cache_capacity']}), {len(d.warmup)} "
+            f"warm-up items, built in {time.monotonic() - t0:.1f}s")
         return d
 
     def ready(self, seed: int) -> None:
@@ -186,40 +188,27 @@ class Bench:
         self.system.update(info)
         say(f"device ready: {json.dumps(info)}")
 
-    def want_route(self) -> tuple:
-        return ("device", "pipelined") if self.device["count"] == 1 else (
-            "device", "mesh_sharded")
-
-    def call(self, d):
-        from chipbench import system
-
-        entry = system.entry_point(d.vset, self.cfg["entry"])
-        return lambda pc: entry(data.CHAIN_ID, pc.block_id, pc.height, pc.commit)
-
     def warm(self, d) -> None:
-        """The cell's own rung and no other: warm-up commits through the
-        entry point itself, each required to resolve on the device."""
+        """The cell's own rung and no other: the warm-up items through the
+        bound entry point itself, each required to be accepted along the
+        entry's path (every path number 0; the compiles are what a warm-up
+        is for and are not counted)."""
         from chipbench import system
 
-        call = self.call(d)
-        for pc in d.warmup:
+        call = self.entry.bind(d)
+        for item in d.warmup:
             before = system.counters()
-            exc = None
-            try:
-                call(pc)
-            except Exception as e:  # noqa: BLE001
-                exc = e
+            made = generator.timed(call, -1, item)
             after = system.counters()
             route = system.last_route()
-            grew = after["resolved_on_device"] - before["resolved_on_device"]
-            if (generator.outcome_of(exc) != ("accept", None)
-                    or tuple(route or ()) != self.want_route()
-                    or grew != d.consulted or after["device_errors"]):
+            numbers = self.entry.path(before, after, [made], 0, route,
+                                      self.device["count"])
+            if made.outcome != ("accept", None) or any(numbers.values()):
                 raise StageFailed(
                     EXIT_SETUP, "warm-up",
-                    f"warm-up call did not resolve {d.consulted} rows on the "
-                    f"device: outcome {generator.outcome_of(exc)}, route {route}, "
-                    f"resolved_on_device +{grew}, counters {after}, threshold "
+                    f"warm-up call of {item.n_rows} rows did not resolve along "
+                    f"the entry's path: outcome {made.outcome}, path numbers "
+                    f"{numbers}, route {route}, counters {after}, threshold "
                     f"{self.system.get('threshold')}")
         programs = [{"rung": e["rung"], "impl": e["impl"], "source": e["source"],
                      "first_call_s": e["seconds"]} for e in system.compile_events()]
@@ -236,10 +225,10 @@ class Bench:
             tr = self.traffic["trace"]
             sl = tracing.Slice(os.path.join(OUT_DIR, f"{self.cell['name']}.trace"),
                                tr["lead_s"], tr["slice_s"], tr["min_flushes"],
-                               tr["max_flushes"])
+                               tr["max_flushes"], chips=self.device["count"])
         before = system.counters()
         pauses = GcPauses()
-        watched = StallWatch(self.call(d))
+        watched = StallWatch(self.entry.bind(d))
         try:
             calls, t0, t1 = generator.run_window(
                 self.traffic, d.pool, watched, seconds,
@@ -253,24 +242,25 @@ class Bench:
         gc_seen = pauses.stop()
         after = system.counters()
         route = system.last_route()
+        shards = system.last_shard_layout()
         peak = system.memory_peak_bytes()
         if not calls:
             raise StageFailed(EXIT_WINDOW, "window", "no call was made")
         compiles = self.watch.between(t0, t1)
         obs = Observation(
-            cell=self.cell, device=self.device, rows_per_call=d.consulted,
-            calls=calls, window_s=t1 - t0, before=before, after=after,
-            compiles_in_window=compiles, spans=[], trace=None, slice=None)
+            cell=self.cell, device=self.device, calls=calls, window_s=t1 - t0,
+            before=before, after=after, compiles_in_window=compiles, spans=[],
+            trace=None, slice=None)
         if trace:
             obs.spans = system.spans_since(int(t0 * 1e9))
             obs.trace, obs.slice = self._reduce(sl, obs, calls)
-        numbers = correct.check_calls(d, calls, seed)
-        numbers.update(correct.check_path(
-            before, after, len(calls) * d.consulted, compiles, route,
-            self.want_route()))
+        numbers = correct.check_calls(self.entry, d, calls, seed)
+        numbers.update(self.entry.path(before, after, calls, compiles, route,
+                                       self.device["count"]))
         ok, compared = correct.compared(numbers)
         return {"obs": obs, "ok": ok, "compared": compared,
                 "detail": numbers["detail"], "peak": peak, "t0": t0, "gc": gc_seen,
+                "route": route, "shards": shards,
                 "failed": numbers["calls_wrong"]}
 
     def _reduce(self, sl, obs, calls):
@@ -289,10 +279,10 @@ class Bench:
             raise StageFailed(EXIT_TRACE, "trace", str(e))
         except Exception as e:  # noqa: BLE001 — a trace the reader chokes on
             raise StageFailed(EXIT_TRACE, "trace", f"{type(e).__name__}: {e}")
-        if len(red.program_events) < self.traffic["trace"]["min_flushes"]:
+        if len(red.program_events) < sl.min_flushes:
             raise StageFailed(
-                EXIT_TRACE, "trace", f"only {len(red.program_events)} whole "
-                f"program events in the slice, {self.traffic['trace']['min_flushes']} needed")
+                EXIT_TRACE, "trace", f"only {len(red.program_events)} whole flushes of "
+                f"the program in the slice, {sl.min_flushes} needed")
         if os.environ.get("CHIPBENCH_KEEP_TRACE"):
             # a builder's aid: an excerpt small enough to keep as a test's
             # recorded trace; the raw trace stays for a look by hand
@@ -307,7 +297,7 @@ class Bench:
         obs = w["obs"]
         lat = [c.seconds * 1e3 for c in obs.calls]
         values = {
-            "sigs_per_s": len(obs.calls) * obs.rows_per_call / obs.window_s,
+            "sigs_per_s": obs.rows() / obs.window_s,
             "verify_p50_ms": percentile(lat, 0.50),
             "verify_p95_ms": percentile(lat, 0.95),
             "setup_s": setup_s,
@@ -388,12 +378,13 @@ def main() -> int:
     slowest = sorted(obs.calls, key=lambda c: -c.seconds)[:8]
     print("summary: " + json.dumps({
         "workload": args.workload, "seed": args.seed, "calls": len(obs.calls),
-        "window_s": obs.window_s, "rows_per_call": obs.rows_per_call,
+        "window_s": obs.window_s, "rows_per_call": obs.rows() / len(obs.calls),
         "setup_s": setup_s, "system": bench.system, "check_detail": w["detail"],
+        "last_route": w["route"], "last_shard_layout": w["shards"],
         # diagnostics, not metrics: where in the window the slowest calls
         # lay, and what the collector did meanwhile
         "slowest_calls": [{"at_s": round(c.t_start - w["t0"], 3),
-                           "ms": round(c.seconds * 1e3, 3), "commit": c.commit,
+                           "ms": round(c.seconds * 1e3, 3), "item": c.item,
                            "outcome": c.outcome[0]} for c in slowest],
         "sum_of_calls_s": sum(c.seconds for c in obs.calls),
         "gc_in_window": w["gc"],

@@ -55,7 +55,7 @@ def start(trace_on: bool) -> dict:
     cache = jaxcache.enable(jax)
     host_prep.load_lib()
     trace.set_ring_size(1 << 18)
-    trace.set_enabled(trace_on)
+    trace.set_enabled(trace_on)   # `tracing(on)` changes it between windows
     bv = cbatch.new_batch_verifier()
     if not isinstance(bv, cbatch.JAXBatchVerifier):
         raise SetupFailed(f"batch verifier is {type(bv).__name__}, not the jax backend")
@@ -107,6 +107,13 @@ def wait_ready(seed: int) -> dict:
                 "reason", "platform", "device_kind")}}
 
 
+def tracing(on: bool) -> None:
+    """The program's spans on or off (one branch a site when off)."""
+    from tendermint_tpu.utils import trace
+
+    trace.set_enabled(on)
+
+
 def counters() -> dict:
     """Read together: `device_batches` counts enqueues, so alone it
     proves nothing; `resolved_on_device` is the path="device" count of
@@ -140,6 +147,13 @@ def last_route():
     return av.get_service().last_route
 
 
+def last_shard_layout():
+    """((device id, padded rows), ...) of the last sharded flush, or None."""
+    from tendermint_tpu.crypto import async_verify as av
+
+    return av.get_service().last_shard_layout
+
+
 def compile_events() -> list[dict]:
     from tendermint_tpu.utils import devmon
 
@@ -150,14 +164,6 @@ def spans_since(t0_ns: int) -> list[dict]:
     from tendermint_tpu.utils import trace
 
     return [s for s in trace.spans() if s["t0_ns"] >= t0_ns]
-
-
-def entry_point(vset, name: str):
-    """The bound entry point the window drives, by the configuration's
-    `entry`: ValidatorSet.verify_commit or .verify_commit_light."""
-    if name not in ("verify_commit", "verify_commit_light"):
-        raise SetupFailed(f"unknown entry point {name!r}")
-    return getattr(vset, name)
 
 
 def memory_peak_bytes() -> int:

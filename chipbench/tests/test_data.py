@@ -43,7 +43,8 @@ def test_bad_rows_lie_in_their_ranges_and_light_consults_two_thirds():
 def test_rows_are_what_the_commit_carries():
     d = _build(6)
     pc = d.pool[0]
-    pub, msg, sig = pc.row(d.pubs, 3)
+    pub, msg, sig = pc.row(3)
+    assert pc.n_rows == d.consulted == 48
     cs = pc.commit.signatures[3]
     assert sig == cs.signature and pub == d.vset.validators[3].pub_key.bytes_()
     assert msg == pc.commit.vote_sign_bytes(data.CHAIN_ID, 3)

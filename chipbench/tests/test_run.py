@@ -1,9 +1,10 @@
 """The rest of a run, driven without the look for a chip (XLA-CPU, the
-configurations' rehearse sizes): the result line's shape, the control,
-and `correct` coming out false once for each fault a one-chip cell can
-have — half of the batch left out, and an answer altered where it is
-produced.  (A state left unchanged and an exchange between chips do not
-exist on this path.)
+configurations' rehearse sizes) through the entry points of
+chipbench/entries/: the result line's shape, the control, and `correct`
+coming out false once for each fault a cell can have — half of the batch
+left out, and an answer altered where it is produced.  (A state left
+unchanged does not exist on this path; the verdicts of a sharded flush
+are gathered by the same `_resolve` the faults are planted in.)
 
 One Bench is started for the whole file: the first warm-up traces and
 compiles the rung-96 program on XLA-CPU (~2 min, then cached in
@@ -22,7 +23,11 @@ import pytest
 from chipbench import control, correct, generator, manifest
 from chipbench import run as runner
 
-CELLS = [w["name"] for w in manifest.load()["workloads"]]
+# the first cell of each entry point: on XLA-CPU a four-chip cell
+# rehearses as the one-chip cell of its entry point does
+_M = manifest.load()
+CELLS = list({manifest.cell(_M, w["name"])["config_file"]["entry"]: w["name"]
+              for w in reversed(_M["workloads"])}.values())
 
 
 @pytest.fixture(scope="module")
@@ -128,9 +133,9 @@ def test_fault_flush_resolved_on_the_host(benches, cell, monkeypatch):
 def test_control_comes_out_not_correct(benches, cell, seed):
     b = benches[cell]
     d = b.build(seed)
-    calls, _, _ = generator.run_window(b.traffic, d.pool, control.entry(d), 0.0,
+    calls, _, _ = generator.run_window(b.traffic, d.pool, control.bound(b.entry, d), 0.0,
                                        min_calls=len(d.pool))
-    ok, compared = correct.compared(correct.check_calls(d, calls, seed))
+    ok, compared = correct.compared(correct.check_calls(b.entry, d, calls, seed))
     assert not ok and compared["calls_wrong"]["value"] >= 1
 
 

@@ -37,7 +37,7 @@ def _call(t0, flush, select=2.0, add=8.0, resolve_path="device"):
 
 
 def _obs(spans, trace=None):
-    return Observation(cell={}, device={}, rows_per_call=10, calls=[], window_s=1.0,
+    return Observation(cell={}, device={}, calls=[], window_s=1.0,
                        before={}, after={}, compiles_in_window=0, spans=spans,
                        trace=trace, slice=None)
 

@@ -27,14 +27,21 @@ class TraceUnreadable(Exception):
 
 class Slice:
     """Starts the profiler `lead_s` into the window and stops it once
-    `slice_s` have passed or `max_flushes` calls were made in it (a program
-    execution is ~150,000 trace events, whatever its rung), and never
-    before `min_flushes`; driven from the caller's thread between calls."""
+    `slice_s` have passed or `max_flushes` program executions were made in
+    it, and never before `min_flushes` executions; driven from the caller's
+    thread between calls.  The traffic mix counts executions because they
+    are what a trace is made of (~150,000 events each, whatever the rung;
+    on four chips stopping the profiler costs ~34 s and 2 s an execution,
+    reading it 2 s an execution): a flush is one execution on each of the
+    cell's `chips`, so the counts are turned into flushes here, as many as
+    keep the executions between the two (3 to 6 on one chip, 1 on four)."""
 
     def __init__(self, out_dir: str, lead_s: float, slice_s: float,
-                 min_flushes: int, max_flushes: int):
+                 min_flushes: int, max_flushes: int, chips: int = 1):
         self.out_dir, self.lead_s = out_dir, lead_s
-        self.slice_s, self.min_flushes, self.max_flushes = slice_s, min_flushes, max_flushes
+        self.slice_s = slice_s
+        self.min_flushes = -(-min_flushes // chips)
+        self.max_flushes = max(self.min_flushes, max_flushes // chips)
         self.t_window = None
         self.t_on = self.t_off = None       # perf_counter, just inside the slice
         self.sync_perf_ns = None
@@ -149,9 +156,12 @@ def _union(intervals: list[tuple[float, float]]) -> list[tuple[float, float]]:
 @dataclass
 class Reduced:
     window_s: float
-    busy_s: float                    # mean over the device planes used
-    program_events: list[float]      # device seconds of each whole verify program in the slice
-    device_ops: list[list]           # [[name, seconds], ...] top 10
+    busy_s: float                    # mean over the cell's chips
+    program_events: list[float]      # per whole flush of the slice: device seconds of its verify
+                                     # program, on several chips those of the slowest chip
+    device_ops: list[list]           # [[name, seconds], ...] top 10, seconds summed over the chips
+    flush_programs: list[list[float]] = field(default_factory=list)  # per whole flush: each
+                                     # chip's program event, seconds
     gaps: list[tuple[float, float]] = field(default_factory=list)  # idle gaps, perf_counter seconds
     idle_gaps: list[list] = field(default_factory=list)            # [[label, seconds], ...] top 10
 
@@ -160,8 +170,11 @@ def reduce(events: dict, t_on: float, t_off: float, sync_perf_ns: int,
            spans: list[dict] | None = None, chips: int = 1) -> Reduced:
     """`t_on`/`t_off`: the slice on the perf_counter clock (seconds);
     `sync_perf_ns`: perf_counter_ns inside the sync annotation.  Device
-    events are clipped to the slice; a program event counts only when it
-    lies wholly inside it."""
+    events are clipped to the slice.  Every device plane is walked: busy
+    time is the mean over the `chips` of the cell, the idle gaps are the
+    first plane's, and the program events of the planes are gathered into
+    flushes (`_flushes`); a flush counts only when every chip's event of
+    it lies wholly inside the slice."""
     def found() -> str:
         inv = events.get("inventory") or [(p, list(ls)) for p, ls in events["planes"].items()]
         names = sorted({e[0] for ls in events["planes"].values()
@@ -183,7 +196,7 @@ def reduce(events: dict, t_on: float, t_off: float, sync_perf_ns: int,
 
     busy_total = 0.0
     used = 0
-    programs: list[float] = []
+    programs: list[tuple] = []       # (start, end, plane, whole, seconds), perf_counter clock
     op_seconds: dict[str, float] = {}
     gaps: list[tuple[float, float]] = []
     for plane, lines in sorted(device_planes.items()):
@@ -207,20 +220,46 @@ def reduce(events: dict, t_on: float, t_off: float, sync_perf_ns: int,
             gaps = [(edges[i], edges[i + 1]) for i in range(0, len(edges), 2)
                     if edges[i + 1] > edges[i]]
         for name, start, dur in (mod_line or []):
-            if PROGRAM_MARK in name and perf(start) >= t_on and perf(start + dur) <= t_off:
-                programs.append(dur / 1e9)
+            a, b = perf(start), perf(start + dur)
+            if PROGRAM_MARK in name and b > t_on and a < t_off:
+                programs.append((a, b, plane, a >= t_on and b <= t_off, dur / 1e9))
+    flushes = _flushes(programs)
     if not used:
         raise TraceUnreadable("no device operation inside the traced slice; " + found())
-    if not programs:
+    if not flushes:
         raise TraceUnreadable(f"no whole {PROGRAM_MARK!r} program event inside "
                               "the traced slice; " + found())
     red = Reduced(window_s=t_off - t_on, busy_s=busy_total / max(used, chips),
-                  program_events=programs,
+                  program_events=[max(f) for f in flushes],
                   device_ops=[[n, s] for n, s in sorted(
                       op_seconds.items(), key=lambda kv: -kv[1])[:10]],
-                  gaps=gaps)
+                  flush_programs=flushes, gaps=gaps)
     red.idle_gaps = label_gaps(gaps, spans or [])
     return red
+
+
+def _flushes(programs: list[tuple]) -> list[list[float]]:
+    """Program events (start, end, plane, whole, seconds) of all planes ->
+    per flush the seconds of each chip's event.  One flush runs the
+    program once on each chip at the same time, and flushes follow one
+    another: an event belongs to the open flush if it starts before that
+    flush's last end on a plane the flush does not hold yet.  A flush of
+    which any event is cut by the slice's edge is left out."""
+    out: list[list[float]] = []
+    planes: set = set()
+    end, whole = float("-inf"), True
+    for a, b, plane, inside, seconds in sorted(programs):
+        if a >= end or plane in planes:
+            if not whole:
+                out.pop()
+            out.append([])
+            planes, end, whole = set(), b, True
+        out[-1].append(seconds)
+        planes.add(plane)
+        end, whole = max(end, b), whole and inside
+    if out and not whole:
+        out.pop()
+    return out
 
 
 def label_gaps(gaps: list[tuple[float, float]], spans: list[dict]) -> list[list]:
