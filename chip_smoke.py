@@ -75,7 +75,11 @@ COMPILE_RESERVE_S = 520.0
 # blocks are ONE request pipeline of the pool (MAX_PENDING_PER_PEER), so
 # the catch-up is one or two windows — one or two cold compiles.  Every
 # further distinct window size is one more program at 190-340 s each
-# (measured on a v5e), and a 192-block catch-up touches a dozen.
+# (measured on a v5e).  Since the reactor cuts a step to one flush
+# (blocksync/reactor.py verify_window), a longer catch-up runs the top
+# rung, 16,384, step after step — one program more, however long the
+# chain — but its last, short windows near the tip still meet a rung
+# each, which a smoke that must end inside the wall limit cannot pay.
 FULL = {"commit_validators": 10_000, "small_validators": 128,
         "light_validators": 1_000, "chain_validators": 200,
         "chain_blocks": 20, "valid_sample": 512, "corrupt_rows": 64}

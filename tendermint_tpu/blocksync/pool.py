@@ -9,8 +9,9 @@ reactor owns the send loop — same pipelining, two tasks total.
 
 The pool's output is not one block at a time (pool.go:194 PeekTwoBlocks)
 but a *verifiable window*: the longest run of consecutive downloaded
-blocks, which the reactor verifies as ONE batched device call
-(types.batch_verify_commits) — the TPU-shaped replacement for the
+blocks, of which the reactor verifies, per step, as many leading blocks
+as one flush holds as ONE batched device call (reactor.verify_window →
+types.batch_verify_commits) — the TPU-shaped replacement for the
 reference's per-block VerifyCommitLight (reactor.go:517).
 """
 

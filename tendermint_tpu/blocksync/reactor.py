@@ -7,12 +7,17 @@ SwitchToConsensus handoff (:566 via consensus/reactor.go:106).
 
 TPU redesign of the hot loop: the reference verifies one block pair per
 10ms tick (VerifyCommitLight, one sequential sig loop per block).  Here
-the whole downloaded window of consecutive blocks is verified as ONE
-batched device call — every LastCommit in the window full-verified plus
-one light pair-check for the newest block — then the window is applied
-with signature checks already done (strictly ≥ the reference's checks:
-it light-verifies each pair AND full-verifies each commit one height
-later; we full-verify each commit exactly once, in the batch).
+a step (`verify_window`) verifies as many leading blocks of the downloaded
+window as ONE flush of the verify service holds — every LastCommit of the
+blocks it takes full-verified plus one light pair-check for the newest of
+them — as one batched device call, then applies those blocks with
+signature checks already done (strictly ≥ the reference's checks: it
+light-verifies each pair AND full-verifies each commit one height later;
+we full-verify each commit exactly once, in the batch).  The blocks past
+the cut stay in the pool and head the next step's window, so a catch-up
+from a peer faster than the verifier runs one device program (the top
+rung, `MAX_COALESCE` rows) step after step, whatever the window's length;
+only the short windows near the tip meet smaller rungs.
 
 Round 6: batch_verify_commits submits through the async verification
 service (crypto.async_verify), so a blocksync window verifying while
@@ -25,10 +30,13 @@ from __future__ import annotations
 
 import asyncio
 
+from tendermint_tpu.crypto.async_verify import MAX_COALESCE
 from tendermint_tpu.p2p.types import ChannelDescriptor, Envelope, PeerStatus
 from tendermint_tpu.types.basic import BlockID
 from tendermint_tpu.types.validator import CommitVerifyJob, batch_verify_commits
+from tendermint_tpu.utils import trace as _trace
 from tendermint_tpu.utils.log import Logger, nop_logger
+from tendermint_tpu.utils.metrics import Counter
 
 from .messages import (
     BlockRequest,
@@ -55,6 +63,146 @@ def _descriptor() -> ChannelDescriptor:
     )
 
 
+# Bumped once a step, in `verify_window` (process-wide; registered by
+# node/metrics.py): how many steps ran, the blocks and the rows they
+# verified, and how often a step left downloaded blocks for the next one.
+WINDOWS_TOTAL = Counter(
+    "windows_total", "Window verify steps (one batched device call each)",
+    namespace="tendermint", subsystem="blocksync",
+)
+WINDOW_BLOCKS_TOTAL = Counter(
+    "window_blocks_total", "Blocks verified by window steps",
+    namespace="tendermint", subsystem="blocksync",
+)
+WINDOW_ROWS_TOTAL = Counter(
+    "window_rows_total",
+    "Commit rows window steps were sized by (signature counts of their jobs)",
+    namespace="tendermint", subsystem="blocksync",
+)
+WINDOW_CUTS_TOTAL = Counter(
+    "window_cuts_total",
+    "Window steps that left downloaded blocks for the next step",
+    namespace="tendermint", subsystem="blocksync",
+)
+WINDOW_COUNTERS = (WINDOWS_TOTAL, WINDOW_BLOCKS_TOTAL, WINDOW_ROWS_TOTAL,
+                   WINDOW_CUTS_TOTAL)
+
+
+def _static_valset_prefix(state, window: list) -> list:
+    """Leading blocks of window[:-1] whose ValidatorsHash matches the
+    current set — the slice batch verification and per-block redo must
+    both scan (past the valset boundary different signers apply)."""
+    cur_hash = state.validators.hash()
+    prefix = []
+    for b in window[:-1]:
+        if b.header.validators_hash != cur_hash:
+            break
+        prefix.append(b)
+    return prefix
+
+
+def _commit_rows(block) -> int:
+    return len(block.last_commit.signatures) if block.last_commit is not None else 0
+
+
+def _cut(state, window: list, max_rows: int) -> list:
+    """The blocks one step takes: the longest leading run of the
+    static-valset prefix whose jobs fit one flush — the LastCommits of
+    the blocks taken plus the successor's (the pair check), each counted
+    at its signature count, an upper bound on the rows the job submits
+    that needs no pass over them.  Never fewer than one block: a commit
+    wider than a flush is still verified, and the service cuts the flush."""
+    prefix = _static_valset_prefix(state, window)
+    if not prefix:
+        return []
+    rows = _commit_rows(window[0]) + _commit_rows(window[1])
+    k = 1
+    while k < len(prefix) and rows + _commit_rows(window[k + 1]) <= max_rows:
+        rows += _commit_rows(window[k + 1])
+        k += 1
+    return prefix[:k]
+
+
+def window_jobs(state, window: list,
+                max_rows: int = MAX_COALESCE) -> tuple[list, list[CommitVerifyJob]]:
+    """The blocks one step takes off `window` (consecutive downloaded
+    blocks from the apply point of `state`) and the single device batch
+    that covers them.
+
+    applied  = the leading blocks of window[:-1] whose ValidatorsHash
+               equals the current valset's (the valset can only change
+               at a header boundary, where the batch must stop because
+               future valsets aren't known until the app runs), cut to
+               what one flush of `max_rows` rows holds (`_cut`).
+    jobs     = full-verify of every applied block's LastCommit
+               + light pair-check of the newest applied block's commit
+               (carried by its successor's LastCommit).
+    """
+    with _trace.span("blocksync.window_jobs", downloaded=len(window)) as sp:
+        applied = _cut(state, window, max_rows)
+        if not applied:
+            return [], []
+        chain_id = state.chain_id
+        jobs = []
+        for i, b in enumerate(applied):
+            if b.header.height == state.initial_height:
+                continue  # first block ever has an empty LastCommit
+            val_set = state.last_validators if i == 0 else state.validators
+            jobs.append(
+                CommitVerifyJob(
+                    val_set=val_set,
+                    chain_id=chain_id,
+                    block_id=b.header.last_block_id,
+                    height=b.header.height - 1,
+                    commit=b.last_commit,
+                    mode="full",
+                )
+            )
+        # pair-check: successor's LastCommit proves the newest applied block
+        last = applied[-1]
+        successor = window[len(applied)]
+        part_set = last.make_part_set()
+        last_id = BlockID(hash=last.hash(), part_set_header=part_set.header())
+        if successor.header.last_block_id != last_id:
+            raise ValueError(
+                f"successor of height {last.header.height} points at a "
+                "different block"
+            )
+        jobs.append(
+            CommitVerifyJob(
+                val_set=state.validators,
+                chain_id=chain_id,
+                block_id=last_id,
+                height=last.header.height,
+                commit=successor.last_commit,
+                mode="light",
+            )
+        )
+        sp.set(applied=len(applied), jobs=len(jobs))
+    return applied, jobs
+
+
+def verify_window(state, window: list, max_rows: int = MAX_COALESCE) -> list:
+    """One step of a catch-up: verify the leading blocks of `window` that
+    one flush holds as ONE batched device call and return them, ready to
+    be applied with their signature checks done.  An empty list: the first
+    block's ValidatorsHash is not the current set's.  Raises ValueError
+    naming the first failing commit's height (`batch_verify_commits`)."""
+    with _trace.span("blocksync.window", downloaded=len(window)) as sp:
+        applied, jobs = window_jobs(state, window, max_rows)
+        if applied:
+            rows = sum(len(job.commit.signatures) for job in jobs)
+            cut = len(applied) < len(window) - 1
+            sp.set(applied=len(applied), jobs=len(jobs), rows=rows, cut=cut)
+            WINDOWS_TOTAL.inc()
+            WINDOW_BLOCKS_TOTAL.inc(len(applied))
+            WINDOW_ROWS_TOTAL.inc(rows)
+            if cut:
+                WINDOW_CUTS_TOTAL.inc()
+            batch_verify_commits(jobs)
+    return applied
+
+
 class BlocksyncReactor:
     def __init__(
         self,
@@ -75,6 +223,9 @@ class BlocksyncReactor:
         self.on_caught_up = on_caught_up
         self.status_interval_s = status_interval_s
         self.pool = BlockPool(state.last_block_height + 1, startup_grace_s)
+        # rows one step's flush holds; tests shrink it so that the cut
+        # bites at small sizes
+        self.max_rows = MAX_COALESCE
         self.channel = router.open_channel(_descriptor())
         self.peer_updates = router.subscribe_peer_updates()
         self._tasks: list[asyncio.Task] = []
@@ -196,61 +347,6 @@ class BlocksyncReactor:
                 await self._disconnect_banned()
 
     # -- the batched verify+apply pipeline -------------------------------
-    def _window_jobs(self, window: list) -> tuple[list, list[CommitVerifyJob]]:
-        """Trim `window` to the static-valset prefix and build the single
-        device batch covering it.
-
-        applied  = window[:-1] restricted to blocks whose ValidatorsHash
-                   equals the current valset's (the valset can only change
-                   at a header boundary, where the batch must stop because
-                   future valsets aren't known until the app runs).
-        jobs     = full-verify of every applied block's LastCommit
-                   + light pair-check of the newest applied block's commit
-                   (carried by its successor's LastCommit).
-        """
-        applied = self._static_valset_prefix(window)
-        if not applied:
-            return [], []
-        chain_id = self.state.chain_id
-        jobs = []
-        for i, b in enumerate(applied):
-            if b.header.height == self.state.initial_height:
-                continue  # first block ever has an empty LastCommit
-            val_set = (
-                self.state.last_validators if i == 0 else self.state.validators
-            )
-            jobs.append(
-                CommitVerifyJob(
-                    val_set=val_set,
-                    chain_id=chain_id,
-                    block_id=b.header.last_block_id,
-                    height=b.header.height - 1,
-                    commit=b.last_commit,
-                    mode="full",
-                )
-            )
-        # pair-check: successor's LastCommit proves the newest applied block
-        last = applied[-1]
-        successor = window[len(applied)]
-        part_set = last.make_part_set()
-        last_id = BlockID(hash=last.hash(), part_set_header=part_set.header())
-        if successor.header.last_block_id != last_id:
-            raise ValueError(
-                f"successor of height {last.header.height} points at a "
-                "different block"
-            )
-        jobs.append(
-            CommitVerifyJob(
-                val_set=self.state.validators,
-                chain_id=chain_id,
-                block_id=last_id,
-                height=last.header.height,
-                commit=successor.last_commit,
-                mode="light",
-            )
-        )
-        return applied, jobs
-
     async def _sync_loop(self) -> None:
         while True:
             try:
@@ -274,7 +370,17 @@ class BlocksyncReactor:
                 self.pool.blocks_available.clear()
                 continue
             try:
-                applied, jobs = self._window_jobs(window)
+                # ONE device call for the signatures of the blocks a step
+                # takes — on a worker thread: the first flush at a new
+                # rung pays a compile (seconds to minutes), and an event
+                # loop blocked that long serves no peer, answers no RPC,
+                # and wakes to find its in-flight block requests past
+                # REQUEST_TIMEOUT_S (the status ticker then bans the
+                # honest peers that could not answer a loop that was not
+                # running)
+                applied = await asyncio.to_thread(
+                    verify_window, self.state, window, self.max_rows
+                )
                 if not applied:
                     # An honest block at the apply point always carries
                     # ValidatorsHash == current valset hash; an empty
@@ -290,14 +396,6 @@ class BlocksyncReactor:
                     await self._disconnect_banned()
                     self.pool.blocks_available.clear()
                     continue
-                # ONE device call for the whole window's signatures — on
-                # a worker thread: the first flush at a new rung pays a
-                # compile (seconds to minutes), and an event loop blocked
-                # that long serves no peer, answers no RPC, and wakes to
-                # find its in-flight block requests past REQUEST_TIMEOUT_S
-                # (the status ticker then bans the honest peers that
-                # could not answer a loop that was not running)
-                await asyncio.to_thread(batch_verify_commits, jobs)
             except ValueError as e:
                 self.logger.info("bad window, refetching", err=str(e))
                 self._redo_per_block(window)
@@ -334,18 +432,6 @@ class BlocksyncReactor:
             # yield so request/recv tasks keep the pipeline full
             await asyncio.sleep(0)
 
-    def _static_valset_prefix(self, window: list) -> list:
-        """Leading blocks of the window whose ValidatorsHash matches the
-        current set — the slice batch verification and per-block redo must
-        both scan (past the valset boundary different signers apply)."""
-        cur_hash = self.state.validators.hash()
-        prefix = []
-        for b in window[:-1]:
-            if b.header.validators_hash != cur_hash:
-                break
-            prefix.append(b)
-        return prefix
-
     def _commit_for(self, block, window: list):
         """SeenCommit for a fast-synced block = its successor's LastCommit."""
         for b in window:
@@ -362,11 +448,12 @@ class BlocksyncReactor:
         """Batch verification failed somewhere in the window: find the
         first bad height with per-block checks so only the offending peers
         are banned (reference redo bans the sender of the failing pair).
-        Scans exactly the static-valset prefix _window_jobs batched —
-        past the valset boundary different signers apply and honest blocks
-        would fail a naive check."""
+        Scans exactly the blocks `verify_window` batched: the cut
+        static-valset prefix — past the valset boundary different signers
+        apply and honest blocks would fail a naive check, and past the cut
+        nothing was verified."""
         state = self.state
-        applied = self._static_valset_prefix(window)
+        applied = _cut(state, window, self.max_rows)
         for i, b in enumerate(applied):
             try:
                 if b.header.height > state.initial_height:

@@ -505,6 +505,9 @@ class NodeMetrics:
         from tendermint_tpu.blocksync.pool import (
             REQUEST_DURATION_SECONDS as _bsync_hist,
         )
+        from tendermint_tpu.blocksync.reactor import (
+            WINDOW_COUNTERS as _bsync_window_counters,
+        )
         from tendermint_tpu.consensus.state import STEP_DURATION_SECONDS
         from tendermint_tpu.rpc.server import (
             REQUEST_DURATION_SECONDS as _rpc_hist,
@@ -512,6 +515,8 @@ class NodeMetrics:
 
         self.step_duration = reg.register(STEP_DURATION_SECONDS)
         self.blocksync_request_duration = reg.register(_bsync_hist)
+        for counter in _bsync_window_counters:
+            reg.register(counter)
         self.rpc_request_duration = reg.register(_rpc_hist)
         for hist in _av.PIPELINE_HISTOGRAMS:
             reg.register(hist)

@@ -5,10 +5,18 @@ Models reference blockchain/v0/reactor_test.go + pool_test.go.
 """
 
 import asyncio
+import copy
+import dataclasses
+import functools
+import random
 
 import pytest
 
+from chipbench.reference import ed25519_zip215 as ref
+from chipbench.reference import window_rules
+from chipbench.reference.signbytes import precommit_sign_bytes
 from tendermint_tpu.blocksync import BlockPool, BlocksyncReactor
+from tendermint_tpu.blocksync import reactor as bsync
 from tendermint_tpu.blocksync.messages import (
     BlockResponse,
     StatusResponse,
@@ -22,6 +30,7 @@ from tendermint_tpu.store import BlockStore, MemDB
 from tendermint_tpu.abci import AppConns
 from tendermint_tpu.abci.kvstore import KVStoreApplication
 from tendermint_tpu.types.validator import CommitVerifyJob, batch_verify_commits
+from tendermint_tpu.utils import trace
 
 from helpers import ChainBuilder
 
@@ -124,6 +133,156 @@ def test_batch_verify_commits_empty():
 
 
 # ---------------------------------------------------------------------------
+# the window step: blocksync.reactor.verify_window held to the plain
+# reference (chipbench/reference/: the cut, the jobs, the answer from
+# per-row ZIP-215 verdicts; imports nothing of the program)
+# ---------------------------------------------------------------------------
+
+N_VALS = 4
+
+
+@functools.cache
+def _run_chain():
+    """One 14-block chain of 4 validators for all window cases (built once)."""
+    return ChainBuilder(n_vals=N_VALS).build(14)
+
+
+def _run(first, n_blocks):
+    """(state the reactor holds at `first`, deep copies of blocks first ..
+    first + n_blocks - 1) of the static-valset chain."""
+    chain = _run_chain()
+    state = dataclasses.replace(chain.state, last_block_height=first - 1)
+    return state, [copy.deepcopy(chain.block_store.load_block(h))
+                   for h in range(first, first + n_blocks)]
+
+
+def _reference_answer(state, window, max_rows):
+    """What `window_rules` says of the step from the plain reference's
+    verdict on EVERY row of the run's commits."""
+    powers = [v.voting_power for v in state.validators.validators]
+    pubs = [v.pub_key.bytes_() for v in state.validators.validators]
+    commits = [b.last_commit for b in window]
+
+    def row_ok(i, r):
+        c, cs = commits[i], commits[i].signatures[r]
+        psh = c.block_id.part_set_header
+        msg = precommit_sign_bytes(state.chain_id, c.height, c.round, c.block_id.hash,
+                                   psh.total, psh.hash, cs.timestamp_ns)
+        return ref.verify(pubs[r], msg, cs.signature)
+
+    step = window_rules.jobs([len(c.signatures) for c in commits], max_rows)
+    return step, window_rules.expected_step(
+        step, powers, [c.height for c in commits],
+        [range(len(c.signatures)) for c in commits], row_ok)
+
+
+def _answer(state, window, max_rows):
+    try:
+        return ("accept", len(bsync.verify_window(state, window, max_rows)))
+    except ValueError as e:
+        return ("refused", str(e))
+
+
+# (case, blocks offered, max_rows, where the corrupted row goes): with 4
+# rows a commit, max_rows 16 holds 3 blocks and the pair check
+WINDOW_CASES = [
+    ("longer_than_the_cut", 8, 16, None),
+    ("shorter_than_the_cut", 3, 16, None),
+    ("cut_of_one_block", 5, 4, None),
+    ("fits_the_default_flush", 8, None, None),
+    ("bad_row_inside_the_cut", 8, 16, "full"),
+    ("bad_row_in_the_newest_taken_block", 8, 16, "newest"),
+    ("bad_row_in_the_pair_check", 8, 16, "pair"),
+    ("bad_row_past_the_pair_checks_two_thirds", 8, 16, "pair_unconsulted"),
+    ("bad_row_past_the_cut", 8, 16, "past_cut"),
+]
+
+
+@pytest.mark.parametrize("seed", [11, 2**31 + 5])
+@pytest.mark.parametrize("case,n_blocks,max_rows,bad", WINDOW_CASES,
+                         ids=[c[0] for c in WINDOW_CASES])
+def test_verify_window_against_the_reference(case, n_blocks, max_rows, bad, seed):
+    rng = random.Random(seed)
+    max_rows = bsync.MAX_COALESCE if max_rows is None else max_rows
+    state, window = _run(rng.randrange(2, 6), n_blocks)
+    taken = window_rules.cut([N_VALS] * n_blocks, max_rows)
+    assert taken == {"longer_than_the_cut": 3, "shorter_than_the_cut": 2,
+                     "cut_of_one_block": 1, "fits_the_default_flush": 7}.get(case, 3)
+    if bad is not None:
+        commit, row = {
+            # not the newest taken block's LastCommit: see "newest" below
+            "full": lambda: (rng.randrange(taken - 1), rng.randrange(N_VALS)),
+            "newest": lambda: (taken - 1, rng.randrange(N_VALS)),
+            "pair": lambda: (taken, rng.randrange(3)),        # 3 of 4 rows reach +2/3
+            "pair_unconsulted": lambda: (taken, 3),
+            "past_cut": lambda: (rng.randrange(taken + 1, n_blocks), rng.randrange(N_VALS)),
+        }[bad]()
+        cs = window[commit].last_commit.signatures[row]
+        if rng.random() < 0.5:
+            cs.signature = cs.signature[:-1] + bytes([cs.signature[-1] ^ 1])
+        else:
+            cs.timestamp_ns += 7
+
+    if bad == "newest":
+        # the newest taken block's part set is re-made for the pair check,
+        # so a LastCommit changed after its successor was built is refused
+        # before any signature is looked at
+        with pytest.raises(ValueError, match="points at a different block"):
+            bsync.window_jobs(state, window, max_rows)
+        return
+
+    applied, jobs = bsync.window_jobs(state, window, max_rows)
+    step, expected = _reference_answer(state, window, max_rows)
+    # the cut and the job order are the reference's
+    assert [b.header.height for b in applied] == [
+        b.header.height for b in window[:taken]]
+    assert [(j.mode, j.height, j.commit) for j in jobs] == [
+        (mode, window[i].last_commit.height, window[i].last_commit) for mode, i in step]
+    assert [j.val_set for j in jobs] == (
+        [state.last_validators] + [state.validators] * taken)
+
+    got = _answer(state, window, max_rows)
+    if bad in ("full", "pair"):
+        height = window[commit].last_commit.height
+        assert expected == ("wrong_signature", (height, row))
+        assert got == ("refused", f"wrong signature (#{row}) in commit for height {height}")
+    else:
+        # rows past the cut and past the pair check's +2/3 are never consulted
+        assert expected == got == ("accept", taken)
+
+
+def test_verify_window_spans_and_counters():
+    state, window = _run(2, 8)
+    before = [c.samples()[0][2] for c in bsync.WINDOW_COUNTERS]
+    was = trace.enabled()
+    trace.clear()
+    trace.set_enabled(True)
+    try:
+        assert len(bsync.verify_window(state, window, 16)) == 3
+        assert len(bsync.verify_window(state, window[:3], 16)) == 2
+    finally:
+        trace.set_enabled(was)
+    spans = trace.spans()
+    trace.clear()
+    steps = [s for s in spans if s["name"] == "blocksync.window"]
+    assert [s["attrs"] for s in steps] == [
+        {"downloaded": 8, "applied": 3, "jobs": 4, "rows": 16, "cut": True},
+        {"downloaded": 3, "applied": 2, "jobs": 3, "rows": 12, "cut": False}]
+    by_id = {s["id"]: s for s in spans}
+    builds = [s for s in spans if s["name"] == "blocksync.window_jobs"]
+    assert [by_id[s["parent"]]["name"] for s in builds] == ["blocksync.window"] * 2
+    # one span a step, never one a block or a row; the commit.* spans of
+    # the step's jobs hang under it
+    commit_spans = [s for s in spans if s["name"].startswith("commit.")]
+    assert {s["parent"] for s in commit_spans} == {s["id"] for s in steps}
+    assert sum(s["name"] == "commit.sign_bytes" for s in commit_spans) == 4 + 3
+    # counters: steps, blocks, rows the cut counted, steps that left blocks
+    after = [c.samples()[0][2] for c in bsync.WINDOW_COUNTERS]
+    assert [a - b for a, b in zip(after, before)] == [2, 5, 28, 1]
+
+
+
+# ---------------------------------------------------------------------------
 # wire round-trip
 # ---------------------------------------------------------------------------
 
@@ -164,7 +323,11 @@ def _make_node(genesis, network, node_id, block_store=None, on_caught_up=None):
     return router, reactor
 
 
-def test_fast_sync_two_nodes():
+# max_rows 12 = three 4-row commits: a step takes TWO blocks and the pair
+# check, so the 24 provable blocks need a dozen cut steps; the default
+# flush holds every window a 25-block chain can offer
+@pytest.mark.parametrize("max_rows", [None, 12], ids=["default_flush", "cut_steps"])
+def test_fast_sync_two_nodes(max_rows):
     async def run():
         chain = ChainBuilder(n_vals=4).build(25)
         network = MemoryNetwork()
@@ -185,6 +348,8 @@ def test_fast_sync_two_nodes():
         client_router, client = _make_node(
             chain.genesis, network, "bb" * 20, on_caught_up=on_caught_up
         )
+        if max_rows is not None:
+            client.max_rows = max_rows
 
         await server_router.start()
         await client_router.start()
@@ -203,13 +368,31 @@ def test_fast_sync_two_nodes():
         # the synced chain is byte-identical to the source
         for h in range(1, 25):
             assert client.store.load_block(h).hash() == chain.block_store.load_block(h).hash()
+        # only the client's steps are cut (the serving node's own loop runs
+        # the default): each took two blocks (the first three: block 1's
+        # LastCommit is empty) and left the rest for the next
+        cut_steps = [s["attrs"] for s in trace.spans()
+                     if s["name"] == "blocksync.window" and s["attrs"]["cut"]]
+        if max_rows is None:
+            assert cut_steps == []
+        else:
+            assert len(cut_steps) >= 6
+            assert {(a["applied"], a["jobs"], a["rows"]) for a in cut_steps} == {
+                (2, 3, 12), (3, 3, 12)}
 
         await client.stop()
         await server.stop()
         await client_router.stop()
         await server_router.stop()
 
-    asyncio.run(run())
+    was = trace.enabled()
+    trace.clear()
+    trace.set_enabled(True)
+    try:
+        asyncio.run(run())
+    finally:
+        trace.set_enabled(was)
+        trace.clear()
 
 
 # ---------------------------------------------------------------------------
@@ -287,11 +470,41 @@ def test_fast_sync_survives_byzantine_peer():
                 client.store.load_block(h).hash()
                 == chain.block_store.load_block(h).hash()
             )
-
         for re in (honest, evil, client):
             await re.stop()
         for r in (honest_router, evil_router, client_router):
             await r.stop()
+
+    asyncio.run(run())
+
+
+@pytest.mark.parametrize("bad_height,refusal,banned", [
+    # block 2's LastCommit is a full job of the first cut step (blocks 1-3)
+    (2, "wrong signature \\(#1\\) in commit for height 1", {"p1-3"}),
+    # block 4's LastCommit is that step's pair check: the commits of 1-3
+    # hold, so the newest taken block and its successor are refetched.  A
+    # scan past the cut would full-verify block 4 and blame 4 and 5.
+    (4, "wrong signature \\(#1\\) in commit for height 3", {"p1-3", "p4"}),
+], ids=["full_job_inside_the_cut", "pair_check_of_the_cut"])
+def test_redo_scans_the_cut_window(bad_height, refusal, banned):
+    async def run():
+        chain = _run_chain()
+        _, client = _make_node(chain.genesis, MemoryNetwork(), "bb" * 20)
+        client.max_rows = 12        # blocks 1-3 and the pair check from block 4
+        pool = client.pool
+        for peer, (lo, hi) in {"p1-3": (1, 3), "p4": (4, 4), "p5-9": (5, 9)}.items():
+            pool.set_peer_range(peer, lo, hi)
+        for h in range(1, 10):
+            b = copy.deepcopy(chain.block_store.load_block(h))
+            if h == bad_height:
+                b.last_commit.signatures[1].signature = bytes(64)
+            assert pool.add_block(pool.requesters[h].peer_id, b)
+        window = pool.window()
+        assert len(window) == 9
+        with pytest.raises(ValueError, match=refusal):
+            bsync.verify_window(client.state, window, client.max_rows)
+        client._redo_per_block(window)
+        assert pool.banned == banned
 
     asyncio.run(run())
 

@@ -312,6 +312,8 @@ def test_node_serves_prometheus(tmp_path):
             assert "# TYPE tendermint_consensus_step_duration_seconds histogram" in text
             assert "# TYPE tendermint_crypto_verify_e2e_seconds histogram" in text
             assert "# TYPE tendermint_blocksync_request_duration_seconds histogram" in text
+            for series in ("windows", "window_blocks", "window_rows", "window_cuts"):
+                assert f"# TYPE tendermint_blocksync_{series}_total counter" in text
             assert "# TYPE tendermint_rpc_request_duration_seconds histogram" in text
             # per-program HLO cost gauges (ISSUE 8, utils/costmodel):
             # present and typed even before any program is harvested
