@@ -14,13 +14,33 @@ Pipeline per batch:
   device: unpack bytes → bits/nibbles → limbs (elementwise, free next to the
           curve math), then permissive point decompression for A and R
           (ZIP-215 rule 2 — y >= p accepted, x=0/sign=1 accepted, small order
-          accepted), W = [s]B + [k](-A) with radix-16 fixed-base tables for B
-          (zero doublings) and a 4-bit windowed ladder for A (63 adds + 252
-          doublings via the dedicated doubling formula), Q = W - R, and the
-          cofactored check [8]Q == identity (ZIP-215 rule 3).
+          accepted), W = [s]B + [k](-A), Q = W - R, and the cofactored
+          check [8]Q == identity (ZIP-215 rule 3).
+
+Both scalar multiplications read their scalar as 64 SIGNED radix-16 digits
+in -8..7 (_signed_digits: exact as an integer, a 6-step prefix scan over the
+nibbles) and add, per window, one table entry PRECOMPUTED into the form its
+addition wants, |digit| picked by a 3-level select tree and the sign applied
+inside the addition for free (a negated entry swaps its first two
+coordinates and the addition's F and G):
+  * [s]B: fixed-base tables of j·16^i·B, j = 1..8, as affine Niels triples
+    (y+x, y-x, 2d·x·y) — host constants; 64 mixed additions (pt_madd, 7
+    multiplications), zero doublings.
+  * [k](-A): a per-signature table [1..8](-A) in cached form (Y+X, Y-X, Z,
+    2d·T) — a chain of 7 additions of the cached -A, 8 conversions — then
+    63 windows of 4 doublings (the dedicated doubling formula) + 1 cached
+    addition (pt_add_cached, 8 multiplications): 37 field operations a
+    window.
+3,433 field multiplications and squarings a signature in all
+(tests/kernel_cases.py counts them: decompressions 2 x 275, base 448,
+table 64, loop 2,331, finish 40); 3,677 by the same count with unsigned
+digits and the general addition (before PR 29).  The unified a = -1
+formulas are complete, so small-order keys, the identity and non-canonical
+encodings take the same path as honest rows.
 
 Note: -[k]A is computed as [k](-A), never as [L-k]A — the latter is wrong for
 points with a torsion component (L·A ≠ O), exactly the inputs ZIP-215 admits.
+The signed recoding keeps that: digits sum to k itself, nothing is reduced.
 
 Field backends (TM_TPU_FIELD_IMPL, or the `impl=` argument):
   * "int64"  — 15 limbs × 17 bits in int64 lanes (fe25519.py).  The
@@ -133,13 +153,22 @@ def _field(impl: str):
 
 
 @functools.cache
-def _base_point_table() -> list[list[tuple[int, int, int, int]]]:
-    """[j * 16^i]B for i in 0..63, j in 0..15 as big-int extended coords —
-    host-side, shared by every field backend's constant encoding."""
+def _base_point_table() -> list[list[tuple[int, int, int]]]:
+    """[j * 16^i]B for i in 0..63, j in 1..8 as big-int AFFINE Niels
+    triples (y+x, y-x, 2d·x·y) mod p — host-side, shared by every field
+    backend's constant encoding.  Eight entries a window: a signed digit
+    in -8..7 picks |d| and negates for free (pt_madd)."""
+    p = _ref.P
     rows = []
     g = _ref.BASE
     for _i in range(NWINDOWS):
-        rows.append([_ref.scalar_mult(j, g) for j in range(16)])
+        row, pt = [], g
+        for _j in range(8):
+            zi = pow(pt[2], p - 2, p)
+            x, y = pt[0] * zi % p, pt[1] * zi % p
+            row.append(((y + x) % p, (y - x) % p, 2 * _ref.D * x * y % p))
+            pt = _ref.pt_add(pt, g)
+        rows.append(row)
         g = _ref.scalar_mult(16, g)
     return rows
 
@@ -258,57 +287,114 @@ class _Core:
                    for i in range(len(cur) // 2)]
         return cur[0]
 
+    @staticmethod
+    def _signed_digits(nibbles: jnp.ndarray) -> jnp.ndarray:
+        """[..., 64] radix-16 digits in 0..15 → [..., 64] digits in -8..7
+        with the same value, sum(d_i * 16^i), EXACTLY (as an integer —
+        nothing is reduced, so [k](-A) stays [k](-A)): a digit >= 8
+        becomes digit - 16 and carries one up.  The carry chain is a
+        6-step prefix scan (generate: d >= 8, propagate: d == 7), not 64
+        dependent steps.  Scalars below 2^253 (s and k are) leave a top
+        digit of 0..2 and no carry out."""
+        def up(x, k):  # x[..., i - k] at i, False below k
+            return jnp.pad(x[..., :-k], [(0, 0)] * (x.ndim - 1) + [(k, 0)])
+
+        gen, prop = nibbles >= 8, nibbles == 7
+        k = 1
+        while k < NWINDOWS:
+            gen, prop = gen | (prop & up(gen, k)), prop & up(prop, k)
+            k *= 2
+        # gen[i] is now the carry OUT of digit i
+        return (nibbles + up(gen, 1).astype(jnp.int32)
+                - 16 * gen.astype(jnp.int32))
+
+    @staticmethod
+    def _select_signed(digit: jnp.ndarray, tbl: list, identity):
+        """(|digit|·P, digit < 0) from tbl = [1P, ..., 8P], each entry a
+        tuple of coordinates in a precomputed form whose negation is the
+        addition's business (pt_madd / pt_add_cached take the sign): a
+        3-level binary select tree over |digit| - 1 (7 selects a
+        coordinate — elementwise, no gathers), then `identity`, the same
+        form's neutral element, where the digit is 0."""
+        mag = jnp.abs(digit)
+        idx = (mag - 1) & 7
+        cur = list(tbl)
+        for b in range(3):
+            bit = (((idx >> b) & 1) == 1)[..., None]
+            cur = [tuple(jnp.where(bit, hi, lo) for lo, hi in zip(*cur[i:i + 2]))
+                   for i in range(0, len(cur), 2)]
+        zero = (mag == 0)[..., None]
+        sel = tuple(jnp.where(zero, jnp.asarray(o), c)
+                    for o, c in zip(identity, cur[0]))
+        return sel, digit < 0
+
     def _scalarmul_var(self, digits: jnp.ndarray, neg_a):
-        """[k](-A) by 4-bit fixed windows: 16-entry per-signature table
-        (14 adds to build), then 63 iterations of 4 doublings + 1 add."""
+        """[k](-A) by signed 4-bit windows (digits: _signed_digits of a
+        scalar below 2^253): the table [1..8](-A) in cached form (a
+        chain of 7 additions of the cached -A, 8 conversions: 64
+        multiplications), then 63 iterations of 4 doublings + 1 cached
+        addition (37).  The top digit is 0..2, so the loop starts from
+        O, -A or 2(-A) as they stand.
+
+        The table is a CHAIN on purpose.  Built as 4 doublings + 3
+        additions (the same 64 multiplications, a shallower tree) the
+        whole program came out of the TPU compiler's memory-space
+        assignment with the product columns of EVERY field operation, in
+        every loop, copied out to HBM and sliced back (`slice-start` in
+        the compiled text: 448 of them, none with the chain), and a
+        squaring took 55 us where it takes 39.5 at rung 10,240 (PERF.md
+        section 6, PR 29).  tests/test_fe25519_packed.py compiles the
+        program for a described v5e and counts them (slow)."""
         fe = self.fe
-        shape = digits.shape[:-1]
-        tbl = [fe.pt_identity(shape), neg_a]
-        for _ in range(14):
-            tbl.append(fe.pt_add(tbl[-1], neg_a))
+        c1 = fe.pt_to_cached(neg_a)
+        ext = [neg_a]
+        for _ in range(7):
+            ext.append(fe.pt_add_cached(ext[-1], c1))
+        tbl = [c1] + [fe.pt_to_cached(p) for p in ext[1:]]
+        identity = (fe.ONE, fe.ONE, fe.ONE, fe.ZERO)
 
         def body(i, acc):
             d = jnp.take(digits, NWINDOWS - 1 - i, axis=-1)
             acc = fe.pt_dbl_n(acc, 4)
-            return fe.pt_add(acc, self._select16(d, tbl))
+            return fe.pt_add_cached(acc, *self._select_signed(d, tbl, identity))
 
-        top = self._select16(jnp.take(digits, NWINDOWS - 1, axis=-1), tbl)
-        return lax.fori_loop(1, NWINDOWS, body, top)
+        top = jnp.take(digits, NWINDOWS - 1, axis=-1)
+        acc0 = fe.pt_select(top == 2, ext[1], fe.pt_select(
+            top == 1, neg_a, fe.pt_identity(digits.shape[:-1])))
+        return lax.fori_loop(1, NWINDOWS, body, acc0)
 
     @functools.cached_property
     def _fixed_base_tables(self) -> tuple[np.ndarray, ...]:
-        """The shared big-int table encoded as four [64, 16, NLIMBS] limb
-        tensors (X, Y, Z, T) in this backend's limb dtype.  numpy, NOT jnp:
-        device constants created inside one jit trace must not be cached
-        across traces; callers convert per-trace (XLA folds them into
-        program constants)."""
+        """The shared big-int table encoded as three [64, 8, NLIMBS] limb
+        tensors (y+x, y-x, 2d·x·y — canonical limbs) in this backend's
+        limb dtype.  numpy, NOT jnp: device constants created inside one
+        jit trace must not be cached across traces; callers convert
+        per-trace (XLA folds them into program constants)."""
         fe = self.fe
         dtype = np.asarray(fe.ONE).dtype
-        coords = [np.zeros((NWINDOWS, 16, fe.NLIMBS), dtype=dtype) for _ in range(4)]
+        coords = [np.zeros((NWINDOWS, 8, fe.NLIMBS), dtype=dtype) for _ in range(3)]
         for i, row in enumerate(_base_point_table()):
-            for j, pt in enumerate(row):
-                for c in range(4):
-                    coords[c][i, j] = fe.limbs_from_int(pt[c])
+            for j, niels in enumerate(row):
+                for c in range(3):
+                    coords[c][i, j] = fe.limbs_from_int(niels[c])
         return tuple(coords)
 
     def _scalarmul_base(self, digits: jnp.ndarray):
-        """[s]B from the fixed-base tables (no doublings)."""
+        """[s]B from the fixed-base Niels tables (digits: _signed_digits
+        of s): 64 mixed additions, 7 multiplications each, no
+        doublings."""
         fe = self.fe
-        tx, ty, tz, tt = (jnp.asarray(c) for c in self._fixed_base_tables)
-        shape = digits.shape[:-1]
+        tables = [jnp.asarray(c) for c in self._fixed_base_tables]
+        identity = (fe.ONE, fe.ONE, fe.ZERO)
 
-        def body_dyn(i, acc):
-            rx, ry, rz, rt = (jnp.take(c, i, axis=0) for c in (tx, ty, tz, tt))
-            row = [fe.Pt(rx[j], ry[j], rz[j], rt[j]) for j in range(16)]
-            sel = self._select16(jnp.take(digits, i, axis=-1), row)
-            return fe.pt_add(acc, sel)
+        def body(i, acc):
+            rows = [jnp.take(c, i, axis=0) for c in tables]
+            tbl = [tuple(r[j] for r in rows) for j in range(8)]
+            d = jnp.take(digits, i, axis=-1)
+            return fe.pt_madd(acc, *self._select_signed(d, tbl, identity))
 
-        acc0 = self._select16(
-            jnp.take(digits, 0, axis=-1),
-            [fe.Pt(tx[0, j], ty[0, j], tz[0, j], tt[0, j]) for j in range(16)],
-        )
-        acc0 = fe.Pt(*(jnp.broadcast_to(c, shape + (fe.NLIMBS,)) for c in acc0.astuple()))
-        return lax.fori_loop(1, NWINDOWS, body_dyn, acc0)
+        return lax.fori_loop(0, NWINDOWS, body,
+                             fe.pt_identity(digits.shape[:-1]))
 
     @functools.cached_property
     def _fixed_base_tables256(self) -> np.ndarray:
@@ -548,8 +634,8 @@ class _Core:
             r_bits = self._bits_of(r_rows)
             y_a, sign_a = self._limbs_of(pub_bits[..., :255]), pub_bits[..., 255]
             y_r, sign_r = self._limbs_of(r_bits[..., :255]), r_bits[..., 255]
-            s_digits = self._nibbles_of(s_rows)
-            k_digits = self._nibbles_of(k_rows)
+            s_digits = self._signed_digits(self._nibbles_of(s_rows))
+            k_digits = self._signed_digits(self._nibbles_of(k_rows))
         with jax.named_scope("ed25519.decompress_a"):
             a_pt, ok_a = self.decompress(y_a, sign_a)
         with jax.named_scope("ed25519.decompress_r"):

@@ -246,12 +246,61 @@ def pt_add(p: Pt, q: Pt) -> Pt:
     b = fe_mul(fe_add(p.y, p.x), fe_add(q.y, q.x))
     c = fe_mul(fe_mul(p.t, q.t), D2_CONST)
     d = fe_mul(p.z, q.z)
-    d2 = fe_add(d, d)
+    return _add_tail(a, b, c, fe_add(d, d), None)
+
+
+def _add_tail(a, b, c, d2, neg) -> Pt:
+    """The second half of every addition: E, F, G, H and the four
+    products.  `neg` (bool [...], or None) adds the NEGATED entry: its
+    C term changes sign, which is F and G exchanged.  Bounds: E < 2^18.7,
+    H < 2^18.3, F < 2^19.2, G < 2^18.9 — under fe_mul's 2^20 ceiling in
+    either order."""
     e = fe_sub(b, a)
     f = fe_sub(d2, c)
     g = fe_add(d2, c)
     h = fe_add(b, a)
+    if neg is not None:
+        m = neg[..., None]
+        f, g = jnp.where(m, g, f), jnp.where(m, f, g)
     return Pt(fe_mul(e, f), fe_mul(g, h), fe_mul(f, g), fe_mul(e, h))
+
+
+def _add_entry(p: Pt, ypx, ymx, tc, d2, neg) -> Pt:
+    """p ± a table entry whose addition-side terms are precomputed:
+    (y+x, y-x), the coefficient `tc` of T1 and D2 = 2·Z1·Z2.  Negating
+    the entry is free: its first two coordinates swap (and F, G in
+    _add_tail).  Entry coordinates are reduced; the p side is the same
+    as pt_add's, so every product stays under 2^20 x 2^17.3."""
+    if neg is not None:
+        m = neg[..., None]
+        ypx, ymx = jnp.where(m, ymx, ypx), jnp.where(m, ypx, ymx)
+    a = fe_mul(fe_sub(p.y, p.x), ymx)
+    b = fe_mul(fe_add(p.y, p.x), ypx)
+    c = fe_mul(p.t, tc)
+    return _add_tail(a, b, c, d2, neg)
+
+
+def pt_madd(p: Pt, niels, neg=None) -> Pt:
+    """p ± an AFFINE point precomputed as a Niels triple (y+x, y-x,
+    2d·x·y) of canonical limbs, Z = 1: the unified addition less Z1·Z2
+    and the ·2d — 7 multiplications.  neg: bool [...] or None (= add)."""
+    ypx, ymx, xy2d = niels
+    return _add_entry(p, ypx, ymx, xy2d, fe_add(p.z, p.z), neg)
+
+
+def pt_to_cached(p: Pt):
+    """(Y+X, Y-X, Z, 2d·T), every coordinate reduced: what pt_add_cached
+    wants of the point it adds, computed once per table entry."""
+    return (fe_carry(fe_add(p.y, p.x), rounds=1),
+            fe_carry(fe_sub(p.y, p.x), rounds=1),
+            p.z, fe_mul(p.t, D2_CONST))
+
+
+def pt_add_cached(p: Pt, cached, neg=None) -> Pt:
+    """p ± a point in cached form (pt_to_cached): 8 multiplications."""
+    ypx, ymx, z, t2d = cached
+    d = fe_mul(p.z, z)
+    return _add_entry(p, ypx, ymx, t2d, fe_add(d, d), neg)
 
 
 def pt_dbl(p: Pt) -> Pt:

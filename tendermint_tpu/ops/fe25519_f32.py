@@ -350,21 +350,77 @@ def pt_identity(shape=()) -> Pt:
 def pt_add(p: Pt, q: Pt) -> Pt:
     """Unified, complete a=-1 extended addition (add-2008-hwcd-3 shape).
 
-    Bounds with reduced inputs (|coords| <= 51): a,b,c,d mul outputs are
-    reduced; |d2|,|h| <= 102; |e| <= 102; f = d2 - c <= |153| gets one
-    3-round partial carry (back to reduced) so every product fits the
-    fe_mul contract: e*f 102*51, g*h 153*102 = 15606 (the worst, 11%
-    margin), f*g 51*153, e*h 102*102."""
+    Bounds with reduced inputs (|coords| <= 51): the operands of a and b
+    are <= 102 each (10404), c's are reduced; a, b, c, d come out
+    reduced and _add_tail's ledger takes over."""
     a = fe_mul(fe_sub(p.y, p.x), fe_sub(q.y, q.x))
     b = fe_mul(fe_add(p.y, p.x), fe_add(q.y, q.x))
     c = fe_mul(fe_mul(p.t, q.t), jnp.asarray(D2_CONST))
     d = fe_mul(p.z, q.z)
-    d2 = fe_add(d, d)
+    return _add_tail(a, b, c, fe_add(d, d), None)
+
+
+def _add_tail(a, b, c, d2, neg) -> Pt:
+    """The second half of every addition: E, F, G, H and the four
+    products, from reduced a, b, c and |d2| <= 102.  `neg` (bool [...],
+    or None) adds the NEGATED entry: its C term changes sign, which is
+    F and G exchanged.
+
+    Ledger: |e|, |h| <= 102; f = d2 - c <= |153| always takes the
+    3-round partial carry (back to reduced); g = d2 + c <= |153|.
+      * neg None (pt_add, the table build): e*f 102*51, g*h 153*102 =
+        15606 (the worst, 11% margin), f*g 51*153, e*h 102*102.
+      * neg given: f*g must not meet two uncarried operands (153*153),
+        so g takes the same carry BEFORE the exchange and both orders
+        read e*f 102*51, g*h 51*102, f*g 51*51, e*h 102*102."""
     e = fe_sub(b, a)
     f = fe_carry(fe_sub(d2, c), rounds=3)
     g = fe_add(d2, c)
     h = fe_add(b, a)
+    if neg is not None:
+        g = fe_carry(g, rounds=3)
+        m = neg[..., None]
+        f, g = jnp.where(m, g, f), jnp.where(m, f, g)
     return Pt(fe_mul(e, f), fe_mul(g, h), fe_mul(f, g), fe_mul(e, h))
+
+
+def _add_entry(p: Pt, ypx, ymx, tc, d2, neg) -> Pt:
+    """p ± a table entry whose addition-side terms are precomputed:
+    (y+x, y-x), the coefficient `tc` of T1 and D2 = 2·Z1·Z2.  Negating
+    the entry is free: its first two coordinates swap (and F, G in
+    _add_tail).  Entry coordinates are reduced (<= 51), the p side is
+    <= 102: a, b 102*51, c 51*51, swapped or not."""
+    if neg is not None:
+        m = neg[..., None]
+        ypx, ymx = jnp.where(m, ymx, ypx), jnp.where(m, ypx, ymx)
+    a = fe_mul(fe_sub(p.y, p.x), ymx)
+    b = fe_mul(fe_add(p.y, p.x), ypx)
+    c = fe_mul(p.t, tc)
+    return _add_tail(a, b, c, d2, neg)
+
+
+def pt_madd(p: Pt, niels, neg=None) -> Pt:
+    """p ± an AFFINE point precomputed as a Niels triple (y+x, y-x,
+    2d·x·y) of canonical limbs, Z = 1: the unified addition less Z1·Z2
+    and the ·2d — 7 multiplications.  neg: bool [...] or None (= add)."""
+    ypx, ymx, xy2d = niels
+    return _add_entry(p, ypx, ymx, xy2d, fe_add(p.z, p.z), neg)
+
+
+def pt_to_cached(p: Pt):
+    """(Y+X, Y-X, Z, 2d·T), every coordinate reduced (the sum and the
+    difference, <= 102, each take a 3-round carry here, once per table
+    entry)."""
+    return (fe_carry(fe_add(p.y, p.x), rounds=3),
+            fe_carry(fe_sub(p.y, p.x), rounds=3),
+            p.z, fe_mul(p.t, jnp.asarray(D2_CONST)))
+
+
+def pt_add_cached(p: Pt, cached, neg=None) -> Pt:
+    """p ± a point in cached form (pt_to_cached): 8 multiplications."""
+    ypx, ymx, z, t2d = cached
+    d = fe_mul(p.z, z)
+    return _add_entry(p, ypx, ymx, t2d, fe_add(d, d), neg)
 
 
 def pt_dbl(p: Pt) -> Pt:

@@ -40,6 +40,18 @@ Bound analysis (why int64 never overflows; R = reduced bound):
     Worst in-tree product (pt_add/pt_dbl g*h): 2^27.59 * 2^27.01 =
     2^54.61 — 1.25x margin.  Enforced empirically at the bound by
     tests/test_fe25519_packed.py.
+  * The precomputed-form additions (pt_madd, pt_add_cached; PR 29) add a
+    table ENTRY whose coordinates are reduced — canonical host constants,
+    or pt_to_cached outputs, which carry Y+X (A) and Y-X (S) once with
+    rounds=2 — so the accumulator's side needs no carry before a and b:
+    a = (Y1-X1)*ymx is S*R = 2^53.60, b = (Y1+X1)*ypx is A*R = 2^53.02,
+    c = T1*tc is R*R, d = Z1*Z2 is R*R (d2 = A; pt_madd's d2 = 2*Z1 = A),
+    and the swap of (ypx, ymx) by the sign exchanges two R's.  The sign
+    also exchanges f and g: raw f = d2 + 2p - c < 2^28.01 against h = A
+    would be 2^55.02, PAST the contract, so with a sign both f and g take
+    the rounds=2 carry before the exchange (then e*f = S*R, g*h = R*A,
+    f*g = R*R, e*h = S*A = 2^54.60 in either order); without one (pt_add,
+    the table build) only f does, as before.
   * fe_sq operand contract: |a| <= 2^26.9 (cross terms doubled AGAIN on
     top of the odd-odd doubling: worst coefficient sum 534) — i.e.
     reduced inputs only; wider operands route through fe_mul(a, a)
@@ -52,8 +64,9 @@ Bound analysis (why int64 never overflows; R = reduced bound):
 The point formulas are the unified a=-1 extended-coordinate set shared
 with both siblings (complete for all curve points, ZIP-215 included);
 the only deltas are rounds=2 partial carries where the tighter headroom
-(25.5+1.5 bits vs 17+3) demands them — one in pt_add (the f term and the
-first subtrahend), two in pt_dbl (e and f).
+(25.5+1.5 bits vs 17+3) demands them — in pt_add the first subtrahend and
+the f term, in pt_madd/pt_add_cached f and g (never the subtrahend), two
+in pt_dbl (e and f), two in pt_to_cached.
 
 Parity target: identical to fe25519.py — the reference's ed25519consensus
 verify semantics (crypto/ed25519/ed25519.go:149-156), ZIP-215 rules,
@@ -316,18 +329,79 @@ def pt_add(p: Pt, q: Pt) -> Pt:
     Bound ledger (R < 2^26.01 reduced, S = R + 2p < 2^27.59 sub output,
     A = 2R < 2^27.01 add output): the first subtrahend and f each get a
     rounds=2 partial carry so every product meets the pairwise 2^54.9
-    contract — a: R*S, b: A*A = 2^54.02, e*f: S*R, g*h: (A+R)*A =
-    2^54.61 (the in-tree worst), f*g, e*h: S*A = 2^54.60."""
+    contract — a: R*S, b: A*A = 2^54.02, and _add_tail's four."""
     a = fe_mul(fe_carry(fe_sub(p.y, p.x), rounds=2), fe_sub(q.y, q.x))
     b = fe_mul(fe_add(p.y, p.x), fe_add(q.y, q.x))
     c = fe_mul(fe_mul(p.t, q.t), jnp.asarray(D2_CONST))
     d = fe_mul(p.z, q.z)
-    d2 = fe_add(d, d)
+    return _add_tail(a, b, c, fe_add(d, d), None)
+
+
+def _add_tail(a, b, c, d2, neg) -> Pt:
+    """The second half of every addition: E, F, G, H and the four
+    products, from reduced a, b, c and d2 <= A.  `neg` (bool [...], or
+    None) adds the NEGATED entry: its C term changes sign, which is F
+    and G exchanged.
+
+    Ledger: e = S, h = A; raw f = d2 + 2p - c < 2^28.01 always takes a
+    rounds=2 carry (-> R); g = d2 + c < A + R = 2^27.6.
+      * neg None (pt_add, the table build): e*f S*R, g*h (A+R)*A =
+        2^54.61 (the in-tree worst), f*g R*(A+R), e*h S*A = 2^54.60.
+      * neg given: whichever of the two lands in g meets h = A, and an
+        uncarried f there would be 2^28.01 * 2^27.01 = 2^55.02, past
+        the contract — so g takes the same carry BEFORE the exchange
+        and both orders read e*f S*R, g*h R*A, f*g R*R, e*h S*A."""
     e = fe_sub(b, a)
     f = fe_carry(fe_sub(d2, c), rounds=2)
     g = fe_add(d2, c)
     h = fe_add(b, a)
+    if neg is not None:
+        g = fe_carry(g, rounds=2)
+        m = neg[..., None]
+        f, g = jnp.where(m, g, f), jnp.where(m, f, g)
     return Pt(fe_mul(e, f), fe_mul(g, h), fe_mul(f, g), fe_mul(e, h))
+
+
+def _add_entry(p: Pt, ypx, ymx, tc, d2, neg) -> Pt:
+    """p ± a table entry whose addition-side terms are precomputed:
+    (y+x, y-x), the coefficient `tc` of T1 and D2 = 2·Z1·Z2.  Negating
+    the entry is free: its first two coordinates swap (and F, G in
+    _add_tail).  The entry's coordinates are REDUCED (canonical host
+    constants, or pt_to_cached outputs), so the p side needs no carry,
+    swapped or not: a = S*R = 2^53.60, b = A*R = 2^53.02, c = R*R."""
+    if neg is not None:
+        m = neg[..., None]
+        ypx, ymx = jnp.where(m, ymx, ypx), jnp.where(m, ypx, ymx)
+    a = fe_mul(fe_sub(p.y, p.x), ymx)
+    b = fe_mul(fe_add(p.y, p.x), ypx)
+    c = fe_mul(p.t, tc)
+    return _add_tail(a, b, c, d2, neg)
+
+
+def pt_madd(p: Pt, niels, neg=None) -> Pt:
+    """p ± an AFFINE point precomputed as a Niels triple (y+x, y-x,
+    2d·x·y) of canonical limbs, Z = 1: the unified addition less Z1·Z2
+    and the ·2d — 7 multiplications.  neg: bool [...] or None (= add).
+    d2 = 2·Z1 = A, as _add_tail wants."""
+    ypx, ymx, xy2d = niels
+    return _add_entry(p, ypx, ymx, xy2d, fe_add(p.z, p.z), neg)
+
+
+def pt_to_cached(p: Pt):
+    """(Y+X, Y-X, Z, 2d·T), every coordinate reduced (the sum A and the
+    difference S each take a rounds=2 carry here, once per table entry,
+    so that pt_add_cached needs none on the entry's side)."""
+    return (fe_carry(fe_add(p.y, p.x), rounds=2),
+            fe_carry(fe_sub(p.y, p.x), rounds=2),
+            p.z, fe_mul(p.t, jnp.asarray(D2_CONST)))
+
+
+def pt_add_cached(p: Pt, cached, neg=None) -> Pt:
+    """p ± a point in cached form (pt_to_cached): 8 multiplications.
+    d = Z1*Z2 is R*R, d2 = A."""
+    ypx, ymx, z, t2d = cached
+    d = fe_mul(p.z, z)
+    return _add_entry(p, ypx, ymx, t2d, fe_add(d, d), neg)
 
 
 def pt_dbl(p: Pt) -> Pt:

@@ -18,6 +18,8 @@ import jax.numpy as jnp  # noqa: E402
 from tendermint_tpu.ops import ed25519_jax as dev  # noqa: E402
 from tendermint_tpu.ops import fe25519_f32 as fe  # noqa: E402
 
+import kernel_cases  # noqa: E402
+
 
 def _val(limbs) -> int:
     return fe.int_from_limbs(np.asarray(limbs))
@@ -203,6 +205,28 @@ def test_point_ops_on_torsion():
         want = ref.pt_add(pt, pt)
         wzi = pow(want[2], ref.P - 2, ref.P)
         assert doubled == (want[0] * wzi % ref.P, want[1] * wzi % ref.P)
+
+
+# ---------------------------------------------------------------------------
+# The precomputed-form additions at the bounds of the operand contract (the
+# checks shared by the three backends run from tests/test_ed25519_jax.py)
+# ---------------------------------------------------------------------------
+
+def test_new_operations_at_operand_contract(monkeypatch):
+    """pt_madd, pt_to_cached and pt_add_cached with every coordinate at
+    the ends of the reduced band [-20, 51], signs mixed so sums and
+    differences both reach their extremes: every fe_mul operand pair
+    stays within |a|inf * |b|inf <= 17641 in both orders of the sign."""
+    alt = np.where(np.arange(fe.NLIMBS) % 2 == 0, 51.0, -20.0)
+    patterns = [np.full(fe.NLIMBS, 51.0), np.full(fe.NLIMBS, -20.0),
+                alt, alt[::-1].copy()]
+    patterns = [p.astype(np.float32) for p in patterns]
+    cached = kernel_cases.check_products_at_bounds(
+        fe, monkeypatch, patterns,
+        lambda a, b: np.abs(a).max() * np.abs(b).max() <= 17641)
+    for c in cached:
+        c = np.asarray(c)
+        assert c.min() >= -20 and c.max() <= 51
 
 
 # ---------------------------------------------------------------------------

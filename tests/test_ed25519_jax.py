@@ -15,6 +15,8 @@ jax = pytest.importorskip("jax")
 from tendermint_tpu.ops import ed25519_jax as dev  # noqa: E402
 from tendermint_tpu.ops import fe25519 as fe  # noqa: E402
 
+import kernel_cases  # noqa: E402
+
 
 # ---------------------------------------------------------------------------
 # Field-level fuzz vs big-int arithmetic
@@ -111,6 +113,71 @@ def test_point_add_matches_reference():
         wzi = pow(want[2], ref.P - 2, ref.P)
         assert gx == want[0] * wzi % ref.P
         assert gy == want[1] * wzi % ref.P
+
+
+# ---------------------------------------------------------------------------
+# Signed digits and precomputed-form additions, on the three field backends
+# (tests/kernel_cases.py holds the checks; each backend's own file holds them
+# to its operand contract at the bounds)
+# ---------------------------------------------------------------------------
+
+IMPLS = pytest.mark.parametrize("impl", dev.IMPLS)
+SIGNS = pytest.mark.parametrize("sign", [None, 1, -1])
+
+
+@IMPLS
+def test_signed_digits_exact(impl):
+    kernel_cases.check_signed_digits(impl)
+
+
+@IMPLS
+@SIGNS
+def test_pt_madd_matches_reference(impl, sign):
+    kernel_cases.check_pt_madd(impl, sign)
+
+
+@IMPLS
+@SIGNS
+def test_pt_add_cached_matches_reference(impl, sign):
+    kernel_cases.check_pt_add_cached(impl, sign)
+
+
+@IMPLS
+def test_precomputed_additions_sign_is_per_row(impl):
+    kernel_cases.check_mixed_signs(impl)
+
+
+@IMPLS
+def test_scalarmul_base_matches_reference(impl):
+    kernel_cases.check_scalarmul_base(impl)
+
+
+@pytest.mark.parametrize("impl", [
+    "int64", "packed",
+    # a 75 s XLA-CPU compile of the 51-limb loop body; tier-1 covers the
+    # f32 loop end to end (test_differential_vs_reference_f32)
+    pytest.param("f32", marks=pytest.mark.slow)])
+def test_scalarmul_var_matches_reference(impl):
+    kernel_cases.check_scalarmul_var(impl)
+
+
+@IMPLS
+def test_field_operation_counts(impl, monkeypatch):
+    assert kernel_cases.check_op_counts(impl, monkeypatch) == \
+        kernel_cases.OPS_PER_SIGNATURE
+
+
+def test_new_operations_at_input_ceiling(monkeypatch):
+    """Reduced limbs at their ceiling (< 2^17.3) in every coordinate:
+    every fe_mul operand stays under the 2^20 input ceiling, in both
+    orders of the sign."""
+    top = np.full(fe.NLIMBS, 161_000, dtype=np.int64)
+    patterns = [top, fe.limbs_from_int(ref.P - 1), fe.ZERO, fe.ONE]
+    cached = kernel_cases.check_products_at_bounds(
+        fe, monkeypatch, patterns,
+        lambda a, b: a.max() < (1 << 20) and b.max() < (1 << 20))
+    for c in cached:
+        assert 0 <= np.asarray(c).min() and np.asarray(c).max() < 2 ** 17.3
 
 
 # ---------------------------------------------------------------------------
@@ -261,7 +328,8 @@ def test_scalarmul_base_mxu_matches_tree_and_reference():
         core = dev._Core(dev._field(impl))
         f = core.fe
         s_rows = jnp.asarray(s_rows_np)
-        p_tree = core._scalarmul_base(core._nibbles_of(s_rows))
+        p_tree = core._scalarmul_base(
+            core._signed_digits(core._nibbles_of(s_rows)))
         p_mxu = core._scalarmul_base_mxu(s_rows)
         ex = np.asarray(f.fe_eq(f.fe_mul(p_tree.x, p_mxu.z),
                                 f.fe_mul(p_mxu.x, p_tree.z)))

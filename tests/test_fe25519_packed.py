@@ -26,6 +26,8 @@ import jax.numpy as jnp  # noqa: E402
 from tendermint_tpu.ops import ed25519_jax as dev  # noqa: E402
 from tendermint_tpu.ops import fe25519_packed as fe  # noqa: E402
 
+import kernel_cases  # noqa: E402
+
 slow = pytest.mark.slow
 
 
@@ -275,6 +277,86 @@ def test_pt_dbl_n_matches_chained():
     for _ in range(4):
         chained = fe.pt_dbl(chained)
     assert _affine(fe.pt_dbl_n(_to_dev(p), 4)) == _affine(chained)
+
+
+# ---------------------------------------------------------------------------
+# The precomputed-form additions at the bounds of the operand contract (the
+# checks shared by the three backends run from tests/test_ed25519_jax.py)
+# ---------------------------------------------------------------------------
+
+def _reduced_ceiling():
+    return np.array([(1 << w) + 63 for w in fe.LIMB_WIDTHS], dtype=np.int64)
+
+
+def test_new_operations_at_pairwise_bound(monkeypatch):
+    """pt_madd, pt_to_cached and pt_add_cached with every coordinate at
+    the reduced ceiling (even limbs 2^26 + 63, odd 2^25 + 63): every
+    product meets the pairwise 2^54.9 contract in BOTH orders of the
+    sign (the ledger of _add_tail), and no column wraps."""
+    patterns = [_reduced_ceiling(), fe.limbs_from_int(ref.P - 1),
+                fe.ZERO, fe.ONE]
+    cached = kernel_cases.check_products_at_bounds(
+        fe, monkeypatch, patterns,
+        lambda a, b: float(a.max()) * float(b.max()) <= 2 ** 54.9)
+    # pt_to_cached's outputs are reduced
+    ceiling = _reduced_ceiling()
+    for c in cached:
+        c = np.asarray(c)
+        assert c.min() >= 0 and (c <= ceiling).all()
+
+
+def test_unswapped_g_would_break_the_contract():
+    """Why _add_tail carries g when a sign is given: the raw difference
+    d2 + 2p - c against h = 2R is past the pairwise contract."""
+    r = float((1 << 26) + 63)
+    raw_f = 2 * r + float(fe._2P.max())
+    assert raw_f * (2 * r) > 2 ** 54.9
+    assert (3 * r) * (2 * r) <= 2 ** 54.9  # g = d2 + c as pt_add leaves it
+
+
+# ---------------------------------------------------------------------------
+# The program as the TPU's compiler leaves it (no chip: a described v5e)
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def one_v5e_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu, or it is held
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@slow
+@pytest.mark.parametrize("rung", [768, 10240])
+def test_no_field_operation_round_trips_through_hbm(one_v5e_chip, rung):
+    """PR 29's finding (PERF.md section 6): the compiler's memory-space
+    assignment has a second regime in which the product columns of EVERY
+    field operation are copied out to HBM and sliced back — a
+    `slice-start` per operation in the compiled text, 40 % on a squaring
+    — and which of the two a program gets turned on how the 8-entry
+    table of -A was built.  The program that is served has none.  ~2.5
+    min a rung (no chip needed, nothing is run), hence slow."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    rows = jax.ShapeDtypeStruct((rung, 32), jnp.uint8, sharding=one_v5e_chip)
+    valid = jax.ShapeDtypeStruct((rung,), jnp.bool_, sharding=one_v5e_chip)
+    # a compile for a described chip can be written to the persistent
+    # cache but never read back: keep it out
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = dev._jit_for("verify", "packed", donate=True).lower(
+            rows, rows, rows, rows, valid).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    assert text.count(" while(") == 20  # 18 square chains, base, var
+    assert text.count("slice-start(") == 0
 
 
 # ---------------------------------------------------------------------------
