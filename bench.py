@@ -32,8 +32,7 @@ Env knobs:
   TM_BENCH_SHOOTOUT_N      impl-shootout batch size (default 1024 cpu /
                            4096 device; bucketed to the active plan)
   TM_BENCH_SHOOTOUT_IMPLS  comma list for the impl-shootout stage
-                           (default "int64,packed" cpu /
-                           "int64,packed,f32" device)
+                           (default "int64,packed")
 """
 
 import json
@@ -131,7 +130,7 @@ def _stage_set(name: str) -> None:
 def _deadline_left() -> float:
     """Seconds of watchdog budget remaining.  Optional stages budget
     themselves against this (the round-5 driver run overran the 480 s deadline inside
-    timed-throughput-rlc and the artifact line reported the watchdog
+    an optional stage and the artifact line reported the watchdog
     error instead of the already-measured headline): a stage that cannot
     afford its runs skips or shrinks, so the final JSON reports clean."""
     return DEADLINE - (time.monotonic() - _t_start)
@@ -306,7 +305,7 @@ def main() -> int:
         # metrics: accepted-tx/s under faults, heights/min, the longest
         # consecutive rounds>0 streak, and recovery time after heal.
         # Runs BEFORE the device stages: in the round-5 driver run the watchdog fired
-        # mid-RLC and every later stage never landed, so a tail position
+        # in a device stage and every later stage never landed, so a tail position
         # would silently drop these keys.  Budgeted: the scenario's own
         # max_runtime is capped so the device stages keep >=300s, and
         # the stage skips outright when too little is left.
@@ -437,7 +436,7 @@ def main() -> int:
         # accepted-tx/s.  The metric keys end in _ms so benchdiff tracks
         # them in the latency class (10% rel threshold).  Placed BEFORE
         # the device stages with the simnet stage (the round-5 driver run lesson:
-        # tail stages silently vanish when the watchdog fires mid-RLC),
+        # tail stages silently vanish when the watchdog fires),
         # and budgeted so the device pipeline keeps its reserve.
         _stage_set("tx-latency")
         try:
@@ -545,8 +544,7 @@ def main() -> int:
             _partial["fleet_scrape_error"] = str(e)[-300:]
 
         # -- impl shootout (round 9, ISSUE 12): the field-representation
-        # comparison int64 vs packed vs f32(+MXU where the golden gate
-        # validates it) on ONE rung, timed side by side, with each
+        # comparison int64 vs packed on ONE rung, timed side by side, with each
         # impl's HLO bytes/row and FLOPs/row from the cost harvest — the
         # steering metrics of the representation attack, landing in
         # benchdiff's tracked set (_sigs_per_sec / _bytes_per_row rules)
@@ -563,10 +561,8 @@ def main() -> int:
                 "1024" if platform == "cpu" else "4096"))
             sn = max(8, min(sn, N))
             shoot_rung = _dev9._bucket(sn)
-            default_impls = ("int64,packed" if platform == "cpu"
-                             else "int64,packed,f32")
             impls_s = [i.strip() for i in os.environ.get(
-                "TM_BENCH_SHOOTOUT_IMPLS", default_impls).split(",")
+                "TM_BENCH_SHOOTOUT_IMPLS", "int64,packed").split(",")
                 if i.strip()]
             shoot_runs = max(2, min(TIMED_RUNS, 3))
             # the reserve keeps the production headline + device stages
@@ -890,22 +886,17 @@ def main() -> int:
             except Exception as e:  # noqa: BLE001
                 _partial["xla_cpu_device_error"] = str(e)[-300:]
         else:
-            # Device headline path.  Round 3 added a second field backend
-            # (f32 radix-5, ops/fe25519_f32.py) shaped for the TPU's
-            # native-float VPU; measure both and let the faster one carry
-            # the headline so the bench self-tunes to the hardware it
-            # lands on.
+            # Device headline path: every impl in TM_BENCH_FIELD_IMPLS is
+            # measured and the faster one carries the headline.
             from tendermint_tpu.ops import ed25519_jax as dev
 
             _stage_set("smoke-n8")
             ok = dev.verify_batch(pubs[:8], msgs[:8], sigs[:8])
             assert ok.all(), "n=8 smoke verification failed"
 
-            # int64 only by default: the r4 hardware sweep (kernel_bench,
-            # benchmarks/tpu_kernel_r04.jsonl) measured f32 radix-5 at
-            # 3.2x slower on real TPU, and measuring it here cost ~260 s
-            # of the 480 s watchdog budget.  TM_BENCH_FIELD_IMPLS=int64,f32
-            # restores the sweep.
+            # int64 only by default: a second impl's rungs cost a cold
+            # compile of the 480 s watchdog budget.
+            # TM_BENCH_FIELD_IMPLS=int64,packed measures both.
             impls = os.environ.get("TM_BENCH_FIELD_IMPLS", "int64").split(",")
             ours = 0.0
             p50_ms = None
@@ -963,8 +954,8 @@ def main() -> int:
                 )
                 _partial["baseline_sampling"] = "interleaved-pair-median"
             # Device-only 10k-commit latency (VERDICT r4 item 2): rows
-            # prepared and placed on device ONCE, then only the compiled
-            # chunk programs + the verdict-bit readback are timed — the
+            # prepared ONCE and placed on device, then only the compiled
+            # program + the verdict-bit readback are timed — the
             # device's share of the end-to-end p50 reported beside it.
             _stage_set("timed-commit-device-only")
             try:
@@ -976,160 +967,33 @@ def main() -> int:
                 import jax as _jax
 
                 impl0 = _partial.get("field_impl", "int64")
-                if impl0 in ("int64", "f32"):
-                    cn = min(COMMIT_N, N)
-                    rows = dev.prepare_batch(pubs[:cn], msgs[:cn], sigs[:cn])
-                    chunk = dev._chunk_size()
-                    plan = (dev.chunks_of(cn, chunk)
-                            if chunk and cn > chunk
-                            else [(0, cn, dev._bucket(cn))])
-                    padded_np = []
-                    for start, end, b in plan:
-                        sub = tuple(r[start:end] for r in rows)
-                        padded_np.append(
-                            (dev._pad_rows(end - start, b, *sub),
-                             b, end - start))
+                cn = min(COMMIT_N, N)
+                b = dev._bucket(cn)
+                padded_np = dev._pad_rows(
+                    cn, b, *dev.prepare_batch(pubs[:cn], msgs[:cn], sigs[:cn]))
 
-                    # donated row buffers (ISSUE 7) mean a device array
-                    # is DELETED by the call that consumes it, so the
-                    # pre-placed inputs are re-placed per run — the
-                    # device_put stays OUTSIDE the timed window, which
-                    # is exactly the device-only semantics this stage
-                    # has always measured
-                    def _place():
-                        return [([_jax.device_put(_np.asarray(x))
-                                  for x in padded], b, m)
-                                for padded, b, m in padded_np]
+                # donated row buffers (ISSUE 7) mean a device array
+                # is DELETED by the call that consumes it, so the
+                # pre-placed inputs are re-placed per run — the
+                # device_put stays OUTSIDE the timed window, which
+                # is exactly the device-only semantics this stage
+                # has always measured
+                def _place():
+                    return [_jax.device_put(x) for x in padded_np]
 
-                    for inputs, b, _m in _place():  # warm every bucket
-                        _np.asarray(dev._compiled(b, impl0)(*inputs))
-                    lat = []
-                    for _ in range(5):
-                        placed = _place()
-                        t0 = time.perf_counter()
-                        enq = [(dev._compiled(b, impl0)(*inputs), m)
-                               for inputs, b, m in placed]
-                        okd = _np.concatenate(
-                            [_np.asarray(o)[:m] for o, m in enq])
-                        lat.append(time.perf_counter() - t0)
-                        assert okd.all()
-                    _partial["commit10k_device_only_p50_ms"] = round(
-                        statistics.median(lat) * 1e3, 3)
-                    _partial["commit10k_chunk_plan"] = [
-                        [b, m] for _padded, b, m in padded_np]
+                _np.asarray(dev._compiled(b, impl0)(*_place()))  # warm
+                lat = []
+                for _ in range(5):
+                    placed = _place()
+                    t0 = time.perf_counter()
+                    okd = _np.asarray(dev._compiled(b, impl0)(*placed))[:cn]
+                    lat.append(time.perf_counter() - t0)
+                    assert okd.all()
+                _partial["commit10k_device_only_p50_ms"] = round(
+                    statistics.median(lat) * 1e3, 3)
             except Exception as e:  # noqa: BLE001
                 _partial["commit10k_device_only_error"] = str(e)[-300:]
 
-            # Round 4: the RLC batch equation (ops/ed25519_jax.verify_batch_rlc,
-            # shared-doubling Straus — an exactly-tested OPT-IN, measured
-            # slower than per-row on r4 TPU and therefore NOT the
-            # production default; see crypto/batch.py) competes for the
-            # headline so each round's artifact re-records the comparison.
-            _stage_set("warmup-rlc-n%d" % N)
-            try:
-                # optional stage: never let it threaten the headline's
-                # spot inside the watchdog budget (cold-process compile
-                # loads can eat ~40 s; the int64 headline must be
-                # emitted whole)
-                if time.monotonic() - _t_start > 0.55 * DEADLINE:
-                    raise RuntimeError(
-                        "skipped: %.0fs elapsed of %.0fs budget"
-                        % (time.monotonic() - _t_start, DEADLINE)
-                    )
-                # Warm the RLC rungs through the shape plan FIRST, and
-                # budget the compile SEPARATELY from the timed window
-                # (ISSUE 7; the round-5 driver run tripped its 480 s watchdog inside
-                # timed-throughput-rlc because fresh traces and timing
-                # shared one budget).  After this, warm_dt below is a
-                # pure run — so the affordable-runs arithmetic stops
-                # being inflated by compile cost.
-                from tendermint_tpu.ops import shape_plan as _sp
-
-                impl_rlc = dev.default_impl()
-                t_wc = time.perf_counter()
-                wrep = _sp.warm_rungs(
-                    kinds=("rlc",),
-                    rungs=sorted({dev._bucket(N),
-                                  dev._bucket(min(COMMIT_N, N))}),
-                    impls=(impl_rlc,), serialize=False)
-                _partial["rlc_warm_compile_s"] = round(
-                    time.perf_counter() - t_wc, 3)
-                _partial["rlc_warm_sources"] = {
-                    str(e["rung"]): e["source"] for e in wrep}
-
-                t_warm = time.perf_counter()
-                ok = dev.verify_batch_rlc(pubs, msgs, sigs)
-                warm_dt = time.perf_counter() - t_warm
-                assert ok.all(), "rlc warmup verification failed"
-
-                # budget the timed stages against the remaining deadline
-                # (the round-5 driver run overran HERE): each throughput run costs the
-                # run itself plus a matched-duration baseline window, so
-                # ~2x the measured warm run; keep a reserve for the
-                # emit path and shrink/skip instead of tripping the
-                # watchdog
-                reserve = 25.0
-                per_run = 2.0 * warm_dt
-                affordable = int(
-                    max(0.0, _deadline_left() - reserve) * 0.6 / max(per_run, 1e-6)
-                )
-                if affordable < 1:
-                    raise RuntimeError(
-                        "timed stage skipped: %.0fs left, run costs ~%.1fs"
-                        % (_deadline_left(), per_run)
-                    )
-                rlc_runs = min(TIMED_RUNS, affordable)
-                if rlc_runs < TIMED_RUNS:
-                    _partial["rlc_runs_shrunk_to"] = rlc_runs
-
-                _stage_set("timed-throughput-rlc")
-                times = []
-                rlc_pairs = []
-                for _ in range(rlc_runs):
-                    t0 = time.perf_counter()
-                    ok = dev.verify_batch_rlc(pubs, msgs, sigs)
-                    dt = time.perf_counter() - t0
-                    times.append(dt)
-                    base_rate = run_baseline_for(dt)
-                    rlc_pairs.append((N / dt, base_rate))
-                    assert ok.all()
-                rate = N / statistics.median(times)
-                _partial["rlc_sigs_per_sec"] = round(rate, 1)
-
-                cn = min(COMMIT_N, N)
-                lat_per_run = warm_dt * cn / N
-                lat_runs = min(
-                    max(TIMED_RUNS, 5),
-                    int(max(0.0, _deadline_left() - reserve) * 0.6
-                        / max(lat_per_run, 1e-6)),
-                )
-                rlc_p50 = None
-                if lat_runs >= 1:
-                    _stage_set("timed-commit-latency-rlc")
-                    lat = []
-                    for _ in range(lat_runs):
-                        t0 = time.perf_counter()
-                        ok = dev.verify_batch_rlc(pubs[:cn], msgs[:cn], sigs[:cn])
-                        lat.append(time.perf_counter() - t0)
-                        assert ok.all()
-                    rlc_p50 = statistics.median(lat) * 1e3
-                    _partial["rlc_commit_p50_ms"] = round(rlc_p50, 3)
-                else:
-                    _partial["rlc_commit_latency_skipped"] = (
-                        "budget: %.0fs left" % _deadline_left()
-                    )
-                # only a fully-measured RLC (throughput AND latency) may
-                # carry the headline — the headline's p50 key must never
-                # be missing
-                if rate > ours and rlc_p50 is not None:
-                    ours = rate
-                    p50_ms = rlc_p50
-                    headline_pairs = rlc_pairs
-                    _partial.update(
-                        {"value": round(ours, 1), "n": N, "field_impl": "rlc"}
-                    )
-            except Exception as e:  # noqa: BLE001
-                _partial["rlc_error"] = str(e)[-300:]
             if ours == 0.0:
                 raise RuntimeError("no field impl produced a device number")
             cn = min(COMMIT_N, N)

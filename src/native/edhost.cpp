@@ -327,93 +327,6 @@ void tmed_sha512(const uint8_t* data, uint64_t len, uint8_t out[64]) {
 }
 
 // ---------------------------------------------------------------------------
-// RLC batch-verification scalars: zk_i = z_i * k_i mod L and
-// c = sum_i z_i * s_i mod L (the random-linear-combination batch
-// equation in ops/ed25519_jax.verify_core_rlc; the Python big-int loop
-// costs ~1.5us/row — 15ms on a 10k commit, off the BASELINE budget).
-// z is 128-bit (2 LE limbs); rows with z = 0 are host-excluded and emit
-// zk = 0.  Reuses the Barrett mod_L above (input zero-extended to 8
-// limbs; z*k < 2^381 < 2^512).
-// ---------------------------------------------------------------------------
-
-static inline void load_le(const uint8_t* p, int nl, uint64_t* out) {
-  for (int j = 0; j < nl; j++) {
-    uint64_t v = 0;
-    for (int b = 7; b >= 0; b--) v = (v << 8) | p[8 * j + b];
-    out[j] = v;
-  }
-}
-
-static inline void store_le(const uint64_t* in, int nl, uint8_t* p) {
-  for (int j = 0; j < nl; j++)
-    for (int b = 0; b < 8; b++) p[8 * j + b] = (uint8_t)(in[j] >> (8 * b));
-}
-
-static inline void mul_2x4_modL(const uint64_t z[2], const uint64_t a[4],
-                                uint64_t out[4]) {
-  uint64_t prod[8] = {0};
-  for (int i = 0; i < 2; i++) {
-    u128 carry = 0;
-    for (int j = 0; j < 4; j++) {
-      u128 cur = (u128)z[i] * a[j] + prod[i + j] + carry;
-      prod[i + j] = (uint64_t)cur;
-      carry = cur >> 64;
-    }
-    prod[i + 4] += (uint64_t)carry;
-  }
-  mod_L(prod, out);
-}
-
-static inline void add4_modL(uint64_t acc[4], const uint64_t v[4]) {
-  u128 carry = 0;
-  uint64_t s[4];
-  for (int i = 0; i < 4; i++) {
-    u128 cur = (u128)acc[i] + v[i] + carry;
-    s[i] = (uint64_t)cur;
-    carry = cur >> 64;
-  }
-  // both inputs < L < 2^253 so the sum fits 4 limbs (no carry out) and
-  // is < 2L: one conditional subtract
-  bool ge = false;
-  for (int i = 3; i >= 0; i--) {
-    if (s[i] != L_LIMBS[i]) {
-      ge = s[i] > L_LIMBS[i];
-      break;
-    }
-    if (i == 0) ge = true;  // equal
-  }
-  if (ge) {
-    u128 borrow = 0;
-    for (int i = 0; i < 4; i++) {
-      u128 cur = (u128)s[i] - L_LIMBS[i] - borrow;
-      s[i] = (uint64_t)cur;
-      borrow = (cur >> 64) & 1;
-    }
-  }
-  memcpy(acc, s, sizeof s);
-}
-
-void tmed_rlc_scalars(uint64_t n, const uint8_t* z16, const uint8_t* k32,
-                      const uint8_t* s32, uint8_t* zk32, uint8_t* c32) {
-  uint64_t acc[4] = {0, 0, 0, 0};
-  for (uint64_t i = 0; i < n; i++) {
-    uint64_t z[2], k[4], s[4], zk[4], zs[4];
-    load_le(z16 + 16 * i, 2, z);
-    if (z[0] == 0 && z[1] == 0) {
-      memset(zk32 + 32 * i, 0, 32);
-      continue;
-    }
-    load_le(k32 + 32 * i, 4, k);
-    load_le(s32 + 32 * i, 4, s);
-    mul_2x4_modL(z, k, zk);
-    store_le(zk, 4, zk32 + 32 * i);
-    mul_2x4_modL(z, s, zs);
-    add4_modL(acc, zs);
-  }
-  store_le(acc, 4, c32);
-}
-
-// ---------------------------------------------------------------------------
 // Batched libcrypto Ed25519 verification
 //
 // The CPU production path (crypto/batch.py CPUBatchVerifier →

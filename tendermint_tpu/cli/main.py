@@ -1406,7 +1406,7 @@ def build_parser() -> argparse.ArgumentParser:
     sp.add_argument("--impls", default="",
                     help="comma-separated field impls (default: the plan's)")
     sp.add_argument("--kinds", default="",
-                    help="comma-separated program kinds: verify,rlc "
+                    help="comma-separated program kinds: verify "
                          "(default: the plan's)")
     sp.add_argument("--stats", default="",
                     help="devmon device_stats() JSON to tune the "
@@ -1433,7 +1433,7 @@ def build_parser() -> argparse.ArgumentParser:
     sp.add_argument("--impls", default="",
                     help="comma-separated field impls (default: the plan's)")
     sp.add_argument("--kinds", default="",
-                    help="comma-separated program kinds: verify,rlc "
+                    help="comma-separated program kinds: verify "
                          "(default: the plan's)")
     sp.add_argument("--runs", type=int, default=3,
                     help="timed runs per rung (default 3)")

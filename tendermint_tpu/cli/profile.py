@@ -26,7 +26,7 @@ by:
 Selection flags (`--rungs/--impls/--kinds`) mirror `tendermint-tpu
 warm`; the default is the ACTIVE shape plan, so a consolidated-plan
 deployment profiles exactly the programs it runs.  With 2+ impls
-selected (`--impls int64,packed,f32`) the output ends with a
+selected (`--impls int64,packed`) the output ends with a
 side-by-side **impl comparison table** — per (kind, rung): HLO
 bytes/row, FLOPs, wall p50 and sigs/s per impl plus ratios against the
 first impl — so a representation round (ISSUE 12) steers from one
@@ -100,9 +100,6 @@ def _synth_rows(kind: str, rung: int):
 
     u8 = np.zeros((rung, 32), dtype=np.uint8)
     valid = np.ones(rung, dtype=bool)
-    if kind == "rlc":
-        return (u8, u8.copy(), u8.copy(),
-                np.zeros((rung, 16), dtype=np.uint8), valid)
     return (u8, u8.copy(), u8.copy(), u8.copy(), valid)
 
 
@@ -119,8 +116,9 @@ def timed_window(kind: str, rung: int, impl: str, *, runs: int,
 
     from tendermint_tpu.ops import ed25519_jax as dev
 
-    fn = (dev._compiled_rlc(rung, impl, dev.rlc_reduce_lanes())
-          if kind == "rlc" else dev._compiled(rung, impl))
+    if kind != "verify":
+        raise ValueError(f"unknown program kind {kind!r}")
+    fn = dev._compiled(rung, impl)
     rows = _synth_rows(kind, rung)
 
     def _place():

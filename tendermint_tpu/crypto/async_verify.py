@@ -27,8 +27,7 @@ verification; PAPERS.md):
     compiled device program for batch i (JAX dispatch is async) and
     immediately starts host prep (sign-bytes SHA-512, s<L) for batch
     i+1; verdicts are drained when a second batch is in flight or the
-    queue runs dry.  Batches over TM_TPU_CHUNK reuse the r5 chunk
-    machinery (ops.ed25519_jax.chunks_of).
+    queue runs dry.  A flush is one program at its rung.
   * A bounded verified-signature LRU cache keyed by
     (pub, sha256(msg), sig) is consulted before enqueue — one bulk
     probe per submit, under one lock acquisition — and populated ONLY
@@ -122,7 +121,7 @@ VERIFY_HOST_PREP_SECONDS = Histogram(
     namespace="tendermint", subsystem="crypto", buckets=_FAST_BUCKETS)
 VERIFY_DEVICE_EXECUTE_SECONDS = Histogram(
     "verify_device_execute_seconds",
-    "Device enqueue to verdict readback per chunk, by bucket rung",
+    "Device enqueue to verdict readback per flush, by bucket rung",
     namespace="tendermint", subsystem="crypto", label_names=("rung",),
     buckets=_FAST_BUCKETS)
 VERIFY_E2E_SECONDS = Histogram(
@@ -200,16 +199,15 @@ class _Group:
 
 
 class _Batch:
-    """The rows of one flush, or of one chunk of it: the segments
-    `(group, start, end)` in row order, and the three lists a verify
-    path takes — the group's own when the batch is one whole group (a
-    commit's case: no copy), else the segments' slices concatenated."""
+    """The rows of one flush: the segments `(group, start, end)` in
+    row order, and the three lists a verify path takes — the group's
+    own when the batch is one whole group (a commit's case: no copy),
+    else the segments' slices concatenated."""
 
-    __slots__ = ("segs", "pubs", "msgs", "sigs", "enqueued")
+    __slots__ = ("segs", "pubs", "msgs", "sigs")
 
     def __init__(self, segs: list):
         self.segs = segs
-        self.enqueued = 0  # rows already in flight on the device
         g, a, b = segs[0]
         if len(segs) == 1 and a == 0 and b == len(g.keys):
             self.pubs, self.msgs, self.sigs = g.pubs, g.msgs, g.sigs
@@ -220,18 +218,6 @@ class _Batch:
 
     def __len__(self) -> int:
         return len(self.pubs)
-
-    def cut(self, start: int, end: int) -> "_Batch":
-        """Rows [start, end) as a batch of their own."""
-        if start == 0 and end == len(self):
-            return self
-        segs, at = [], 0
-        for g, a, b in self.segs:
-            lo, hi = max(a, a + start - at), min(b, a + end - at)
-            if lo < hi:
-                segs.append((g, lo, hi))
-            at += b - a
-        return _Batch(segs)
 
 
 class VerifiedSigCache:
@@ -596,8 +582,8 @@ class VerifyService:
             self._host_verify(batch)
             return "host", "device_not_ready"
         mixed = any(len(p) != 32 for p in batch.pubs)
-        if mixed or os.environ.get("TM_TPU_RLC", "0") == "1":
-            # rarer shapes (secp-mixed batches, RLC) run the existing
+        if mixed:
+            # the rarer shape (a secp-mixed batch) runs the existing
             # synchronous routing — bit-identical verdicts, no pipelining
             self._sync_device_verify(batch, bv)
             return "device", "sync_routing"
@@ -627,8 +613,7 @@ class VerifyService:
             return "device", "pipelined"
         except Exception:  # noqa: BLE001 — device failure: host fallback
             self._device_error("enqueue", n)
-            # the chunks already in flight keep their device verdicts
-            self._host_verify(batch.cut(batch.enqueued, n))
+            self._host_verify(batch)
             return "host", "device_error"
 
     def _device_error(self, site: str, n: int) -> None:
@@ -648,41 +633,34 @@ class VerifyService:
                          "only", site, n, exc_info=True)
 
     def _enqueue_device(self, batch: _Batch, inflight: deque) -> None:
-        """Host prep + async enqueue of the per-row device program,
-        chunked via the r5 machinery when TM_TPU_CHUNK is set.  Verdict
-        readback happens in _drain_one — by then the worker has already
-        host-prepped the NEXT batch behind the executing one.
-        `batch.enqueued` says how far it got if a chunk raises."""
+        """Host prep + async enqueue of the per-row device program at
+        the flush's rung.  Verdict readback happens in _drain_one — by
+        then the worker has already host-prepped the NEXT batch behind
+        the executing one."""
         from tendermint_tpu.ops import ed25519_jax as dev
 
         n = len(batch)
         flush = self._flush_no
         impl = dev.default_impl()
-        base_mxu = dev._resolve_optin(impl)
-        chunk = dev._chunk_size()
-        plan = (dev.chunks_of(n, chunk) if chunk and n > chunk
-                else [(0, n, dev._bucket(n))])
-        for start, end, b in plan:
-            sub = batch.cut(start, end)
-            t_prep = time.perf_counter()
-            rows = dev.prepare_batch(sub.pubs, sub.msgs, sub.sigs)
-            padded = dev._pad_rows(end - start, b, *rows)
-            prep_dt = time.perf_counter() - t_prep
-            VERIFY_HOST_PREP_SECONDS.observe(prep_dt)
-            if _trace.enabled():
-                _trace.record("verify.host_prep", t_prep, prep_dt,
-                              n=end - start, rung=b, flush=flush)
-            if _devmon.STATS.enabled:
-                _mesh.record_pinned_flush(
-                    end - start, b, nbytes=sum(a.nbytes for a in padded))
-            while len(inflight) >= 2:
-                self._drain_one(inflight)
-            t_enq = time.perf_counter()
-            pending = dev._compiled(b, impl, base_mxu)(*padded)
-            inflight.append((pending, sub, t_enq, b, flush))
-            batch.enqueued = end
-            with self._cv:
-                self.stats["device_batches"] += 1
+        b = dev._bucket(n)
+        t_prep = time.perf_counter()
+        rows = dev.prepare_batch(batch.pubs, batch.msgs, batch.sigs)
+        padded = dev._pad_rows(n, b, *rows)
+        prep_dt = time.perf_counter() - t_prep
+        VERIFY_HOST_PREP_SECONDS.observe(prep_dt)
+        if _trace.enabled():
+            _trace.record("verify.host_prep", t_prep, prep_dt,
+                          n=n, rung=b, flush=flush)
+        if _devmon.STATS.enabled:
+            _mesh.record_pinned_flush(
+                n, b, nbytes=sum(a.nbytes for a in padded))
+        while len(inflight) >= 2:
+            self._drain_one(inflight)
+        t_enq = time.perf_counter()
+        pending = dev._compiled(b, impl)(*padded)
+        inflight.append((pending, batch, t_enq, b, flush))
+        with self._cv:
+            self.stats["device_batches"] += 1
 
     def _enqueue_sharded(self, batch: _Batch, inflight: deque,
                          m: int) -> None:

@@ -473,18 +473,7 @@ class JAXBatchVerifier(_BaseBatch):
 
     def _ed_batch(self, pubs, msgs, sigs) -> list[bool]:
         """The ed25519-only core: device program (sharded on a mesh) or
-        host fallback below the dispatch threshold.
-
-        TM_TPU_RLC=1 routes device batches through the RLC batch
-        equation (ops.ed25519_jax.verify_batch_rlc — shared-doubling
-        Straus, the same cofactored check as the reference's batch
-        verifier, with exact per-row fallback so verdicts stay
-        bit-identical).  It is OFF by default: despite ~2x fewer
-        point-op flops, the per-window cross-batch reductions are
-        latency-bound on TPU and measured SLOWER than the uniform
-        per-row program at every accumulator width
-        (benchmarks/tpu_rlc_r04.jsonl, r4: 511-668 ms vs 313-338 ms at
-        16384; docs/tpu-verifier.md records the analysis)."""
+        host fallback below the dispatch threshold."""
         if len(pubs) < self._resolved_threshold(len(pubs)):
             return _ed.verify_batch_fast(pubs, msgs, sigs)
         if not _DEVICE_READY.is_set():
@@ -508,16 +497,10 @@ class JAXBatchVerifier(_BaseBatch):
                 "tm-tpu: first device dispatch n=%d backend=%s threshold=%s\n"
                 % (len(pubs), jax.default_backend(), self.cpu_threshold))
             sys.stderr.flush()
-        rlc = os.environ.get("TM_TPU_RLC", "0") == "1"
         if self._device_count() > 1:
             from tendermint_tpu.parallel import sharding
 
-            if rlc:
-                oks = sharding.verify_batch_rlc_sharded(pubs, msgs, sigs)
-            else:
-                oks = sharding.verify_batch_sharded(pubs, msgs, sigs)
-        elif rlc:
-            oks = self._impl.verify_batch_rlc(pubs, msgs, sigs)
+            oks = sharding.verify_batch_sharded(pubs, msgs, sigs)
         else:
             oks = self._impl.verify_batch(pubs, msgs, sigs)
         return [bool(v) for v in oks]

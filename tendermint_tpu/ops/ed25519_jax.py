@@ -44,24 +44,18 @@ The signed recoding keeps that: digits sum to k itself, nothing is reduced.
 
 Field backends (TM_TPU_FIELD_IMPL, or the `impl=` argument):
   * "int64"  — 15 limbs × 17 bits in int64 lanes (fe25519.py).  The
-    historical default; ideal bit-density for a 64-bit integer machine
-    but ~47 dead bits per lane of HLO traffic.
+    XLA-CPU default (every tier-1 program) and the fallback of the
+    start-up golden check.
   * "packed" — 10 limbs at the mixed radix 25.5 in int64 lanes
-    (fe25519_packed.py, round 9).  Same integer datapath, 33% fewer
-    bytes per limb tensor and ~2.2x fewer limb products — the
-    representation attack on the PR 8 roofline (AI ≈ 0.03 FLOP/B:
-    the limb encoding IS the traffic).
-  * "f32"    — 51 limbs × 5 bits in f32 lanes (fe25519_f32.py).  Every op
-    is a native float multiply/add/floor — the round-3 TPU datapath
-    redesign; with TM_TPU_FE_MXU its fe_mul contracts on the MXU.
-TM_TPU_FIELD_IMPL also accepts "auto" (the default since round 9):
-XLA-CPU resolves to "int64" with no golden run (tier-1 warm cache keys
-stay bit-identical); accelerator backends run the golden differential
-check once at startup and take packed where it validates, else int64
-(see _resolve_auto_impl).  f32 (with its MXU fe_mul) is explicit-only:
-it computed wrong verdicts on every TPU that ran it.
-The curve/scalar pipeline below is field-agnostic; all backends share it
-and all are differentially tested against the pure ZIP-215 reference.
+    (fe25519_packed.py).  Same integer datapath, 33% fewer bytes per
+    limb tensor and ~2.2x fewer limb products; what an accelerator runs.
+TM_TPU_FIELD_IMPL also accepts "auto" (the default, and what any other
+value reads as): XLA-CPU resolves to "int64" with no golden run (tier-1
+warm cache keys stay bit-identical); accelerator backends run the golden
+differential check once at startup and take packed where it validates,
+else int64 (see _resolve_auto_impl).
+The curve/scalar pipeline below is field-agnostic; both backends share it
+and both are differentially tested against the pure ZIP-215 reference.
 
 Static batch sizes: inputs are padded to a bucket ladder — the ACTIVE
 shape plan (ops/shape_plan.py; default: the formula ladder of powers of
@@ -71,11 +65,10 @@ OR ahead of time: `tendermint-tpu warm` / the shape plan's background
 warm pre-builds (and serializes) every plan rung's executable, so a warm
 node never pays a first-call compile (first call per bucket pays compile
 otherwise; consensus reuses steady-state buckets) with measured
-worst-case padding 1.49x (n=129→192;
-<=1.34x for n>=321 — ADVICE r5: the 1.33x previously stated here holds
-only above the 320 rung); batches over TM_TPU_CHUNK dispatch as a
-pipeline of sub-batches (host prep overlaps device execution — see
-verify_batch).
+worst-case padding 1.49x (n=129→192; <=1.34x for n>=321).  A flush is
+one program: a second program in a flush costs its fixed latency
+(≈ 27.7 ms on a v5e, PERF.md section 5) to hide at most a few ms of host
+prep.
 """
 
 from __future__ import annotations
@@ -98,7 +91,7 @@ SCALAR_BITS = 253  # s, k < L < 2^253
 
 NWINDOWS = 64  # 253-bit scalars as 64 little-endian radix-16 digits
 
-IMPLS = ("int64", "f32", "packed")
+IMPLS = ("int64", "packed")
 
 # TM_TPU_FIELD_IMPL=auto resolution, memoized per process (the
 # TM_TPU_DONATE=auto idiom): None = not yet resolved.  Resolved lazily at
@@ -125,14 +118,6 @@ def _resolve_auto_impl() -> str:
     An accelerator: the packed int64 layout if it reproduces the golden
     verdicts on THIS device, else the historical int64 layout as the
     unconditional fallback.
-
-    f32 with its MXU fe_mul is NOT a candidate: Precision.HIGHEST
-    matmuls are not exact on the TPU, and the path returned wrong
-    verdicts on both chips that ever ran it (round 4; and PR 21 on
-    "TPU v5 lite", where its 8-row golden came back all-False).  The
-    golden gate did refuse it — after a ~170 s cold compile that every
-    start of every node paid only to learn what is already known.
-    TM_TPU_FIELD_IMPL=f32 still selects it, golden-gated as before.
     Which of packed/int64 SHOULD lead is a question of chip timings
     this function does not try to answer."""
     if jax.default_backend() == "cpu":
@@ -143,12 +128,14 @@ def _resolve_auto_impl() -> str:
 
 
 def _field(impl: str):
-    if impl == "f32":
-        from . import fe25519_f32 as m
-    elif impl == "packed":
+    if impl == "packed":
         from . import fe25519_packed as m
-    else:
+    elif impl == "int64":
         from . import fe25519 as m
+    else:
+        # a saved plan or `warm --impls` can name anything: never run
+        # one backend under another's label
+        raise ValueError(f"unknown field impl {impl!r} (known: {IMPLS})")
     return m
 
 
@@ -170,37 +157,6 @@ def _base_point_table() -> list[list[tuple[int, int, int]]]:
             pt = _ref.pt_add(pt, g)
         rows.append(row)
         g = _ref.scalar_mult(16, g)
-    return rows
-
-
-# Opt-in MXU path for the fixed-base scalar mult: selection from a SHARED
-# constant table is the one shape in this kernel with a genuine shared
-# contraction dimension (docs/tpu-verifier.md "The MXU question, answered
-# with arithmetic" names it as the open avenue).  Default off; resolved
-# per call (not at import) and, in production paths, gated behind the
-# golden-batch self-check below — the sibling TM_TPU_FE_MXU path was
-# measured returning WRONG verdicts on real TPU (Precision.HIGHEST f32
-# matmul exactness does not hold there), so no opt-in kernel flag is
-# trusted until it reproduces known verdicts on the device it runs on
-# (VERDICT r4 item 6).
-def _base_mxu_requested() -> bool:
-    return os.environ.get("TM_TPU_BASE_MXU", "0") == "1"
-
-
-@functools.cache
-def _base_point_table256() -> list[list[tuple[int, int, int, int]]]:
-    """[j * 256^i]B for i in 0..31, j in 0..255 — the w=8 comb the MXU
-    one-hot path uses (the signature's s bytes ARE its radix-256 digits).
-    Built iteratively (adds/doublings), not 8192 scalar_mults."""
-    rows = []
-    g = _ref.BASE
-    for _i in range(32):
-        row = [_ref.IDENTITY]
-        for _j in range(255):
-            row.append(_ref.pt_add(row[-1], g))
-        rows.append(row)
-        for _ in range(8):
-            g = _ref.pt_double(g)
     return rows
 
 
@@ -275,17 +231,6 @@ class _Core:
         x = jnp.where(flip[..., None], fe.fe_carry(fe.fe_neg(cx)), cx)
         yr = fe.fe_canonical(y)
         return fe.Pt(x, yr, jnp.broadcast_to(jnp.asarray(fe.ONE), yr.shape), fe.fe_mul(x, yr)), ok
-
-    def _select16(self, digit: jnp.ndarray, tbl: list):
-        """tbl[digit] per batch element via a 4-level binary select tree
-        (15 pt_selects — elementwise, no gathers)."""
-        fe = self.fe
-        cur = list(tbl)
-        for b in range(4):
-            bit = (digit >> b) & 1
-            cur = [fe.pt_select(bit, cur[2 * i + 1], cur[2 * i])
-                   for i in range(len(cur) // 2)]
-        return cur[0]
 
     @staticmethod
     def _signed_digits(nibbles: jnp.ndarray) -> jnp.ndarray:
@@ -396,234 +341,10 @@ class _Core:
         return lax.fori_loop(0, NWINDOWS, body,
                              fe.pt_identity(digits.shape[:-1]))
 
-    @functools.cached_property
-    def _fixed_base_tables256(self) -> np.ndarray:
-        """The w=8 comb table as ONE [32, 256, 4*NLIMBS] float32 tensor
-        (limb values in this backend's radix; int64-backend limbs < 2^18
-        and f32-backend limbs < 2^5 are both f32-exact — the packed
-        backend's 26-bit limbs are NOT, which is why _resolve_optin
-        never routes base_mxu to it).  numpy, not jnp — converted
-        per-trace like _fixed_base_tables."""
-        fe = self.fe
-        out = np.zeros((32, 256, 4 * fe.NLIMBS), dtype=np.float32)
-        for i, row in enumerate(_base_point_table256()):
-            for j, pt in enumerate(row):
-                for c in range(4):
-                    out[i, j, c * fe.NLIMBS:(c + 1) * fe.NLIMBS] = np.asarray(
-                        fe.limbs_from_int(pt[c]), dtype=np.float64
-                    )
-        return out
-
-    def _scalarmul_base_mxu(self, s_rows: jnp.ndarray):
-        """[s]B via one-hot × constant-table matmuls (w=8 comb): the
-        signature's 32 s bytes are its radix-256 digits, each window
-        selects from a SHARED 256-entry table — one_hot[N,256] @
-        table[256, 4*NLIMBS] has a true shared contraction dimension,
-        the one shape here the MXU can genuinely accelerate
-        (docs/tpu-verifier.md).  Halves the fixed-base adds (32 vs 64)
-        as a bonus.  Exactness: exactly one nonzero per one-hot row and
-        every table entry is f32-exact, so each output IS the selected
-        limb; Precision.HIGHEST keeps TPU matmuls in (6-pass emulated)
-        f32 rather than raw bf16."""
-        fe = self.fe
-        tbl = jnp.asarray(self._fixed_base_tables256)  # [32,256,4*NLIMBS] f32
-        out_dtype = jnp.asarray(fe.ONE).dtype
-        shape = s_rows.shape[:-1]
-
-        def sel(i, acc_unused=None):
-            digit = jnp.take(s_rows, i, axis=-1).astype(jnp.int32)
-            oh = (digit[..., None] == jnp.arange(256, dtype=jnp.int32)).astype(
-                jnp.float32
-            )
-            flat = lax.dot_general(
-                oh,
-                jnp.take(tbl, i, axis=0),
-                (((oh.ndim - 1,), (0,)), ((), ())),
-                precision=lax.Precision.HIGHEST,
-                preferred_element_type=jnp.float32,
-            )
-            c = flat.reshape(shape + (4, fe.NLIMBS)).astype(out_dtype)
-            return fe.Pt(c[..., 0, :], c[..., 1, :], c[..., 2, :], c[..., 3, :])
-
-        def body(i, acc):
-            return fe.pt_add(acc, sel(i))
-
-        return lax.fori_loop(1, 32, body, sel(0))
-
-    # -- RLC batch equation (shared-doubling Straus) -------------------------
-
-    # Accumulator width for the batch-axis reduction: every point op in
-    # the window loop stays >= this many lanes (VPU-friendly), and the
-    # compiler sees few distinct shapes.  The final P-wide accumulator
-    # collapses once, outside the loop.  Wider = shallower (lower
-    # latency) per-window trees but more doubling lanes; measured on a
-    # v5e (round 4), narrow trees are latency-bound (the 128-lane variant's
-    # 7 serial levels per window made RLC SLOWER than per-row despite
-    # ~2x fewer flops), so the default keeps every level wide.
-    # This class attribute is only the DEFAULT for direct verify_core_rlc
-    # calls; the production entry points (verify_batch_rlc and
-    # parallel.sharding) resolve TM_TPU_RLC_LANES per call via
-    # rlc_reduce_lanes() and key their compiled-program caches on it
-    # (ADVICE r4 #3: the env var must not bind at import time).
-    REDUCE_LANES = 2048
-
-    @staticmethod
-    def _reduced_width(n: int, target: int) -> int:
-        """The deterministic output width of _pt_reduce_to_lanes(n,
-        target) — n is NOT required to be a multiple of a power of two
-        (per-shard batches on 3/5/6-device meshes are odd)."""
-        while n > target:
-            n = n // 2 + (n % 2)
-        return n
-
-    def _pt_reduce_to_lanes(self, p, target: int | None = None):
-        """Fold a [N]-point down to a [_reduced_width(N, target)]-point
-        (target defaults to REDUCE_LANES) by pairwise tree reduction; an
-        odd leftover element rides along via concat so ANY N works."""
-        fe = self.fe
-        if target is None:
-            target = self.REDUCE_LANES
-        n = p.x.shape[0]
-        while n > target:
-            m = n // 2
-            a = fe.Pt(p.x[:m], p.y[:m], p.z[:m], p.t[:m])
-            b = fe.Pt(p.x[m : 2 * m], p.y[m : 2 * m], p.z[m : 2 * m], p.t[m : 2 * m])
-            s = fe.pt_add(a, b)
-            if n % 2:
-                s = fe.Pt(
-                    jnp.concatenate([s.x, p.x[2 * m :]], axis=0),
-                    jnp.concatenate([s.y, p.y[2 * m :]], axis=0),
-                    jnp.concatenate([s.z, p.z[2 * m :]], axis=0),
-                    jnp.concatenate([s.t, p.t[2 * m :]], axis=0),
-                )
-            p = s
-            n = m + (n % 2)
-        return p
-
-    def _table16(self, base):
-        """[O, P, 2P, ..., 15P] from a [N]-point (14 adds)."""
-        fe = self.fe
-        tbl = [fe.pt_identity(base.x.shape[:-1]), base]
-        for _ in range(14):
-            tbl.append(fe.pt_add(tbl[-1], base))
-        return tbl
-
-    def verify_core_rlc(self, pub_rows, r_rows, zk_rows, z_rows, valid,
-                        *, shard_varying: bool = False,
-                        reduce_lanes: int | None = None):
-        """Cofactored random-linear-combination batch equation:
-
-            [8]( [c]B - sum_i [z_i k_i](A_i) - sum_i [z_i](R_i) ) == O
-            with c = sum_i z_i s_i mod L, z_i random 128-bit
-
-        — the standard ZIP-215 cofactored batch equation, as implemented
-        by the ed25519consensus library's upstream VerifyBatch (the
-        library whose per-signature Verify the reference calls at
-        crypto/ed25519/ed25519.go:149-156; the reference itself never
-        batches — crypto/batch.py documents that).
-
-        The TPU win over the per-row program: the variable-base ladders'
-        ~252 doublings per signature collapse into 4 doublings per
-        window on ONE shared accumulator — per-window each row only
-        contributes a table select plus one lane of a batch-axis add
-        tree.  Per-signature point-op cost drops from ~128 adds + ~255
-        doublings to ~96 add-lanes + ~28 table-build adds, i.e. the
-        doubling term (half the total fe_mul volume) vanishes.
-
-        Completeness is exact: every ZIP-215-valid batch passes (any
-        torsion components are annihilated by the final [8]).  Soundness
-        is 2^-125-probabilistic over z, so callers MUST fall back to the
-        exact per-row program when the combined check fails
-        (verify_batch_rlc does).
-
-        Inputs: pub/r/zk rows [N,32] uint8, z_rows [N,16] uint8 (the
-        128-bit z_i), valid [N] bool (host-side s<L / well-formedness;
-        rows the host excluded carry z_i = 0).  Returns
-        ((acc_x, acc_y, acc_z, acc_t) — the P-lane partial-sum
-        accumulator, P = _reduced_width(N, 128) — and prevalid [N] bool);
-        the host finishes the equation (see the comment at the end).
-        """
-        fe = self.fe
-        if reduce_lanes is None:
-            reduce_lanes = self.REDUCE_LANES
-        pub_bits = self._bits_of(pub_rows)
-        r_bits = self._bits_of(r_rows)
-        a_pt, ok_a = self.decompress(self._limbs_of(pub_bits[..., :255]), pub_bits[..., 255])
-        r_pt, ok_r = self.decompress(self._limbs_of(r_bits[..., :255]), r_bits[..., 255])
-        prevalid = valid & ok_a & ok_r
-
-        # digits of z_i*k_i (64 windows) and z_i (32 windows); rows that
-        # failed device-side decompression are masked to digit 0, which
-        # selects the identity entry of both tables — they contribute
-        # nothing to the sums (their host-side s-term, if any, makes the
-        # equation fail and routes the batch to the exact fallback).
-        zk_digits = jnp.where(prevalid[..., None], self._nibbles_of(zk_rows), 0)
-        z_digits = jnp.where(prevalid[..., None], self._nibbles_of(z_rows), 0)
-
-        tbl_a = self._table16(fe.pt_neg(a_pt))
-        tbl_r = self._table16(fe.pt_neg(r_pt))
-
-        # P-wide accumulator: doublings and the per-window add stay
-        # vector ops; the P partial sums (each over a distinct residue
-        # class of the batch) collapse once after the loop.
-        lanes = self._reduced_width(int(pub_rows.shape[0]), reduce_lanes)
-
-        def body_hi(i, acc):
-            # windows 63..32: only the 253-bit z*k digits contribute
-            w = 63 - i
-            sel = self._select16(jnp.take(zk_digits, w, axis=-1), tbl_a)
-            acc = fe.pt_dbl_n(acc, 4)
-            return fe.pt_add(acc, self._pt_reduce_to_lanes(sel, reduce_lanes))
-
-        def body_lo(i, acc):
-            # windows 31..0: z*k and the 128-bit z digits both contribute
-            w = 63 - i
-            sel_a = self._select16(jnp.take(zk_digits, w, axis=-1), tbl_a)
-            sel_r = self._select16(jnp.take(z_digits, w, axis=-1), tbl_r)
-            acc = fe.pt_dbl_n(acc, 4)
-            return fe.pt_add(
-                acc,
-                self._pt_reduce_to_lanes(fe.pt_add(sel_a, sel_r), reduce_lanes),
-            )
-
-        acc0 = fe.pt_identity((lanes,))
-        if shard_varying:
-            # under shard_map the fori_loop carry must be batch-varying
-            # like the loop outputs; derive a zero from the sharded
-            # input (XLA folds it).  Kept off the single-chip path so
-            # its compiled-program cache key is unchanged.
-            vzero = (jnp.take(zk_digits, 0, axis=-1)[:lanes, None] * 0).astype(
-                acc0.x.dtype
-            )
-            acc0 = fe.Pt(acc0.x + vzero, acc0.y + vzero,
-                         acc0.z + vzero, acc0.t + vzero)
-        acc = lax.fori_loop(0, 32, body_hi, acc0)
-        acc = lax.fori_loop(32, 64, body_lo, acc)
-        # one-time fold to <=128 lanes so the host big-int finalization
-        # stays ~1 ms; a narrow serial chain ONCE (outside the 64-window
-        # loop) costs nothing measurable
-        acc = self._pt_reduce_to_lanes(acc, 128)
-
-        # The final steps — collapsing the P lanes, [c]B, and the
-        # cofactored identity test — are a rounding error of the batch's
-        # total work but would run at width P..1, and narrow-shape int64
-        # limb programs are disproportionately expensive for the TPU
-        # compiler (the first cut kept them in-program and its compile
-        # ran >35 min vs ~4 min for the per-row program).  They run on
-        # host big-int instead (~1 ms): verify_batch_rlc sums the
-        # returned P-lane accumulator, adds [c]B, and applies the exact
-        # [8]·==O test.
-        return acc.astuple(), prevalid
-
-    def verify_core(self, pub_rows, r_rows, s_rows, k_rows, valid,
-                    *, base_mxu: bool = False):
+    def verify_core(self, pub_rows, r_rows, s_rows, k_rows, valid):
         """Inputs are PACKED byte rows ([N,32] uint8 each) — unpacking to
         bits/limbs happens on device, so the host→device transfer is 128
-        bytes/signature instead of ~2.3KB of pre-expanded tensors.
-
-        base_mxu selects the opt-in one-hot-comb fixed-base path; it is
-        a trace-time constant, so compiled-program caches must key on it
-        (_compiled does)."""
+        bytes/signature instead of ~2.3KB of pre-expanded tensors."""
         fe = self.fe
         # one named scope per phase: metadata only — the scope
         # lands in each HLO op's op_name, which a profiler trace keeps
@@ -641,8 +362,7 @@ class _Core:
         with jax.named_scope("ed25519.decompress_r"):
             r_pt, ok_r = self.decompress(y_r, sign_r)
         with jax.named_scope("ed25519.scalarmul_base"):
-            sb = (self._scalarmul_base_mxu(s_rows) if base_mxu
-                  else self._scalarmul_base(s_digits))
+            sb = self._scalarmul_base(s_digits)
         with jax.named_scope("ed25519.scalarmul_var"):
             ka = self._scalarmul_var(k_digits, fe.pt_neg(a_pt))
         with jax.named_scope("ed25519.finish"):
@@ -711,51 +431,39 @@ def reload_env() -> None:
     _AUTO_IMPL = None
 
 
-def _jit_for(kind: str, impl: str, *, base_mxu: bool = False,
-             reduce_lanes: int | None = None, donate: bool | None = None):
-    """The raw jax.jit for one (kind, impl, flags) — shared by the lazy
-    _compiled*/ caches below and the AOT shape-plan compiler
+def _jit_for(kind: str, impl: str, *, donate: bool | None = None):
+    """The raw jax.jit for one (kind, impl) — shared by the lazy
+    _compiled cache below and the AOT shape-plan compiler
     (ops/shape_plan.py), so ahead-of-time executables and first-call
     jits have IDENTICAL call conventions, donation included.
 
-    Named wrappers, NOT functools.partial: jit derives the HLO module
+    A named wrapper, NOT functools.partial: jit derives the HLO module
     name from __name__, and the persistent compile cache keys on it —
     a partial would rename every program and cold-recompile the world."""
+    if kind != "verify":
+        raise ValueError(f"unknown jit kind {kind!r}")
     core = _core(impl)
     if donate is None:
         donate = donate_rows()
-    if kind == "rlc":
-        lanes = reduce_lanes if reduce_lanes is not None else 2048
 
-        def verify_core_rlc(pub_rows, r_rows, zk_rows, z_rows, valid):
-            return core.verify_core_rlc(pub_rows, r_rows, zk_rows, z_rows,
-                                        valid, reduce_lanes=lanes)
+    def verify_core(pub_rows, r_rows, s_rows, k_rows, valid):
+        return core.verify_core(pub_rows, r_rows, s_rows, k_rows, valid)
 
-        fn = verify_core_rlc
-    elif kind == "verify":
-        def verify_core(pub_rows, r_rows, s_rows, k_rows, valid):
-            return core.verify_core(pub_rows, r_rows, s_rows, k_rows, valid,
-                                    base_mxu=base_mxu)
-
-        fn = verify_core
-    else:
-        raise ValueError(f"unknown jit kind {kind!r}")
     kw = {"donate_argnums": _DONATE_ARGNUMS} if donate else {}
-    return jax.jit(fn, **kw)
+    return jax.jit(verify_core, **kw)
 
 
-def _compiled(n: int, impl: str | None = None, base_mxu: bool = False):
-    """The tracked program for one (rung, impl, base_mxu).  Arguments
-    are normalized HERE, before the cache: functools.cache keys on the
-    call's form, so `_compiled(8, "packed")` and `_compiled(8, "packed",
-    False)` would otherwise be two jits — one more trace, lower and
-    compile (or ~1 min cache load) of the same program."""
-    return _compiled_entry(n, impl or default_impl(), bool(base_mxu))
+def _compiled(n: int, impl: str | None = None):
+    """The tracked program for one (rung, impl).  The impl is resolved
+    HERE, before the cache: functools.cache keys on the call's form, so
+    `_compiled(8)` and `_compiled(8, "packed")` would otherwise be two
+    jits — one more trace, lower and compile (or ~1 min cache load) of
+    the same program."""
+    return _compiled_entry(n, impl or default_impl())
 
 
 @functools.cache
-def _compiled_entry(n: int, impl_r: str, base_mxu: bool):
-    # base_mxu is part of the cache key because it is baked into the trace
+def _compiled_entry(n: int, impl_r: str):
     donate = donate_rows()
 
     # AOT first (ops/shape_plan): an executable warmed ahead of time —
@@ -765,17 +473,15 @@ def _compiled_entry(n: int, impl_r: str, base_mxu: bool):
     # prerecorded and the steady state records nothing.
     from . import shape_plan as _plan
 
-    entry = _plan.aot_lookup("verify", n, impl_r, base_mxu=base_mxu,
-                             donate=donate)
+    entry = _plan.aot_lookup("verify", n, impl_r, donate=donate)
     if entry is not None:
         return _devmon.track_jit(entry.executable, kind="verify",
-                                 impl=impl_r, rung=n, prerecorded=True,
-                                 base_mxu=base_mxu)
+                                 impl=impl_r, rung=n, prerecorded=True)
 
     # compile tracking (utils/devmon): the first call per cache entry is
     # the one that pays trace+compile; re-tracing the same key after a
     # cache_clear is the unexpected-recompile the tracker warns about
-    jitted = _jit_for("verify", impl_r, base_mxu=base_mxu, donate=donate)
+    jitted = _jit_for("verify", impl_r, donate=donate)
     # cost model (utils/costmodel): register the program for HLO-cost
     # harvest; the thunk only runs when `tendermint-tpu profile` (or a
     # costmodel.resolve_pending caller) asks — a trace, never a compile
@@ -783,46 +489,12 @@ def _compiled_entry(n: int, impl_r: str, base_mxu: bool):
 
     if _cost.COSTS.enabled:
         _cost.COSTS.record_pending(
-            "verify", n, impl_r, {"base_mxu": base_mxu, "donate": donate},
+            "verify", n, impl_r, {"donate": donate},
             lambda: jitted.lower(*_plan.abstract_rows("verify", n)))
-    return _devmon.track_jit(
-        jitted, kind="verify", impl=impl_r, rung=n, base_mxu=base_mxu)
+    return _devmon.track_jit(jitted, kind="verify", impl=impl_r, rung=n)
 
 
 _compiled.cache_clear = _compiled_entry.cache_clear
-
-
-def rlc_reduce_lanes() -> int:
-    """TM_TPU_RLC_LANES resolved per call (ADVICE r4 #3 — the companion
-    TM_TPU_RLC flag is read per call in crypto/batch.py, and an env var
-    that silently binds at import is a footgun in tests/benchmarks)."""
-    try:
-        return int(os.environ.get("TM_TPU_RLC_LANES", "2048"))
-    except ValueError:
-        return 2048
-
-
-@functools.cache
-def _compiled_rlc(n: int, impl: str, reduce_lanes: int = 2048):
-    # reduce_lanes is baked into the trace -> part of the cache key.
-    donate = donate_rows()
-    from . import shape_plan as _plan
-
-    entry = _plan.aot_lookup("rlc", n, impl, reduce_lanes=reduce_lanes,
-                             donate=donate)
-    if entry is not None:
-        return _devmon.track_jit(entry.executable, kind="rlc", impl=impl,
-                                 rung=n, prerecorded=True,
-                                 reduce_lanes=reduce_lanes)
-    jitted = _jit_for("rlc", impl, reduce_lanes=reduce_lanes, donate=donate)
-    from tendermint_tpu.utils import costmodel as _cost
-
-    if _cost.COSTS.enabled:
-        _cost.COSTS.record_pending(
-            "rlc", n, impl, {"reduce_lanes": reduce_lanes, "donate": donate},
-            lambda: jitted.lower(*_plan.abstract_rows("rlc", n)))
-    return _devmon.track_jit(
-        jitted, kind="rlc", impl=impl, rung=n, reduce_lanes=reduce_lanes)
 
 
 # ---------------------------------------------------------------------------
@@ -930,34 +602,6 @@ def _bucket(n: int) -> int:
     return _plan.bucket(n)
 
 
-def _chunk_size() -> int:
-    """TM_TPU_CHUNK: sub-batch size for pipelined large-batch dispatch.
-    Default 0 (disabled): every chunk is one more dispatch, the bucket
-    ladder already holds a 10k commit's padding to 2.4%, and the only
-    gain is the overlap of one chunk's host prep with another's device
-    time.  The round-5 probes that measured chunking slower
-    (benchmarks/tpu_kernel_r05.jsonl "chunk") paid a per-dispatch cost
-    no attached chip has; the question is open again until a cell
-    measures it on one.
-    Resolved per call.  Negative values clamp to 0 (disabled): a
-    misconfigured env var must degrade to the unchunked path, not crash
-    verify_batch in np.concatenate([])."""
-    try:
-        return max(0, int(os.environ.get("TM_TPU_CHUNK", "0")))
-    except ValueError:
-        return 0
-
-
-def chunks_of(n: int, chunk: int) -> list[tuple[int, int, int]]:
-    """[(start, end, bucket)] covering [0, n) in `chunk`-sized pieces;
-    the tail lands in its own (smaller) bucket."""
-    out = []
-    for start in range(0, n, chunk):
-        end = min(start + chunk, n)
-        out.append((start, end, _bucket(end - start)))
-    return out
-
-
 def _pad_rows(n: int, b: int, *arrays):
     """Zero-pad leading axis from n to bucket b."""
     if b == n:
@@ -967,19 +611,15 @@ def _pad_rows(n: int, b: int, *arrays):
 
 
 # ---------------------------------------------------------------------------
-# Golden-batch self-check for opt-in kernel flags (VERDICT r4 item 6)
+# Golden-batch self-check of a field backend on the device it runs on
 # ---------------------------------------------------------------------------
 #
-# TM_TPU_FE_MXU was measured computing WRONG verdicts on real TPU
-# (benchmarks/tpu_kernel_r04.jsonl: verify_ok=false — Precision.HIGHEST
-# does not deliver exact f32 dots on the TPU MXU the way XLA-CPU does),
-# and TM_TPU_BASE_MXU leans on the same exactness assumption.  Default-off
-# is not a safety mechanism: an operator who sets the flag on a TPU must
-# not get silently-wrong crypto.  So production paths run each opt-in
-# kernel ONCE per process against a known mixed-validity batch and
-# refuse the flag (loudly, with fallback to the standard program) on any
-# verdict mismatch.  Bench harnesses (kernel_bench) bypass the gate on
-# purpose — their job is to measure and report the raw path.
+# A program that compiles is not a program that is right: the f32 backend
+# this kernel once had computed WRONG verdicts on the TPU (CHANGES PR 21)
+# where XLA-CPU ran it exactly.  So before "auto" takes a backend on an
+# accelerator, the backend's own floor-rung program runs ONCE per process
+# against a known mixed-validity batch, and is refused (loudly, with the
+# int64 fallback) on any verdict mismatch or error.
 
 _OPTIN_STATE: dict[tuple[str, str], bool] = {}
 # (flag, impl) -> {"outcome": "pass" | "wrong_verdicts" | "error", ...}:
@@ -1018,12 +658,12 @@ def _golden_batch():
 
 
 def _optin_safe(flag: str, impl: str) -> bool:
-    """True iff the opt-in kernel `flag` reproduces the golden verdicts
-    for `impl` on the current backend.  Memoized per process; a mismatch
-    warns and pins False (the caller falls back to the standard path).
-    flag "impl" gates a whole field backend (the auto-promotion path:
-    the golden batch runs through the candidate impl's standard
-    program), "base_mxu"/"fe_mxu" gate the opt-in kernels within one."""
+    """True iff `impl`'s standard program reproduces the golden verdicts
+    on the current backend — the very program (and cache entry)
+    production dispatch runs.  Memoized per process; a mismatch or an
+    error warns and pins False (the caller falls back to int64).
+    `flag` is "impl": the name and the "impl/<impl>" key of
+    optin_report() are what chipbench and chip_smoke.py read."""
     key = (flag, impl)
     if key in _OPTIN_STATE:
         return _OPTIN_STATE[key]
@@ -1031,16 +671,13 @@ def _optin_safe(flag: str, impl: str) -> bool:
 
     try:
         inputs, want = _golden_batch()
-        # fe_mxu lives inside the f32 backend, and "impl" is the
-        # candidate backend's own standard program: both run the very
-        # program (and cache entry) production dispatch runs
-        got = _compiled(8, impl, flag == "base_mxu")(*inputs)
+        got = _compiled(8, impl)(*inputs)
         got = [bool(v) for v in np.asarray(got)]
     except Exception as e:  # noqa: BLE001 — a crash is also a refusal
         ok = False
         _OPTIN_REPORT[key] = {"outcome": "error", "type": type(e).__name__,
                               "message": str(e)[-1000:]}
-        warnings.warn(f"opt-in kernel {flag!r} ({impl}) RAISED in its golden "
+        warnings.warn(f"field backend {impl!r} RAISED in its golden "
                       f"self-check (a compile or runtime refusal, not a "
                       f"verdict mismatch); disabled for this process: "
                       f"{type(e).__name__}: {e}")
@@ -1051,73 +688,18 @@ def _optin_safe(flag: str, impl: str) -> bool:
                                "want": want})
         if not ok:
             warnings.warn(
-                f"opt-in kernel {flag!r} ({impl}) computed WRONG verdicts "
-                "on this backend (golden-batch self-check); the flag is "
-                "disabled for this process and the standard program is "
-                "used instead")
-    if not ok:
-        if flag == "fe_mxu":
-            # the flag is a trace-time global inside the field module:
-            # flip it and drop every compiled program that may have
-            # baked it in — including the mesh-sharded programs
-            # (parallel.sharding keeps its own jit caches; ADVICE r5)
-            _field("f32")._USE_MXU = False
-            _compiled.cache_clear()
-            _compiled_rlc.cache_clear()
-            try:
-                from tendermint_tpu.parallel import sharding as _sharding
-
-                _sharding.sharded_verify_fn.cache_clear()
-                _sharding.sharded_rlc_fn.cache_clear()
-            except Exception:  # noqa: BLE001 — sharding never imported
-                pass
+                f"field backend {impl!r} computed WRONG verdicts on this "
+                "backend (golden-batch self-check); it is disabled for "
+                "this process and the int64 program is used instead")
     _OPTIN_STATE[key] = ok
     return ok
 
 
-def _resolve_optin(impl: str) -> bool:
-    """Gate the opt-in kernel flags for a production dispatch; returns
-    the base_mxu trace flag to compile with."""
-    base_mxu = False
-    if _base_mxu_requested() and impl != "packed":
-        # packed limbs (< 2^26) exceed the f32-exact ceiling the one-hot
-        # comb's float table depends on — structurally wrong, not merely
-        # unvalidated, so the golden gate is never even consulted
-        base_mxu = _optin_safe("base_mxu", impl)
-    if impl == "f32" and _field("f32")._use_mxu():
-        _optin_safe("fe_mxu", impl)  # flips the module flag on mismatch
-    return base_mxu
-
-
-def _verify_rows(pub_rows, r_rows, s_rows, k_rows, valid, impl: str) -> np.ndarray:
-    """Per-row device program on already-prepared rows (bucket-padded
-    here); shared by verify_batch and the RLC fallback."""
-    base_mxu = _resolve_optin(impl)
-    n = len(valid)
-    b = _bucket(n)
-    pub_rows, r_rows, s_rows, k_rows, valid_p = _pad_rows(
-        n, b, pub_rows, r_rows, s_rows, k_rows, valid
-    )
-    if _devmon.STATS.enabled:
-        _devmon.STATS.record_flush(
-            "verify", n, b,
-            nbytes=(pub_rows.nbytes + r_rows.nbytes + s_rows.nbytes
-                    + k_rows.nbytes + valid_p.nbytes))
-    ok = _compiled(b, impl, base_mxu)(pub_rows, r_rows, s_rows, k_rows, valid_p)
-    return np.asarray(ok)[:n]
-
-
 def verify_batch(pubs, msgs, sigs, impl: str | None = None) -> np.ndarray:
-    """ZIP-215 verification of the whole batch on device.
+    """ZIP-215 verification of the whole batch on device, as one
+    program at the batch's rung.
 
     Returns bool[N].  Inputs are bytes-like sequences of equal length N.
-
-    Batches larger than TM_TPU_CHUNK (default 0 = off; see _chunk_size
-    for the measurement behind the default) are dispatched as a pipeline
-    of sub-batches: each chunk's host prep (SHA-512, s<L) runs while the
-    device executes the previous chunk — JAX dispatch is async, so
-    enqueueing returns immediately and the final verdict collection
-    drains the queue (VERDICT r4 item 2).
     """
     n = len(pubs)
     if n == 0:
@@ -1126,131 +708,10 @@ def verify_batch(pubs, msgs, sigs, impl: str | None = None) -> np.ndarray:
     # to TM_TPU_FIELD_IMPL is honored (and impl=None vs impl="int64"
     # share one compiled program per bucket)
     impl = impl or default_impl()
-    chunk = _chunk_size()
-    if chunk and n > chunk:
-        return _verify_batch_pipelined(pubs, msgs, sigs, impl, chunk)
-    pub_rows, r_rows, s_rows, k_rows, valid = prepare_batch(pubs, msgs, sigs)
-    return _verify_rows(pub_rows, r_rows, s_rows, k_rows, valid, impl)
-
-
-def _verify_batch_pipelined(pubs, msgs, sigs, impl: str, chunk: int) -> np.ndarray:
-    """Chunked large-batch dispatch: prep chunk i+1 on host while the
-    device runs chunk i.  Every chunk program is enqueued before any
-    verdict is read; np.asarray at the end drains the device queue in
-    submission order."""
-    base_mxu = _resolve_optin(impl)
-    pending = []
-    for start, end, b in chunks_of(len(pubs), chunk):
-        rows = prepare_batch(pubs[start:end], msgs[start:end], sigs[start:end])
-        padded = _pad_rows(end - start, b, *rows)
-        if _devmon.STATS.enabled:
-            _devmon.STATS.record_flush(
-                "verify", end - start, b,
-                nbytes=sum(a.nbytes for a in padded))
-        pending.append((_compiled(b, impl, base_mxu)(*padded), end - start))
-    return np.concatenate([np.asarray(ok)[:m] for ok, m in pending])
-
-
-# ---------------------------------------------------------------------------
-# RLC batch verification (shared-doubling batch equation + exact fallback)
-# ---------------------------------------------------------------------------
-
-RLC_STATS = {"pass": 0, "fallback": 0}
-
-
-def prepare_rlc_scalars(s_rows, k_rows, valid):
-    """Sample z_i and compute the RLC scalars on host:
-        zk_i = z_i * k_i mod L   (rows [N,32] uint8, LE)
-        c    = sum_i z_i * s_i mod L   (one [32] uint8 row)
-    z_i is 128-bit cryptographically random (os.urandom) — soundness of
-    the batch equation requires the adversary cannot predict it; rows
-    with valid=False get z_i = 0 so they drop out of every term.
-
-    The native kernel (src/native/edhost.cpp tmed_rlc_scalars) does the
-    mulmods in one threaded C call; the Python big-int loop is the
-    fallback."""
-    n = len(valid)
-    z_rows = np.frombuffer(os.urandom(16 * n), dtype=np.uint8).reshape(n, 16).copy()
-    # z must be nonzero for soundness of per-row exclusion (P[z=0]=2^-128,
-    # but the guard is free)
-    zero = ~z_rows.any(axis=1)
-    z_rows[zero, 0] = 1
-    z_rows[~valid] = 0
-
-    from tendermint_tpu.utils import host_prep
-
-    native = host_prep.rlc_scalars_native(z_rows, k_rows, s_rows)
-    if native is not None:
-        zk_rows, c_row = native
-        return z_rows, zk_rows, c_row
-
-    zk_rows = np.zeros((n, 32), dtype=np.uint8)
-    c = 0
-    for i in range(n):
-        if not valid[i]:
-            continue
-        z = int.from_bytes(z_rows[i].tobytes(), "little")
-        k = int.from_bytes(k_rows[i].tobytes(), "little")
-        s = int.from_bytes(s_rows[i].tobytes(), "little")
-        zk_rows[i] = np.frombuffer((z * k % L).to_bytes(32, "little"), dtype=np.uint8)
-        c = (c + z * s) % L
-    c_row = np.frombuffer(c.to_bytes(32, "little"), dtype=np.uint8).copy()
-    return z_rows, zk_rows, c_row
-
-
-def finalize_rlc(acc_coords, c_row, impl: str) -> bool:
-    """Host finalization of the RLC equation (exact big-int): sum the
-    accumulator lanes (any count — a sharded run concatenates every
-    device's lanes), add [c]B, and apply the cofactored identity test.
-    ~1 ms at 128 lanes."""
-    fe = _field(impl)
-    ax, ay, az, at = (np.asarray(v) for v in acc_coords)
-    total = _ref.IDENTITY
-    for lane in range(ax.shape[0]):
-        p = tuple(
-            fe.int_from_limbs(coord[lane]) % _ref.P for coord in (ax, ay, az, at)
-        )
-        total = _ref.pt_add(total, p)
-    c = int.from_bytes(bytes(c_row), "little")
-    total = _ref.pt_add(total, _ref.scalar_mult(c, _ref.BASE))
-    return _ref.pt_equal(_ref.scalar_mult(8, total), _ref.IDENTITY)
-
-
-def verify_batch_rlc(pubs, msgs, sigs, impl: str | None = None) -> np.ndarray:
-    """Batch verification via the cofactored RLC equation (one shared
-    accumulator, no per-row doubling ladders), falling back to the exact
-    per-row device program when the combined check fails — so returned
-    verdicts are ALWAYS bit-identical to the per-row ZIP-215 reference.
-
-    The fallback fires only when the batch actually contains an invalid
-    signature (or with probability ~2^-125 on a valid batch), i.e. the
-    steady-state consensus path — honest commits — always takes the
-    cheap equation.  Same accept/reject contract as the ed25519consensus
-    library's upstream VerifyBatch (the reference repo itself has no
-    batch verifier; it calls that library's per-signature Verify,
-    crypto/ed25519/ed25519.go:149-156)."""
-    n = len(pubs)
-    if n == 0:
-        return np.zeros(0, dtype=bool)
-    impl = impl or default_impl()
-    _resolve_optin(impl)  # fe_mxu golden gate (RLC has no device [s]B)
-    pub_rows, r_rows, s_rows, k_rows, valid = prepare_batch(pubs, msgs, sigs)
-    z_rows, zk_rows, c_row = prepare_rlc_scalars(s_rows, k_rows, valid)
+    rows = prepare_batch(pubs, msgs, sigs)
     b = _bucket(n)
-    pub_p, r_p, zk_p, z_p, valid_p = _pad_rows(
-        n, b, pub_rows, r_rows, zk_rows, z_rows, valid
-    )
+    padded = _pad_rows(n, b, *rows)
     if _devmon.STATS.enabled:
         _devmon.STATS.record_flush(
-            "rlc", n, b,
-            nbytes=sum(a.nbytes for a in (pub_p, r_p, zk_p, z_p, valid_p)))
-    acc, prevalid = _compiled_rlc(b, impl, rlc_reduce_lanes())(
-        pub_p, r_p, zk_p, z_p, valid_p
-    )
-    if finalize_rlc(acc, c_row, impl):
-        RLC_STATS["pass"] += 1
-        return np.asarray(prevalid)[:n]
-    RLC_STATS["fallback"] += 1
-    # exact per-row fallback on the ALREADY-prepared rows (no second
-    # host prep on the adversarial path)
-    return _verify_rows(pub_rows, r_rows, s_rows, k_rows, valid, impl)
+            "verify", n, b, nbytes=sum(a.nbytes for a in padded))
+    return np.asarray(_compiled(b, impl)(*padded))[:n]

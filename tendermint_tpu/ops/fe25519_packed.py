@@ -32,8 +32,8 @@ Bound analysis (why int64 never overflows; R = reduced bound):
   * fe_add of two reduced: < 2^27.01.  fe_sub adds 2p in limb form
     (even limbs ~2^27): output < R + 2^27 < 2^27.59.  fe_neg adds 4p:
     output < 2^28.01 (callers re-carry; see pt_neg).
-  * fe_mul PAIRWISE operand contract (the f32 backend's style, not a
-    single input ceiling): max|a_i| * max|b_j| <= 2^54.9.  Column
+  * fe_mul PAIRWISE operand contract (not a single input
+    ceiling): max|a_i| * max|b_j| <= 2^54.9.  Column
     coefficient sums C_j = sum(pairs at j) + 19*sum(pairs at j+10) with
     the odd-odd doubling counted are maximal at j=0: C_0 = 1 + 19*14 =
     267 < 2^8.07, so the worst column is < 267 * 2^54.9 < 2^63.

@@ -26,8 +26,8 @@ serializable story in three parts:
   * **AOT compilation** — `warm_entry`/`warm_rungs`/`warm_plan` build
     executables with `jit(...).lower().compile()` for every
     (kind, rung, impl, flags) in the plan, BEFORE traffic needs them,
-    and register them so `ops.ed25519_jax._compiled`/`_compiled_rlc`
-    hand them straight out.  Off XLA-CPU the compiled artifact is also
+    and register them so `ops.ed25519_jax._compiled`
+    hands them straight out.  Off XLA-CPU the compiled artifact is also
     written to disk through `jax.experimental.serialize_executable`
     (utils/jaxcache.aot_dir()) for later starts to deserialize; on
     XLA-CPU the compile itself warms the persistent cache — either way
@@ -410,8 +410,8 @@ def _reg_key(kind: str, rung: int, impl: str, flags: dict) -> tuple:
 
 def aot_lookup(kind: str, rung: int, impl: str, **flags) -> AotEntry | None:
     """The pre-compiled executable for one jit cache key, or None —
-    consulted by ops.ed25519_jax._compiled/_compiled_rlc before they
-    build a lazy jit."""
+    consulted by ops.ed25519_jax._compiled before it builds a lazy
+    jit."""
     with _REG_LOCK:
         return _REGISTRY.get(_reg_key(kind, rung, impl, flags))
 
@@ -438,11 +438,7 @@ def _entry_flags(kind: str, impl: str) -> dict:
     lookup."""
     from tendermint_tpu.ops import ed25519_jax as dev
 
-    if kind == "rlc":
-        return {"reduce_lanes": dev.rlc_reduce_lanes(),
-                "donate": dev.donate_rows()}
-    return {"base_mxu": dev._resolve_optin(impl),
-            "donate": dev.donate_rows()}
+    return {"donate": dev.donate_rows()}
 
 
 def abstract_rows(kind: str, rung: int) -> tuple:
@@ -454,9 +450,6 @@ def abstract_rows(kind: str, rung: int) -> tuple:
 
     u8row = jax.ShapeDtypeStruct((rung, 32), np.uint8)
     valid = jax.ShapeDtypeStruct((rung,), np.bool_)
-    if kind == "rlc":
-        return (u8row, u8row, u8row,
-                jax.ShapeDtypeStruct((rung, 16), np.uint8), valid)
     return (u8row, u8row, u8row, u8row, valid)
 
 

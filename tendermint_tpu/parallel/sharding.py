@@ -90,86 +90,6 @@ def sharded_verify_fn(mesh: Mesh):
         devices=int(mesh.devices.size))
 
 
-@functools.lru_cache(maxsize=8)
-def sharded_rlc_fn(mesh: Mesh, impl: str, reduce_lanes: int = 2048):
-    """shard_map of the RLC core: each device runs the IDENTICAL
-    single-chip program on its local batch shard (no cross-chip
-    collectives — the only fan-in is each device's P-lane accumulator,
-    ~61 KB, folded on host by ops.ed25519_jax.finalize_rlc).  out_specs
-    concatenate the per-device accumulator lanes along axis 0.
-    reduce_lanes is baked into the trace, hence part of the cache key."""
-    from jax import shard_map
-
-    _raw = _dev._core(impl)
-
-    # named wrapper, not functools.partial: the HLO module name derives
-    # from __name__ and the persistent compile cache keys on it
-    def verify_core_rlc(pub_rows, r_rows, zk_rows, z_rows, valid):
-        return _raw.verify_core_rlc(pub_rows, r_rows, zk_rows, z_rows,
-                                    valid, shard_varying=True,
-                                    reduce_lanes=reduce_lanes)
-
-    core = verify_core_rlc
-    b2 = P("batch", None)
-    # donated row buffers (see sharded_verify_fn)
-    kw = {"donate_argnums": _dev._DONATE_ARGNUMS} if _dev.donate_rows() else {}
-    return _devmon.track_jit(
-        jax.jit(
-            shard_map(
-                core,
-                mesh=mesh,
-                in_specs=(b2, b2, b2, b2, P("batch")),
-                out_specs=((b2, b2, b2, b2), P("batch")),
-            ),
-            **kw,
-        ),
-        kind="sharded_rlc", impl=impl, devices=int(mesh.devices.size),
-        reduce_lanes=reduce_lanes)
-
-
-def verify_batch_rlc_sharded(pubs, msgs, sigs, mesh: Mesh | None = None,
-                             impl: str | None = None) -> np.ndarray:
-    """RLC batch verification sharded over the mesh's batch axis, exact
-    per-row sharded fallback on combined-check failure (same contract
-    as ops.ed25519_jax.verify_batch_rlc)."""
-    n = len(pubs)
-    if n == 0:
-        return np.zeros(0, dtype=bool)
-    if mesh is None:
-        mesh = make_mesh()
-    impl = impl or _dev.default_impl()
-    # opt-in kernel gate (ADVICE r5): a direct sharded call must run the
-    # same golden-batch self-check as the single-chip entry points — a
-    # wrong-verdict TM_TPU_FE_MXU program is disabled (and the sharded
-    # jit caches cleared) before any mesh trace is built
-    _dev._resolve_optin(impl)
-    n_dev = mesh.devices.size
-    pub_rows, r_rows, s_rows, k_rows, valid = _dev.prepare_batch(pubs, msgs, sigs)
-    z_rows, zk_rows, c_row = _dev.prepare_rlc_scalars(s_rows, k_rows, valid)
-    b = sharded_bucket(n, n_dev)
-    pub_p, r_p, zk_p, z_p, valid_p = _dev._pad_rows(
-        n, b, pub_rows, r_rows, zk_rows, z_rows, valid
-    )
-    if _devmon.STATS.enabled:
-        _devmon.STATS.record_flush(
-            "rlc_sharded", n, b,
-            nbytes=sum(a.nbytes for a in (pub_p, r_p, zk_p, z_p, valid_p)),
-            devices=device_ids(mesh))
-    acc, prevalid = sharded_rlc_fn(mesh, impl, _dev.rlc_reduce_lanes())(
-        pub_p, r_p, zk_p, z_p, valid_p
-    )
-    if _dev.finalize_rlc(acc, c_row, impl):
-        _dev.RLC_STATS["pass"] += 1
-        return np.asarray(prevalid)[:n]
-    _dev.RLC_STATS["fallback"] += 1
-    # exact per-row sharded fallback on the ALREADY-prepared rows — no
-    # second host prep (parsing + SHA-512) on the adversarial path,
-    # matching single-chip verify_batch_rlc (ADVICE r4 #2)
-    return _verify_rows_sharded(
-        (pub_rows, r_rows, s_rows, k_rows, valid), n, mesh
-    )
-
-
 def _verify_rows_sharded(inputs, n: int, mesh: Mesh) -> np.ndarray:
     """Sharded per-row program on already-prepared packed rows
     (pub_rows, r_rows, s_rows, k_rows, valid); pads to the bucket/mesh
@@ -196,8 +116,4 @@ def verify_batch_sharded(pubs, msgs, sigs, mesh: Mesh | None = None) -> np.ndarr
         return np.zeros(0, dtype=bool)
     if mesh is None:
         mesh = make_mesh()
-    # fe_mxu golden gate before any sharded trace (ADVICE r5): the
-    # mismatch branch flips the field-module flag and clears this
-    # module's jit caches, so the program built below is the safe one
-    _dev._resolve_optin(_dev.default_impl())
     return _verify_rows_sharded(_dev.prepare_batch(pubs, msgs, sigs), n, mesh)

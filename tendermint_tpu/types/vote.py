@@ -36,19 +36,25 @@ class Vote:
     signature: bytes = b""
 
     def sign_bytes(self, chain_id: str) -> bytes:
-        # memoized per chain: every verify surface (precheck slices,
-        # single-vote admission, the service cache key) recomputes the
-        # canonical bytes, and the decode memo shares one Vote instance
-        # across all in-process receivers — so one encode serves them
-        # all.  Signing mutates only `signature`, which sign-bytes never
-        # cover; the other fields are set at construction.
+        # memoized per chain and timestamp: every verify surface
+        # (precheck slices, single-vote admission, the service cache
+        # key) recomputes the canonical bytes, and the decode memo
+        # shares one Vote instance across all in-process receivers — so
+        # one encode serves them all.  A signer that finds the same
+        # vote saved under another timestamp rewrites `timestamp_ns`
+        # (privval/file_pv, grpc_pv, socket_pv) AFTER asking for the
+        # bytes, hence the timestamp in the key; `signature` is not
+        # covered by sign-bytes, and type, height, round and block_id
+        # (a frozen dataclass) are set at construction and assigned
+        # nowhere.
+        key = (chain_id, self.timestamp_ns)
         memo = getattr(self, "_sb_memo", None)
-        if memo is not None and memo[0] == chain_id:
+        if memo is not None and memo[0] == key:
             return memo[1]
         sb = vote_sign_bytes_raw(
             chain_id, self.type, self.height, self.round, self.block_id, self.timestamp_ns
         )
-        self._sb_memo = (chain_id, sb)
+        self._sb_memo = (key, sb)
         return sb
 
     def _precheck_digest(self, chain_id: str, pub_key: PubKey) -> bytes:

@@ -15,7 +15,7 @@ This module is the harvest point:
   * ``COSTS`` (CostModel) — one record per (kind, rung, impl).  The AOT
     warm path (ops/shape_plan.warm_entry) harvests COMPILED executables
     (cost + memory analysis, source "compiled"); the lazy jit caches
-    (ops/ed25519_jax._compiled/_compiled_rlc) register a PENDING entry
+    (ops/ed25519_jax._compiled) register a PENDING entry
     whose resolver lowers the program and reads the lowering's cost
     analysis (source "lowered" — tracing only, never an XLA compile:
     resolving costs seconds of Python, not a compile).  Pending
@@ -27,8 +27,7 @@ This module is the harvest point:
     ``peak_flops_per_s()`` (TM_TPU_PEAK_FLOPS override, else a
     device-kind table, else unknown → reported as None, never guessed),
     and bytes/row at both levels: the HLO's working-set bytes vs the
-    129 B/row (verify) / 113 B/row (rlc) host→device transfer devmon
-    measured.
+    129 B/row host→device transfer devmon measured.
   * Exports — ``COSTS.flops_samples()`` etc. feed the
     ``verify_rung_flops`` / ``verify_rung_bytes_accessed`` /
     ``verify_rung_peak_memory_bytes`` gauges in node/metrics.py, and
@@ -53,9 +52,8 @@ import threading
 _log = logging.getLogger("tendermint_tpu.costmodel")
 
 # Host→device transfer bytes per row, by program kind: packed 32-byte
-# rows plus the valid bit (devmon's measured 129 B/row for the per-row
-# program; the RLC program ships 3 rows + a 16-byte scalar row).
-ROW_TRANSFER_BYTES = {"verify": 4 * 32 + 1, "rlc": 3 * 32 + 16 + 1}
+# rows plus the valid bit (devmon's measured 129 B/row).
+ROW_TRANSFER_BYTES = {"verify": 4 * 32 + 1}
 
 # Peak dense-FLOP/s keyed by the EXACT `jax.devices()[0].device_kind`
 # string (an upper bound: the int64-limb kernel runs on the VPU, so

@@ -10,8 +10,8 @@ nothing reported what the verifier holds in device memory.  Three
 trackers close that gap:
 
   * `TRACKER` (CompileTracker): every jit entry point in
-    ops/ed25519_jax (`_compiled`, `_compiled_rlc`) and parallel/sharding
-    is wrapped by `track_jit`, so the FIRST call per bucket rung — the
+    ops/ed25519_jax (`_compiled`) and parallel/sharding is
+    wrapped by `track_jit`, so the FIRST call per bucket rung — the
     call that pays trace+compile — records a compile event (rung, impl,
     flags, wall duration, persistent-cache hit vs cold compile) into a
     bounded event list plus per-(rung, impl) counters.  A rung compiled

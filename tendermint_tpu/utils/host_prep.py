@@ -45,16 +45,6 @@ def load_lib():
             ctypes.c_int,
         ]
         lib.tmed_batch_k.restype = None
-        if hasattr(lib, "tmed_rlc_scalars"):
-            lib.tmed_rlc_scalars.argtypes = [
-                ctypes.c_uint64,
-                ctypes.POINTER(ctypes.c_uint8),
-                ctypes.POINTER(ctypes.c_uint8),
-                ctypes.POINTER(ctypes.c_uint8),
-                ctypes.POINTER(ctypes.c_uint8),
-                ctypes.POINTER(ctypes.c_uint8),
-            ]
-            lib.tmed_rlc_scalars.restype = None
         if hasattr(lib, "tmed_batch_verify"):
             lib.tmed_batch_verify.argtypes = [
                 ctypes.c_uint64,
@@ -95,33 +85,6 @@ def batch_k_native(r_rows: np.ndarray, pub_rows: np.ndarray,
         ctypes.c_int(n_threads),
     )
     return out
-
-
-def rlc_scalars_native(z_rows: np.ndarray, k_rows: np.ndarray,
-                       s_rows: np.ndarray) -> tuple[np.ndarray, np.ndarray] | None:
-    """(zk_rows [N,32], c_row [32]) for the RLC batch equation:
-    zk_i = z_i*k_i mod L and c = sum_i z_i*s_i mod L, computed in one C
-    call (src/native/edhost.cpp tmed_rlc_scalars).  Rows with z_i = 0
-    (host-excluded) contribute nothing.  None when unavailable."""
-    lib = load_lib()
-    if lib is None or not hasattr(lib, "tmed_rlc_scalars"):
-        return None
-    n = z_rows.shape[0]
-    zk = np.zeros((n, 32), dtype=np.uint8)
-    c = np.zeros(32, dtype=np.uint8)
-    z_c = np.ascontiguousarray(z_rows)
-    k_c = np.ascontiguousarray(k_rows)
-    s_c = np.ascontiguousarray(s_rows)
-    u8p = ctypes.POINTER(ctypes.c_uint8)
-    lib.tmed_rlc_scalars(
-        ctypes.c_uint64(n),
-        z_c.ctypes.data_as(u8p),
-        k_c.ctypes.data_as(u8p),
-        s_c.ctypes.data_as(u8p),
-        zk.ctypes.data_as(u8p),
-        c.ctypes.data_as(u8p),
-    )
-    return zk, c
 
 
 def batch_verify_native(pubs, msgs, sigs, n_threads: int = 0) -> list[bool] | None:

@@ -55,23 +55,13 @@ KNOBS: tuple[Knob, ...] = (
          "pod-slice sharded verification: auto/1/0", "crypto"),
     Knob("TM_TPU_MESH_MIN_SHARD", "0",
          "minimum rows per shard before the mesh path engages", "crypto"),
-    Knob("TM_TPU_RLC", "0",
-         "random-linear-combination batch folding", "crypto"),
-    Knob("TM_TPU_RLC_LANES", "2048",
-         "RLC lane count per fold", "crypto"),
     # -- ops / kernels --------------------------------------------------
     Knob("TM_TPU_AOT", "1",
          "ahead-of-time shape-plan warm compile", "ops"),
-    Knob("TM_TPU_BASE_MXU", "0",
-         "force the MXU base-field multiply path", "ops"),
-    Knob("TM_TPU_CHUNK", "0",
-         "verify kernel chunk rows; 0 = unchunked", "ops"),
     Knob("TM_TPU_DONATE", "auto",
          "XLA buffer donation mode: auto/1/0", "ops"),
-    Knob("TM_TPU_FE_MXU", "auto",
-         "f32 field-element MXU mode: auto/1/0", "ops"),
     Knob("TM_TPU_FIELD_IMPL", "auto",
-         "field arithmetic implementation: auto/int64/packed/f32", "ops"),
+         "field arithmetic implementation: auto/int64/packed", "ops"),
     Knob("TM_TPU_RUNGS", "",
          "explicit shape-plan rung ladder (comma ints)", "ops"),
     Knob("TM_TPU_SHAPE_PLAN", "",

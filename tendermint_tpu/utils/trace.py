@@ -104,8 +104,8 @@ def refresh_from_env() -> None:
     set_ring_size(_env_ring_size())
 
 
-#: the lazy-env contract name shared by trace / crypto.batch /
-#: ops.fe25519_f32 (docs/linting.md, import-time-env)
+#: the lazy-env contract name shared with crypto.batch and
+#: ops.ed25519_jax (docs/linting.md, import-time-env)
 reload_env = refresh_from_env
 
 

@@ -1,13 +1,17 @@
-"""Checks of the scalar multiplications' building blocks for any of the
-three field backends: the signed radix-16 recoding, the precomputed-form
-additions (pt_madd, pt_add_cached, pt_to_cached), the two scalar
-multiplications, and the static count of field operations — each takes
-the impl's name and compares with tendermint_tpu.crypto.ed25519's big
-integers (run from tests/test_ed25519_jax.py, parametrised over the
-impls) — and the additions at the bounds of a backend's operand contract
-(run from each backend's own file with its own patterns).  Not a test
-file."""
+"""The one home of kernel cases, for either field backend.  The ZIP-215
+adversarial gauntlet (GAUNTLET: named, the same bytes on every run) with
+its verdicts on the floor rung, and the mixed-validity batch of eight.
+Checks of the scalar multiplications' building blocks: the signed
+radix-16 recoding, the precomputed-form additions (pt_madd,
+pt_add_cached, pt_to_cached), the two scalar multiplications, and the
+static count of field operations — each takes the impl's name and
+compares with tendermint_tpu.crypto.ed25519's big integers (run from
+tests/test_ed25519_jax.py, parametrised over the impls) — and the
+additions at the bounds of a backend's operand contract (run from each
+backend's own file with its own patterns).  Not a test file."""
 
+import functools
+import hashlib
 import random
 
 import numpy as np
@@ -17,9 +21,106 @@ import jax.numpy as jnp
 from jax import lax
 
 from tendermint_tpu.crypto import ed25519 as ref
+from tendermint_tpu.crypto.keys import priv_key_from_seed
 from tendermint_tpu.ops import ed25519_jax as dev
 
 P = ref.P
+
+
+# ---------------------------------------------------------------------------
+# End-to-end cases
+# ---------------------------------------------------------------------------
+
+def _gauntlet():
+    """{name: (pub, msg, sig)} covering the honest, tampered and
+    adversarial space ZIP-215 defines: keys from fixed seeds and garbage
+    from SHA-256 of the case's name, so a failure replays."""
+    cases = {}
+    for i in range(6):
+        k = priv_key_from_seed(bytes([i + 81]) * 32)
+        msg = b"height=%d" % i
+        cases[f"honest-{i}"] = (k.pub_key().bytes_(), msg, k.sign(msg))
+    pub, msg, sig = cases["honest-0"]
+    s_plus_l = int.from_bytes(sig[32:], "little") + ref.L
+    cases.update({
+        "tampered-sig": (pub, msg, sig[:-1] + bytes([sig[-1] ^ 1])),
+        "wrong-msg": (pub, b"other", sig),
+        "s-plus-L": (pub, msg, sig[:32] + s_plus_l.to_bytes(32, "little")),
+        "s-above-L": (pub, msg,
+                      sig[:32] + (ref.L + 12345).to_bytes(32, "little")),
+        # y = 2 has no square root
+        "off-curve-A": ((2).to_bytes(32, "little"), msg, sig),
+        "off-curve-R": (pub, msg, (2).to_bytes(32, "little") + sig[32:]),
+    })
+    # small-order A and R with s = 0: valid under cofactored ZIP-215
+    for t, pt in enumerate(ref.eight_torsion_points()[:4]):
+        for e, enc in enumerate(ref.noncanonical_encodings(pt)):
+            cases[f"small-order-A-R-noncanonical-{t}.{e}"] = (
+                enc, b"any", enc + bytes(32))
+    cases.update({
+        "identity-A": (ref.encode_point(ref.IDENTITY), msg, sig),
+        "short-pub": (pub[:31], msg, sig),
+        "short-sig": (pub, msg, sig[:63]),
+    })
+    for i in range(4):
+        name = f"garbage-{i}"
+        h = [hashlib.sha256(b"%s/%d" % (name.encode(), j)).digest()
+             for j in range(4)]
+        cases[name] = (h[0], h[1][:8], h[2] + h[3])
+    return cases
+
+
+GAUNTLET = _gauntlet()
+
+
+def reference_verdict(pub, msg, sig) -> bool:
+    """crypto/ed25519.verify; a row of the wrong length is False."""
+    return len(pub) == 32 and len(sig) == 64 and ref.verify(pub, msg, sig)
+
+
+@functools.cache
+def gauntlet_verdicts(impl) -> dict:
+    """{name: the device verdict}, the cases verified in batches of
+    eight: the floor rung, whose program the golden tests keep warm for
+    both impls, so the gauntlet compiles nothing new.  Once a process
+    and impl."""
+    names, out = list(GAUNTLET), {}
+    for i in range(0, len(names), 8):
+        pubs, msgs, sigs = zip(*(GAUNTLET[n] for n in names[i:i + 8]))
+        got = dev.verify_batch(list(pubs), list(msgs), list(sigs), impl=impl)
+        out.update(zip(names[i:i + 8], (bool(v) for v in got)))
+    return out
+
+
+def batch8():
+    """(pubs, msgs, sigs, want): 8 deterministic signatures, mixed
+    validity (3 corruption modes)."""
+    pubs, msgs, sigs, want = [], [], [], []
+    for i in range(8):
+        k = priv_key_from_seed(bytes([i + 61]) * 32)
+        m = b"packed-e2e-%d" % i
+        s = k.sign(m)
+        ok = True
+        if i == 2:  # corrupted signature byte
+            s = s[:-1] + bytes([s[-1] ^ 1])
+            ok = False
+        elif i == 4:  # wrong message
+            m = b"packed-e2e-other"
+            ok = False
+        elif i == 6:  # non-canonical s (>= L)
+            s_int = int.from_bytes(s[32:], "little") + ref.L
+            s = s[:32] + s_int.to_bytes(32, "little")
+            ok = False
+        pubs.append(k.pub_key().bytes_())
+        msgs.append(m)
+        sigs.append(s)
+        want.append(ok)
+    return pubs, msgs, sigs, want
+
+
+# ---------------------------------------------------------------------------
+# Building blocks of the scalar multiplications
+# ---------------------------------------------------------------------------
 
 # scalars below 2^253 (what s and k are): the ends of the range, the
 # longest propagate chain (all-7 nibbles), the longest generate chain

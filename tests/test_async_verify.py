@@ -337,14 +337,7 @@ def test_a_row_that_still_raises_fails_its_own_submit_only(svc, monkeypatch):
         av.reset_service()
 
 
-@pytest.mark.parametrize("start,end,want", [
-    (0, 9, [("a", 1, 4), ("b", 0, 2), ("c", 2, 6)]),      # whole: itself
-    (0, 3, [("a", 1, 4)]),
-    (2, 6, [("a", 3, 4), ("b", 0, 2), ("c", 2, 3)]),
-    (3, 5, [("b", 0, 2)]),
-    (6, 9, [("c", 3, 6)]),
-])
-def test_batch_cut_keeps_segments_and_rows_aligned(start, end, want):
+def test_batch_keeps_segments_and_rows_aligned():
     def group(tag, n):
         rows = [b"%s%d" % (tag, i) for i in range(n)]
         return av._Group(rows, rows, rows, rows, None, [False] * n, False, 0.0)
@@ -352,12 +345,9 @@ def test_batch_cut_keeps_segments_and_rows_aligned(start, end, want):
     groups = {"a": group(b"a", 4), "b": group(b"b", 2), "c": group(b"c", 6)}
     batch = av._Batch([(groups["a"], 1, 4), (groups["b"], 0, 2),
                        (groups["c"], 2, 6)])
-    assert batch.pubs == [b"a1", b"a2", b"a3", b"b0", b"b1",
-                          b"c2", b"c3", b"c4", b"c5"]
-    sub = batch.cut(start, end)
-    assert (sub is batch) == ((start, end) == (0, 9))
-    assert [(g.pubs[0][:1].decode(), a, b) for g, a, b in sub.segs] == want
-    assert sub.pubs == sub.msgs == sub.sigs == batch.pubs[start:end]
+    assert len(batch) == 9
+    assert batch.pubs == batch.msgs == batch.sigs == [
+        b"a1", b"a2", b"a3", b"b0", b"b1", b"c2", b"c3", b"c4", b"c5"]
     # one whole group is handed on as its own lists: no copy
     assert av._Batch([(groups["c"], 0, 6)]).pubs is groups["c"].pubs
 
@@ -405,24 +395,34 @@ def test_mixed_key_types(svc):
     assert oks[4] is False
 
 
-def test_device_pipelining_enqueues_chunks(monkeypatch):
+def test_device_pipelining_keeps_two_flushes_in_flight(monkeypatch):
     """With a ready 'device' (XLA-CPU program) and a tiny threshold, a
-    coalesced flush routes through the async enqueue path; TM_TPU_CHUNK
-    splits it into pipelined sub-batches drained in order."""
+    submit wider than a flush is cut into flushes of one program each,
+    enqueued behind one another: never more than two in flight, drained
+    in the order they were enqueued, every row in its place."""
     ev = threading.Event()
     ev.set()
     monkeypatch.setattr(cbatch, "_DEVICE_READY", ev)
-    monkeypatch.setenv("TM_TPU_CHUNK", "8")
+    monkeypatch.setattr(av, "MAX_COALESCE", 8)
     s = av.reset_service(linger_ms=5.0, cpu_threshold=8)
     # the conftest forces 8 virtual devices; pin the single-device view
     # so the flush takes the async-enqueue path rather than sharding
     s._jax_bv._n_devices = 1
+    drained, real_drain = [], s._drain_one
+
+    def drain_one(inflight):
+        drained.append((len(inflight), inflight[0][4]))
+        real_drain(inflight)
+
+    monkeypatch.setattr(s, "_drain_one", drain_one)
     try:
-        items, want = _triples(20, bad=(5, 13), tag=b"pipeline")
+        items, want = _triples(24, bad=(5, 13), tag=b"pipeline")
         assert s.verify_many(items) == want
         st = av.service_stats()
-        assert st["device_batches"] >= 3, st  # 8 + 8 + 4 chunks
-        assert st["pipelined_drains"] >= 3, st
+        assert (st["flushes"], st["device_batches"],
+                st["pipelined_drains"]) == (3, 3, 3), st  # 3 x 8 rows
+        # flush 2 was enqueued behind flush 1, flush 3 behind flush 2
+        assert drained == [(2, 1), (2, 2), (1, 3)]
     finally:
         av.reset_service()
 
@@ -458,39 +458,48 @@ def test_device_flush_counts_rows_not_groups(monkeypatch):
         av.reset_service()
 
 
-def test_chunk_that_fails_to_enqueue_sends_only_the_rest_to_the_host(
-        monkeypatch):
-    """TM_TPU_CHUNK splits a flush; the second chunk's enqueue raises.
-    The first chunk keeps its device verdicts, the rest resolves on the
-    host, every row lands exactly once and in its place."""
+def test_enqueue_that_raises_sends_the_whole_flush_to_the_host(monkeypatch):
+    """The first enqueue raises: the whole flush (two submits coalesced)
+    resolves on the host, every row lands exactly once and in its place,
+    and the next flush goes to the device again."""
     from tendermint_tpu.ops import ed25519_jax as dev
 
     ev = threading.Event()
     ev.set()
     monkeypatch.setattr(cbatch, "_DEVICE_READY", ev)
-    monkeypatch.setenv("TM_TPU_CHUNK", "8")
     real, calls = dev._compiled, []
 
-    def second_call_fails(*key):
+    def first_call_fails(*key):
         calls.append(key)
-        if len(calls) == 2:
+        if len(calls) == 1:
             raise RuntimeError("simulated enqueue failure")
         return real(*key)
 
-    monkeypatch.setattr(dev, "_compiled", second_call_fails)
-    s = av.reset_service(linger_ms=1.0, cpu_threshold=8)
+    monkeypatch.setattr(dev, "_compiled", first_call_fails)
+    s = av.reset_service(linger_ms=100.0, cpu_threshold=8)
     s._jax_bv._n_devices = 1
     try:
-        items, want = _triples(16, bad=(3, 8, 15), tag=b"halfway")
+        a, want_a = _triples(10, bad=(3, 8), tag=b"whole-a")
+        b, want_b = _triples(6, bad=(5,), tag=b"whole-b")
         e2e0 = dict(av.VERIFY_E2E_SECONDS.label_stats())
-        assert s.verify_many(items) == want
-        e2e1 = av.VERIFY_E2E_SECONDS.label_stats()
-        assert e2e1[("device",)][0] - e2e0.get(("device",), (0, 0))[0] == 8
-        assert e2e1[("host",)][0] - e2e0.get(("host",), (0, 0))[0] == 8
+        fa, fb = s.submit_many(a), s.submit_many(b)
+        assert (fa.result(timeout=120.0), fb.result(timeout=10.0)) == \
+            (want_a, want_b)
+        e2e1 = dict(av.VERIFY_E2E_SECONDS.label_stats())
+        assert e2e1.get(("device",), (0, 0))[0] == \
+            e2e0.get(("device",), (0, 0))[0]
+        assert e2e1[("host",)][0] - e2e0.get(("host",), (0, 0))[0] == 16
+        st = av.service_stats()
+        assert (st["flushes"], st["device_errors"], st["device_batches"],
+                st["host_flushes"]) == (1, 1, 0, 1), st
+        assert s.last_route == ("host", "device_error")
+
+        c, want_c = _triples(9, bad=(0,), tag=b"whole-c")
+        assert s.verify_many(c) == want_c
         st = av.service_stats()
         assert (st["device_errors"], st["device_batches"],
                 st["host_flushes"]) == (1, 1, 1), st
-        assert s.last_route == ("host", "device_error")
+        assert s.last_route == ("device", "pipelined")
     finally:
         av.reset_service()
 

@@ -219,12 +219,12 @@ def test_roofline_derivations_full():
 
 
 def test_roofline_degrades_field_by_field():
-    rec = CostRecord("rlc", 64, "int64", {}, "lowered")
+    rec = CostRecord("verify", 64, "int64", {}, "lowered")
     roof = costmodel.roofline(rec, exec_by_rung={}, peak=None)
     # nothing known → only the static transfer constants survive
     assert "arithmetic_intensity" not in roof
     assert "achieved_flops_per_s" not in roof
-    assert roof["transfer_bytes_per_row"] == 113  # rlc row width
+    assert roof["transfer_bytes_per_row"] == 129  # 4 x 32 B + the valid bit
     rec.flops = 1.0e6
     roof = costmodel.roofline(rec,
                               exec_by_rung={"64": {"count": 1,
@@ -312,8 +312,7 @@ def test_warm_entry_harvests_compiled_costs(monkeypatch, tmp_path):
     shape_plan.clear_registry()
     try:
         rep = shape_plan.warm_entry("verify", 8, "int64",
-                                    flags={"base_mxu": False,
-                                           "donate": False},
+                                    flags={"donate": False},
                                     serialize=False)
         assert rep["source"] == "aot"
         rec = costmodel.COSTS.lookup("verify", 8, "int64")
@@ -341,15 +340,6 @@ def test_lazy_compiled_registers_pending():
     costmodel.COSTS.record_pending("verify", rung, "int64", {},
                                    lambda: StubLowered({}))
     assert costmodel.COSTS.pending_count() == 1
-
-
-def test_lazy_rlc_registers_pending():
-    from tendermint_tpu.ops import ed25519_jax as dev
-
-    rung = 27183  # see above: unique rung instead of cache_clear()
-    dev._compiled_rlc(rung, "int64", 2048)
-    assert costmodel.COSTS.pending_count() == 1
-    assert costmodel.COSTS.lookup("rlc", rung, "int64") is None
 
 
 def test_record_to_dict_roundtrip_is_json_safe():

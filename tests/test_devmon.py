@@ -121,7 +121,7 @@ def test_compile_tracker_first_call_and_double_compile(capture_logger):
         return "verdicts"
 
     p1 = devmon.track_jit(fake_jit, kind="verify", impl="int64", rung=192,
-                          tracker=tr, base_mxu=False)
+                          tracker=tr, devices=1)
     assert p1("a") == "verdicts"
     assert p1("b") == "verdicts"  # steady state: no second event
     snap = tr.snapshot()
@@ -137,7 +137,7 @@ def test_compile_tracker_first_call_and_double_compile(capture_logger):
     # the same cache key traced again (functools cache cleared): the
     # unexpected-recompile counter and a warn log
     p2 = devmon.track_jit(fake_jit, kind="verify", impl="int64", rung=192,
-                          tracker=tr, base_mxu=False)
+                          tracker=tr, devices=1)
     p2("c")
     snap = tr.snapshot()
     assert snap["total"] == 2 and snap["recompiles"] == 1
@@ -146,7 +146,7 @@ def test_compile_tracker_first_call_and_double_compile(capture_logger):
 
     # a DIFFERENT key (other rung) is a normal compile, not a recompile
     p3 = devmon.track_jit(fake_jit, kind="verify", impl="int64", rung=320,
-                          tracker=tr, base_mxu=False)
+                          tracker=tr, devices=1)
     p3("d")
     assert tr.snapshot()["recompiles"] == 1
 

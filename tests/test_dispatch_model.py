@@ -234,10 +234,6 @@ def test_device_readiness_gates_dispatch(monkeypatch):
             FakeImpl.calls += 1
             return [True] * len(pubs)
 
-        @staticmethod
-        def verify_batch_rlc(pubs, msgs, sigs):
-            raise AssertionError("rlc not expected")
-
     monkeypatch.setattr(v, "_impl", FakeImpl)
     monkeypatch.setattr(v, "_n_devices", 1)
 

@@ -2,8 +2,6 @@
 reference, over honest, tampered, and adversarial (small-order,
 non-canonical) inputs."""
 
-import secrets
-
 import numpy as np
 import pytest
 
@@ -116,7 +114,7 @@ def test_point_add_matches_reference():
 
 
 # ---------------------------------------------------------------------------
-# Signed digits and precomputed-form additions, on the three field backends
+# Signed digits and precomputed-form additions, on both field backends
 # (tests/kernel_cases.py holds the checks; each backend's own file holds them
 # to its operand contract at the bounds)
 # ---------------------------------------------------------------------------
@@ -152,11 +150,7 @@ def test_scalarmul_base_matches_reference(impl):
     kernel_cases.check_scalarmul_base(impl)
 
 
-@pytest.mark.parametrize("impl", [
-    "int64", "packed",
-    # a 75 s XLA-CPU compile of the 51-limb loop body; tier-1 covers the
-    # f32 loop end to end (test_differential_vs_reference_f32)
-    pytest.param("f32", marks=pytest.mark.slow)])
+@IMPLS
 def test_scalarmul_var_matches_reference(impl):
     kernel_cases.check_scalarmul_var(impl)
 
@@ -165,6 +159,13 @@ def test_scalarmul_var_matches_reference(impl):
 def test_field_operation_counts(impl, monkeypatch):
     assert kernel_cases.check_op_counts(impl, monkeypatch) == \
         kernel_cases.OPS_PER_SIGNATURE
+
+
+def test_unknown_impl_is_refused():
+    """A saved plan or `warm --impls` can name anything: an unknown name
+    raises, it does not run int64 under that label."""
+    with pytest.raises(ValueError, match="unknown field impl 'f32'"):
+        dev._field("f32")
 
 
 def test_new_operations_at_input_ceiling(monkeypatch):
@@ -184,62 +185,20 @@ def test_new_operations_at_input_ceiling(monkeypatch):
 # End-to-end differential verification
 # ---------------------------------------------------------------------------
 
-def _make_cases():
-    """(pub, msg, sig) triples covering honest/tampered/adversarial space."""
-    cases = []
-    keys = [gen_priv_key() for _ in range(6)]
-    for i, k in enumerate(keys):
-        msg = f"height={i}".encode()
-        cases.append((k.pub_key().bytes_(), msg, k.sign(msg)))
-    # tampered signature
-    pub, msg, sig = cases[0]
-    cases.append((pub, msg, sig[:-1] + bytes([sig[-1] ^ 1])))
-    # wrong message
-    cases.append((pub, b"other", sig))
-    # non-canonical s (s + L)
-    s = int.from_bytes(sig[32:], "little") + ref.L
-    cases.append((pub, msg, sig[:32] + s.to_bytes(32, "little")))
-    # s >= L random
-    cases.append((pub, msg, sig[:32] + (ref.L + 12345).to_bytes(32, "little")))
-    # off-curve A (y=2 has no sqrt)
-    cases.append(((2).to_bytes(32, "little"), msg, sig))
-    # off-curve R
-    cases.append((pub, msg, (2).to_bytes(32, "little") + sig[32:]))
-    # small-order A and R with s=0: valid under cofactored ZIP-215
-    torsion = ref.eight_torsion_points()
-    s0 = bytes(32)
-    for pt in torsion[:4]:
-        for enc in ref.noncanonical_encodings(pt):
-            cases.append((enc, b"any", enc + s0))
-    # identity pubkey with honest-format sig
-    ident_enc = ref.encode_point(ref.IDENTITY)
-    cases.append((ident_enc, msg, sig))
-    # malformed lengths
-    cases.append((pub[:31], msg, sig))
-    cases.append((pub, msg, sig[:63]))
-    # random garbage
-    for _ in range(4):
-        cases.append(
-            (secrets.token_bytes(32), secrets.token_bytes(8), secrets.token_bytes(64))
-        )
-    return cases
+@pytest.mark.parametrize("case", list(kernel_cases.GAUNTLET))
+@IMPLS
+def test_gauntlet_case_matches_reference(impl, case):
+    """Each case of the ZIP-215 adversarial gauntlet, on each field
+    backend, against crypto/ed25519.verify (a row of the wrong length is
+    False)."""
+    assert kernel_cases.gauntlet_verdicts(impl)[case] == \
+        kernel_cases.reference_verdict(*kernel_cases.GAUNTLET[case])
 
 
-def test_differential_vs_reference():
-    cases = _make_cases()
-    pubs = [c[0] for c in cases]
-    msgs = [c[1] for c in cases]
-    sigs = [c[2] for c in cases]
-    got = dev.verify_batch(pubs, msgs, sigs)
-    want = [
-        ref.verify(p, m, s) if len(p) == 32 and len(s) == 64 else False
-        for p, m, s in zip(pubs, msgs, sigs)
-    ]
-    assert list(got) == want, [
-        (i, bool(g), w) for i, (g, w) in enumerate(zip(got, want)) if bool(g) != w
-    ]
-    # sanity: the case set actually exercises both outcomes
-    assert any(want) and not all(want)
+def test_gauntlet_exercises_both_outcomes():
+    want = [kernel_cases.reference_verdict(*c)
+            for c in kernel_cases.GAUNTLET.values()]
+    assert len(want) == 27 and any(want) and not all(want)
 
 
 def test_rfc8032_vector_on_device():
@@ -299,79 +258,3 @@ def test_carry_stress_at_worst_case_bounds():
             va = fe.int_from_limbs(row_a)
             vg = fe.int_from_limbs(np.asarray(fe.fe_canonical(jnp.asarray(row_g))))
             assert vg == (va * va) % fe.P
-
-
-# ---------------------------------------------------------------------------
-# MXU one-hot fixed-base path (TM_TPU_BASE_MXU)
-# ---------------------------------------------------------------------------
-
-def test_scalarmul_base_mxu_matches_tree_and_reference():
-    """The w=8 one-hot/matmul comb must agree with the w=4 select-tree
-    comb (projectively) and with the big-int reference (affinely) for
-    random and edge scalars, on BOTH field backends."""
-    import jax.numpy as jnp
-
-    rng = np.random.default_rng(5)
-    svals = [0, 1, dev.L - 1] + [
-        int.from_bytes(rng.bytes(32), "little") % dev.L for _ in range(5)
-    ]
-    s_rows_np = np.stack([
-        np.frombuffer(v.to_bytes(32, "little"), dtype=np.uint8) for v in svals
-    ])
-    for impl in dev.IMPLS:
-        if impl == "packed":
-            # the comb's f32 constant table cannot hold 26-bit packed
-            # limbs exactly — structurally incompatible, and
-            # _resolve_optin never routes base_mxu to it (pinned in
-            # test_optin_golden.test_base_mxu_never_consulted_for_packed)
-            continue
-        core = dev._Core(dev._field(impl))
-        f = core.fe
-        s_rows = jnp.asarray(s_rows_np)
-        p_tree = core._scalarmul_base(
-            core._signed_digits(core._nibbles_of(s_rows)))
-        p_mxu = core._scalarmul_base_mxu(s_rows)
-        ex = np.asarray(f.fe_eq(f.fe_mul(p_tree.x, p_mxu.z),
-                                f.fe_mul(p_mxu.x, p_tree.z)))
-        ey = np.asarray(f.fe_eq(f.fe_mul(p_tree.y, p_mxu.z),
-                                f.fe_mul(p_mxu.y, p_tree.z)))
-        assert ex.all() and ey.all(), (impl, ex, ey)
-        # affine check against the big-int reference
-        for i, v in enumerate(svals):
-            want = ref.encode_point(ref.scalar_mult(v, ref.BASE))
-            zi = [int(c) for c in np.asarray(f.fe_canonical(p_mxu.z))[i]]
-            # reconstruct ints from limbs via the backend's radix
-            def limbs_to_int(row):
-                return sum(int(c) << (f.LIMB_BITS * j)
-                           for j, c in enumerate(row)) % ref.P
-            x = limbs_to_int(np.asarray(f.fe_canonical(p_mxu.x))[i])
-            y = limbs_to_int(np.asarray(f.fe_canonical(p_mxu.y))[i])
-            z = limbs_to_int(np.asarray(f.fe_canonical(p_mxu.z))[i])
-            zinv = pow(z, ref.P - 2, ref.P)
-            got = ref.encode_point((x * zinv % ref.P, y * zinv % ref.P, 1,
-                                    x * zinv * y * zinv % ref.P))
-            assert got == want, (impl, i, v)
-
-
-@pytest.mark.slow
-def test_base_mxu_end_to_end_verdicts(monkeypatch):
-    """verify_batch with TM_TPU_BASE_MXU flipped on must return the exact
-    verdicts of the default path on a mixed-validity batch (r5: the flag
-    is env-resolved per call and golden-gated — tests/test_optin_golden
-    covers the gate; this covers verdict parity end to end)."""
-    monkeypatch.setenv("TM_TPU_BASE_MXU", "1")
-    monkeypatch.setattr(dev, "_OPTIN_STATE", {})
-    dev._compiled.cache_clear()
-    try:
-        privs = [gen_priv_key() for _ in range(8)]
-        pubs = [p.pub_key().bytes_() for p in privs]
-        msgs = [b"mxu-%d" % i for i in range(8)]
-        sigs = [p.sign(m) for p, m in zip(privs, msgs)]
-        sigs[3] = bytes(64)
-        sigs[6] = sigs[6][:-1] + bytes([sigs[6][-1] ^ 1])
-        oks = dev.verify_batch(pubs, msgs, sigs)
-        assert [bool(v) for v in oks] == [
-            True, True, True, False, True, True, False, True
-        ]
-    finally:
-        dev._compiled.cache_clear()

@@ -1,24 +1,19 @@
 """Differential tests for the packed (mixed radix 25.5) int64 field
 backend: field-level fuzz vs big-int arithmetic at the documented bound
 ledger, point ops vs the pure reference, and end-to-end batch
-verification — the same gauntlet as the int64 and f32 backends
-(tests/test_ed25519_jax.py, tests/test_ed25519_f32.py), because every
-backend must be bit-identical to ZIP-215.
+verification; the adversarial gauntlet runs on both backends from
+tests/test_ed25519_jax.py (tests/kernel_cases.py holds the cases),
+because every backend must be bit-identical to ZIP-215.
 
 Tier-1 discipline: the end-to-end tests here stick to the warm n=8
 floor rung (one program, already in the persistent compile cache — the
-test_golden_standard_program_tier1 idiom); the full adversarial-case
-gauntlet and the RLC program land on fresh rungs (novel HLOs, a cold
-XLA-CPU compile of about a minute each) and carry `slow` marks.
+test_golden_standard_program_tier1 idiom).
 """
-
-import secrets
 
 import numpy as np
 import pytest
 
 from tendermint_tpu.crypto import ed25519 as ref
-from tendermint_tpu.crypto.keys import gen_priv_key, priv_key_from_seed
 
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
@@ -363,36 +358,10 @@ def test_no_field_operation_round_trips_through_hbm(one_v5e_chip, rung):
 # End-to-end differential verification (warm n=8 rung: tier-1 eligible)
 # ---------------------------------------------------------------------------
 
-def _batch8():
-    """8 deterministic signatures, mixed validity (3 corruption modes)."""
-    pubs, msgs, sigs, want = [], [], [], []
-    for i in range(8):
-        k = priv_key_from_seed(bytes([i + 61]) * 32)
-        m = b"packed-e2e-%d" % i
-        s = k.sign(m)
-        ok = True
-        if i == 2:  # corrupted signature byte
-            s = s[:-1] + bytes([s[-1] ^ 1])
-            ok = False
-        elif i == 4:  # wrong message
-            m = b"packed-e2e-other"
-            ok = False
-        elif i == 6:  # non-canonical s (>= L)
-            s_int = int.from_bytes(s[32:], "little") + ref.L
-            s = s[:32] + s_int.to_bytes(32, "little")
-            ok = False
-        pubs.append(k.pub_key().bytes_())
-        msgs.append(m)
-        sigs.append(s)
-        want.append(ok)
-    return pubs, msgs, sigs, want
-
-
 def test_differential_vs_reference_packed_tier1():
     """End-to-end packed verification on the warm n=8 floor rung agrees
-    with the pure ZIP-215 reference on a mixed-validity batch — the
-    fast-tier differential; the adversarial gauntlet is `slow` below."""
-    pubs, msgs, sigs, want = _batch8()
+    with the pure ZIP-215 reference on a mixed-validity batch."""
+    pubs, msgs, sigs, want = kernel_cases.batch8()
     got = dev.verify_batch(pubs, msgs, sigs, impl="packed")
     assert [bool(v) for v in got] == want
     assert [ref.verify(p, m, s) for p, m, s in zip(pubs, msgs, sigs)] == want
@@ -401,69 +370,10 @@ def test_differential_vs_reference_packed_tier1():
 def test_impls_agree_on_n8_batch():
     """int64 and packed return identical verdict vectors on the warm
     floor rung (both programs persistent-cached)."""
-    pubs, msgs, sigs, want = _batch8()
+    pubs, msgs, sigs, want = kernel_cases.batch8()
     got_i64 = dev.verify_batch(pubs, msgs, sigs, impl="int64")
     got_pk = dev.verify_batch(pubs, msgs, sigs, impl="packed")
     assert list(got_i64) == list(got_pk) == want
-
-
-def _make_cases():
-    cases = []
-    keys = [gen_priv_key() for _ in range(6)]
-    for i, k in enumerate(keys):
-        msg = f"height={i}".encode()
-        cases.append((k.pub_key().bytes_(), msg, k.sign(msg)))
-    pub, msg, sig = cases[0]
-    cases.append((pub, msg, sig[:-1] + bytes([sig[-1] ^ 1])))
-    cases.append((pub, b"other", sig))
-    s = int.from_bytes(sig[32:], "little") + ref.L
-    cases.append((pub, msg, sig[:32] + s.to_bytes(32, "little")))
-    cases.append((pub, msg, sig[:32] + (ref.L + 12345).to_bytes(32, "little")))
-    cases.append(((2).to_bytes(32, "little"), msg, sig))
-    cases.append((pub, msg, (2).to_bytes(32, "little") + sig[32:]))
-    torsion = ref.eight_torsion_points()
-    s0 = bytes(32)
-    for pt in torsion[:4]:
-        for enc in ref.noncanonical_encodings(pt):
-            cases.append((enc, b"any", enc + s0))
-    ident_enc = ref.encode_point(ref.IDENTITY)
-    cases.append((ident_enc, msg, sig))
-    cases.append((pub[:31], msg, sig))
-    cases.append((pub, msg, sig[:63]))
-    for _ in range(4):
-        cases.append(
-            (secrets.token_bytes(32), secrets.token_bytes(8), secrets.token_bytes(64))
-        )
-    return cases
-
-
-@slow
-def test_differential_vs_reference_packed_full():
-    """The full adversarial gauntlet (torsion, non-canonical encodings,
-    identity, malformed rows) — a fresh rung (novel HLO), hence slow."""
-    cases = _make_cases()
-    pubs = [c[0] for c in cases]
-    msgs = [c[1] for c in cases]
-    sigs = [c[2] for c in cases]
-    got = dev.verify_batch(pubs, msgs, sigs, impl="packed")
-    want = [
-        ref.verify(p, m, s) if len(p) == 32 and len(s) == 64 else False
-        for p, m, s in zip(pubs, msgs, sigs)
-    ]
-    assert list(got) == want, [
-        (i, bool(g), w) for i, (g, w) in enumerate(zip(got, want)) if bool(g) != w
-    ]
-    assert any(want) and not all(want)
-
-
-@slow
-def test_rlc_packed_matches_per_row():
-    """The RLC batch equation on the packed backend: honest batch passes
-    the combined check, a tampered batch routes to the exact fallback —
-    verdicts bit-identical to per-row either way."""
-    pubs, msgs, sigs, want = _batch8()
-    got = dev.verify_batch_rlc(pubs, msgs, sigs, impl="packed")
-    assert [bool(v) for v in got] == want
 
 
 def test_rfc8032_vector_on_packed():

@@ -219,14 +219,3 @@ def test_batch_backend_resolves_env_after_import(monkeypatch):
     monkeypatch.setattr(batch, "_DEFAULT_BACKEND", None)
     monkeypatch.setenv("TM_TPU_CRYPTO_BACKEND", "warp-drive")
     assert batch._default_backend() == "auto"
-
-
-def test_fe_mxu_flag_resolves_env_after_import(monkeypatch):
-    from tendermint_tpu.ops import fe25519_f32 as fe32
-
-    monkeypatch.setattr(fe32, "_USE_MXU", None)
-    monkeypatch.setenv("TM_TPU_FE_MXU", "1")
-    assert fe32._use_mxu() is True
-    monkeypatch.setenv("TM_TPU_FE_MXU", "0")
-    fe32.reload_env()
-    assert fe32._use_mxu() is False

@@ -227,10 +227,8 @@ def test_render_impl_comparison_unit():
 def test_synth_rows_match_abstract_shapes():
     from tendermint_tpu.ops import shape_plan
 
-    for kind in ("verify", "rlc"):
-        rows = profile_mod._synth_rows(kind, 8)
-        specs = shape_plan.abstract_rows(kind, 8)
-        assert [tuple(r.shape) for r in rows] == [tuple(s.shape)
-                                                  for s in specs]
-        assert [str(r.dtype) for r in rows] == [str(s.dtype) for s in specs]
-        assert rows[-1].all()  # every valid bit set → full per-row work
+    rows = profile_mod._synth_rows("verify", 8)
+    specs = shape_plan.abstract_rows("verify", 8)
+    assert [tuple(r.shape) for r in rows] == [tuple(s.shape) for s in specs]
+    assert [str(r.dtype) for r in rows] == [str(s.dtype) for s in specs]
+    assert rows[-1].all()  # every valid bit set → full per-row work

@@ -2,7 +2,7 @@
 to the legacy `_bucket` ladder, every flush site's bucket lands on a
 plan rung, the padding bound holds over the exhaustive device-eligible
 sweep, plan JSON / `warm --json` round-trips, the AOT registry feeds
-`_compiled`/`_compiled_rlc`, and a post-warm standard run records zero
+`_compiled`, and a post-warm standard run records zero
 `source="cold"` compile events.
 
 Every test here is compile-free: the AOT compile/serialize hooks are
@@ -39,7 +39,6 @@ def plan_isolation(monkeypatch, tmp_path):
     if shape_plan.registry_snapshot():
         shape_plan.clear_registry()
         dev._compiled.cache_clear()
-        dev._compiled_rlc.cache_clear()
 
 
 @pytest.fixture
@@ -111,15 +110,12 @@ def test_every_flush_site_bucket_maps_to_a_plan_rung():
         for n in range(1, 20_001, 7):
             b = plan_obj.bucket(n)
             assert b in rungs or n > plan_obj.top, (plan_obj.name, n, b)
-            # verify / rlc / async enqueue / pipelined chunks all use
-            # _bucket directly; the sharded sites pad to the mesh:
+            # verify_batch and the async enqueue use _bucket directly;
+            # the sharded sites pad to the mesh:
             for n_dev in (1, 2, 4, 8):
                 bs = max(b, pad_to_multiple(n, n_dev))
                 bs = pad_to_multiple(bs, n_dev)
                 assert bs == b, (plan_obj.name, n, n_dev, bs, b)
-        # chunked dispatch tails land in their own (smaller) bucket
-        for start, end, cb in dev.chunks_of(10_000, 4096):
-            assert plan_obj.bucket(end - start) == cb or cb in rungs
 
 
 def test_plan_json_and_env_overrides(monkeypatch, tmp_path):
@@ -241,23 +237,6 @@ def test_post_warm_run_records_zero_cold_events(monkeypatch, stub_compile):
     assert "aot" in text and "COLD" in text
 
 
-def test_rlc_warm_feeds_compiled_rlc(monkeypatch, stub_compile):
-    tr = devmon.CompileTracker()
-    monkeypatch.setattr(devmon, "TRACKER", tr)
-    lanes = dev.rlc_reduce_lanes()
-    rep = shape_plan.warm_rungs(kinds=("rlc",), rungs=(128,),
-                                impls=("int64",), serialize=False)
-    assert rep[0]["source"] == "aot"
-    assert rep[0]["flags"]["reduce_lanes"] == lanes
-    dev._compiled_rlc.cache_clear()
-    fn = dev._compiled_rlc(128, "int64", lanes)
-    out = fn(np.zeros((128, 32), np.uint8), np.zeros((128, 32), np.uint8),
-             np.zeros((128, 32), np.uint8), np.zeros((128, 16), np.uint8),
-             np.ones(128, bool))
-    assert out.shape == (128,)
-    assert tr.snapshot()["sources"] == {"aot": 1}
-
-
 def test_serialized_artifact_round_trip(monkeypatch, stub_compile):
     """Artifact lifecycle with the serializer stubbed (XLA-CPU cannot
     relocate real executables — measured: 'Symbols not found' — so the
@@ -306,6 +285,34 @@ def test_warm_entry_errors_are_contained(monkeypatch):
     assert shape_plan.aot_lookup(
         "verify", 8, "int64", **shape_plan._entry_flags("verify", "int64")
     ) is None
+
+
+@pytest.mark.parametrize("kind,impl,words", [
+    ("rlc", "int64", "unknown jit kind 'rlc'"),
+    ("verify", "f32", "unknown field impl 'f32'"),
+])
+def test_saved_plan_naming_a_removed_program_fails_that_entry(kind, impl,
+                                                              words):
+    """A plan file is input from outside: an entry whose kind or impl
+    no longer exists is reported failed before anything is traced, and
+    the rest of the grid is still warmed."""
+    (rep,) = shape_plan.warm_rungs(kinds=(kind,), rungs=(8,), impls=(impl,),
+                                   serialize=False)
+    assert rep["source"] == "error" and words in rep["error"]
+
+
+def test_bucket_ladder():
+    assert [dev._bucket(n) for n in (1, 8, 9, 16, 33, 64, 65, 96, 97,
+                                     128, 129, 200)] == \
+        [8, 8, 16, 16, 64, 64, 96, 96, 128, 128, 192, 256]
+    # 5*2^(k-2) rungs from 320 up
+    assert [dev._bucket(n) for n in (300, 321, 500, 600)] == \
+        [320, 384, 512, 640]
+    # the north-star shape: 10k pads 1.024x, not 1.64x
+    assert dev._bucket(10_000) == 10_240
+    assert dev._bucket(10_241) == 12_288
+    assert dev._bucket(12_289) == 16_384
+    assert dev._bucket(16_384) == 16_384
 
 
 def test_warm_plan_saves_and_activates(stub_compile):

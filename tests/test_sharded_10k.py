@@ -39,45 +39,6 @@ def test_10k_commit_batch_sharded_mesh():
     assert ok[good_mask].all()
 
 
-def test_rlc_sharded_pass_and_fallback():
-    """The sharded RLC equation (parallel/sharding.verify_batch_rlc_sharded:
-    shard-local Straus accumulators, host big-int fold) must pass an
-    all-valid batch without fallback and match the reference exactly on
-    a corrupted batch (via the sharded per-row fallback)."""
-    import jax
-
-    from tendermint_tpu.crypto import ed25519 as ref
-    from tendermint_tpu.ops import ed25519_jax as dev
-    from tendermint_tpu.parallel.sharding import (
-        make_mesh,
-        verify_batch_rlc_sharded,
-    )
-
-    assert len(jax.devices()) > 1, "conftest must provide the virtual mesh"
-
-    keys = [priv_key_from_seed(bytes([i + 11]) * 32) for i in range(8)]
-    n = 24
-    pubs, msgs, sigs = [], [], []
-    for i in range(n):
-        k = keys[i % len(keys)]
-        msg = b"rlc-shard-%d" % i
-        pubs.append(k.pub_key().bytes_())
-        msgs.append(msg)
-        sigs.append(k.sign(msg))
-
-    mesh = make_mesh()
-    before = dict(dev.RLC_STATS)
-    ok = verify_batch_rlc_sharded(pubs, msgs, sigs, mesh=mesh)
-    assert ok.shape == (n,) and ok.all()
-    assert dev.RLC_STATS["pass"] == before["pass"] + 1
-    assert dev.RLC_STATS["fallback"] == before["fallback"]
-
-    sigs[7] = sigs[7][:-1] + bytes([sigs[7][-1] ^ 1])
-    ok2 = verify_batch_rlc_sharded(pubs, msgs, sigs, mesh=mesh)
-    assert ok2.tolist() == ref.verify_batch_reference(pubs, msgs, sigs)
-    assert dev.RLC_STATS["fallback"] == before["fallback"] + 1
-
-
 def test_sharded_unsharded_agree_at_bucket_boundary():
     """The production JAXBatchVerifier routes through the sharded path on
     a multi-device mesh (crypto/batch.py); its verdicts must agree with
