@@ -46,16 +46,19 @@ Field backends (TM_TPU_FIELD_IMPL, or the `impl=` argument):
   * "int64"  — 15 limbs × 17 bits in int64 lanes (fe25519.py).  The
     XLA-CPU default (every tier-1 program) and the fallback of the
     start-up golden check.
-  * "packed" — 10 limbs at the mixed radix 25.5 in int64 lanes
-    (fe25519_packed.py).  Same integer datapath, 33% fewer bytes per
-    limb tensor and ~2.2x fewer limb products; what an accelerator runs.
+  * "packed" — 10 limbs at the mixed radix 25.5 stored as uint32 with
+    the limb axis LEADING, uint64 only in the 19 product columns
+    (fe25519_packed.py).  ~2.2x fewer limb products, and no step of a
+    field operation crosses a tiled axis; what an accelerator runs.
 TM_TPU_FIELD_IMPL also accepts "auto" (the default, and what any other
 value reads as): XLA-CPU resolves to "int64" with no golden run (tier-1
 warm cache keys stay bit-identical); accelerator backends run the golden
 differential check once at startup and take packed where it validates,
 else int64 (see _resolve_auto_impl).
-The curve/scalar pipeline below is field-agnostic; both backends share it
-and both are differentially tested against the pure ZIP-215 reference.
+The curve/scalar pipeline below is field-agnostic — it never touches a
+limb axis: a backend brings fe_const, fe_select, fe_parity, limbs_of_bits
+and batch_in / batch_out for its own layout; both backends share it and
+both are differentially tested against the pure ZIP-215 reference.
 
 Static batch sizes: inputs are padded to a bucket ladder — the ACTIVE
 shape plan (ops/shape_plan.py; default: the formula ladder of powers of
@@ -115,7 +118,7 @@ def default_impl() -> str:
 def _resolve_auto_impl() -> str:
     """The "auto" field impl for this process's backend.  cpu: int64,
     immediately (no golden run, no new compiles — the tier-1 contract).
-    An accelerator: the packed int64 layout if it reproduces the golden
+    An accelerator: the packed layout if it reproduces the golden
     verdicts on THIS device, else the historical int64 layout as the
     unconditional fallback.
     Which of packed/int64 SHOULD lead is a question of chip timings
@@ -169,12 +172,6 @@ class _Core:
 
     def __init__(self, fe):
         self.fe = fe
-        # mixed-radix backends (packed) provide their own bits→limbs map;
-        # uniform-width backends keep the reshape path below unchanged
-        # (same traced ops, same persistent-cache keys)
-        self._limbs_of_bits = getattr(fe, "limbs_of_bits", None)
-        if self._limbs_of_bits is None:
-            self._limb_weights = (1 << np.arange(fe.LIMB_BITS, dtype=np.int64))
 
     # -- unpacking -----------------------------------------------------------
 
@@ -191,28 +188,20 @@ class _Core:
         hi = (rows >> 4).astype(jnp.int32)
         return jnp.stack([lo, hi], axis=-1).reshape(rows.shape[:-1] + (2 * rows.shape[-1],))
 
-    def _limbs_of(self, bits255: jnp.ndarray) -> jnp.ndarray:
-        """[..., 255] bits → [..., NLIMBS] limbs, on device."""
-        fe = self.fe
-        if self._limbs_of_bits is not None:
-            return self._limbs_of_bits(bits255)
-        shaped = bits255.reshape(bits255.shape[:-1] + (fe.NLIMBS, fe.LIMB_BITS))
-        w = jnp.asarray(self._limb_weights, dtype=jnp.asarray(fe.ONE).dtype)
-        return (shaped.astype(w.dtype) * w).sum(-1)
-
     # -- curve pipeline ------------------------------------------------------
 
     def decompress(self, y: jnp.ndarray, sign: jnp.ndarray):
         """Permissive (ZIP-215/dalek) decompression.
 
-        y: [..., NLIMBS] limbs of the 255-bit y encoding (possibly >= p —
-        arithmetic tolerates unreduced input); sign: [...] in {0,1}.
-        Returns (point, on_curve).
+        y: an element, the 255-bit y encoding (possibly >= p — arithmetic
+        tolerates unreduced input); sign: [...] in {0,1}, the batch's
+        shape.  Returns (point, on_curve).
         """
         fe = self.fe
+        one = fe.fe_const(fe.ONE, sign.shape)
         yy = fe.fe_sq(y)
-        u = fe.fe_sub(yy, jnp.asarray(fe.ONE))
-        v = fe.fe_carry(fe.fe_add(fe.fe_mul(yy, jnp.asarray(fe.D_CONST)), jnp.asarray(fe.ONE)))
+        u = fe.fe_sub(yy, one)
+        v = fe.fe_carry(fe.fe_add(fe.fe_mul(yy, fe.fe_const(fe.D_CONST, sign.shape)), one))
         v2 = fe.fe_sq(v)
         v3 = fe.fe_mul(v2, v)
         v7 = fe.fe_mul(fe.fe_sq(v3), v)
@@ -222,15 +211,14 @@ class _Core:
         is_pos = fe.fe_eq(vx2, u)
         is_neg = fe.fe_eq(vx2, fe.fe_carry(fe.fe_neg(fe.fe_canonical(u))))
         ok = is_pos | is_neg
-        x = jnp.where(is_neg[..., None], fe.fe_mul(x, jnp.asarray(fe.SQRT_M1_CONST)), x)
+        x = fe.fe_select(is_neg, fe.fe_mul(x, fe.fe_const(fe.SQRT_M1_CONST, sign.shape)), x)
         # sign-bit adjustment on the canonical representative; x=0/sign=1 is
         # accepted and stays 0 mod p — dalek semantics.
         cx = fe.fe_canonical(x)
-        parity = cx[..., 0].astype(jnp.int32) & 1
-        flip = parity != sign
-        x = jnp.where(flip[..., None], fe.fe_carry(fe.fe_neg(cx)), cx)
+        flip = fe.fe_parity(cx) != sign
+        x = fe.fe_select(flip, fe.fe_carry(fe.fe_neg(cx)), cx)
         yr = fe.fe_canonical(y)
-        return fe.Pt(x, yr, jnp.broadcast_to(jnp.asarray(fe.ONE), yr.shape), fe.fe_mul(x, yr)), ok
+        return fe.Pt(x, yr, one, fe.fe_mul(x, yr)), ok
 
     @staticmethod
     def _signed_digits(nibbles: jnp.ndarray) -> jnp.ndarray:
@@ -253,23 +241,23 @@ class _Core:
         return (nibbles + up(gen, 1).astype(jnp.int32)
                 - 16 * gen.astype(jnp.int32))
 
-    @staticmethod
-    def _select_signed(digit: jnp.ndarray, tbl: list, identity):
+    def _select_signed(self, digit: jnp.ndarray, tbl: list, identity):
         """(|digit|·P, digit < 0) from tbl = [1P, ..., 8P], each entry a
         tuple of coordinates in a precomputed form whose negation is the
         addition's business (pt_madd / pt_add_cached take the sign): a
         3-level binary select tree over |digit| - 1 (7 selects a
-        coordinate — elementwise, no gathers), then `identity`, the same
-        form's neutral element, where the digit is 0."""
+        coordinate — elementwise, no gathers), then `identity` (limb
+        vectors), the same form's neutral element, where the digit is 0."""
+        fe = self.fe
         mag = jnp.abs(digit)
         idx = (mag - 1) & 7
         cur = list(tbl)
         for b in range(3):
-            bit = (((idx >> b) & 1) == 1)[..., None]
-            cur = [tuple(jnp.where(bit, hi, lo) for lo, hi in zip(*cur[i:i + 2]))
+            bit = ((idx >> b) & 1) == 1
+            cur = [tuple(fe.fe_select(bit, hi, lo) for lo, hi in zip(*cur[i:i + 2]))
                    for i in range(0, len(cur), 2)]
-        zero = (mag == 0)[..., None]
-        sel = tuple(jnp.where(zero, jnp.asarray(o), c)
+        zero = mag == 0
+        sel = tuple(fe.fe_select(zero, fe.fe_const(o, digit.shape), c)
                     for o, c in zip(identity, cur[0]))
         return sel, digit < 0
 
@@ -310,9 +298,10 @@ class _Core:
 
     @functools.cached_property
     def _fixed_base_tables(self) -> tuple[np.ndarray, ...]:
-        """The shared big-int table encoded as three [64, 8, NLIMBS] limb
-        tensors (y+x, y-x, 2d·x·y — canonical limbs) in this backend's
-        limb dtype.  numpy, NOT jnp: device constants created inside one
+        """The shared big-int table encoded as three [64, 8, NLIMBS]
+        tensors of limb VECTORS (y+x, y-x, 2d·x·y — canonical limbs) in
+        this backend's limb dtype; fe_const places an entry over the
+        batch.  numpy, NOT jnp: device constants created inside one
         jit trace must not be cached across traces; callers convert
         per-trace (XLA folds them into program constants)."""
         fe = self.fe
@@ -334,8 +323,9 @@ class _Core:
 
         def body(i, acc):
             rows = [jnp.take(c, i, axis=0) for c in tables]
-            tbl = [tuple(r[j] for r in rows) for j in range(8)]
             d = jnp.take(digits, i, axis=-1)
+            tbl = [tuple(fe.fe_const(r[j], d.shape) for r in rows)
+                   for j in range(8)]
             return fe.pt_madd(acc, *self._select_signed(d, tbl, identity))
 
         return lax.fori_loop(0, NWINDOWS, body,
@@ -351,10 +341,12 @@ class _Core:
         # per device op, and JAX's persistent-cache key strips it, so
         # the lowered computation and every cached program are unchanged
         with jax.named_scope("ed25519.unpack"):
+            pub_rows, r_rows, s_rows, k_rows, valid = (
+                fe.batch_in(x) for x in (pub_rows, r_rows, s_rows, k_rows, valid))
             pub_bits = self._bits_of(pub_rows)
             r_bits = self._bits_of(r_rows)
-            y_a, sign_a = self._limbs_of(pub_bits[..., :255]), pub_bits[..., 255]
-            y_r, sign_r = self._limbs_of(r_bits[..., :255]), r_bits[..., 255]
+            y_a, sign_a = fe.limbs_of_bits(pub_bits[..., :255]), pub_bits[..., 255]
+            y_r, sign_r = fe.limbs_of_bits(r_bits[..., :255]), r_bits[..., 255]
             s_digits = self._signed_digits(self._nibbles_of(s_rows))
             k_digits = self._signed_digits(self._nibbles_of(k_rows))
         with jax.named_scope("ed25519.decompress_a"):
@@ -369,7 +361,7 @@ class _Core:
             w = fe.pt_add(sb, ka)
             q = fe.pt_add(w, fe.pt_neg(r_pt))
             q8 = fe.pt_dbl_n(q, 3)
-            return valid & ok_a & ok_r & fe.pt_is_identity(q8)
+            return fe.batch_out(valid & ok_a & ok_r & fe.pt_is_identity(q8))
 
 
 @functools.cache

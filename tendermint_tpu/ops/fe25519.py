@@ -36,6 +36,7 @@ from jax import lax
 from tendermint_tpu.crypto import ed25519 as _ref
 
 NLIMBS = 15
+LIMB_AXIS = -1  # an element is int64[*batch, NLIMBS]
 LIMB_BITS = 17
 MASK = (1 << LIMB_BITS) - 1
 
@@ -49,6 +50,44 @@ def limbs_from_int(v: int) -> np.ndarray:
 def int_from_limbs(a) -> int:
     a = np.asarray(a)
     return sum(int(a[..., i]) << (LIMB_BITS * i) for i in range(NLIMBS))
+
+
+# What the verify pipeline (ed25519_jax._Core) needs so as never to touch
+# a limb axis itself; fe25519_packed.py has the same six for its layout.
+
+def fe_const(limbs, batch_shape=()) -> jnp.ndarray:
+    """A limb vector [NLIMBS] (host constant or traced) as an element
+    over `batch_shape`."""
+    return jnp.broadcast_to(jnp.asarray(limbs, dtype=jnp.int64),
+                            tuple(batch_shape) + (NLIMBS,))
+
+
+def fe_select(mask: jnp.ndarray, a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """mask ? a : b, mask a bool of the batch's shape."""
+    return jnp.where(mask[..., None], a, b)
+
+
+def fe_parity(a: jnp.ndarray) -> jnp.ndarray:
+    """The low bit of a CANONICAL element, int32 of the batch's shape."""
+    return a[..., 0].astype(jnp.int32) & 1
+
+
+def batch_in(rows: jnp.ndarray) -> jnp.ndarray:
+    """verify_core's input rows [N, ...] in this module's batch shape:
+    as they are."""
+    return rows
+
+
+def batch_out(verdicts: jnp.ndarray) -> jnp.ndarray:
+    return verdicts
+
+
+def limbs_of_bits(bits255: jnp.ndarray) -> jnp.ndarray:
+    """[..., 255] LE bits -> [..., NLIMBS] limbs, on device: uniform
+    widths, so one reshape and one weighted sum."""
+    shaped = bits255.reshape(bits255.shape[:-1] + (NLIMBS, LIMB_BITS))
+    w = jnp.asarray(1 << np.arange(LIMB_BITS, dtype=np.int64))
+    return (shaped.astype(jnp.int64) * w).sum(-1)
 
 
 # ---------------------------------------------------------------------------

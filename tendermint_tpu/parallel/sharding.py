@@ -14,6 +14,8 @@ cross-batch communication exists.
 
 from __future__ import annotations
 
+import math
+
 import numpy as np
 
 import jax
@@ -39,9 +41,13 @@ def pad_to_multiple(n: int, m: int) -> int:
 def sharded_bucket(n: int, n_dev: int) -> int:
     """Padded batch size for an n-row flush sharded over n_dev devices:
     the single-chip bucket-ladder rung, rounded up to a device multiple
-    so every shard is equal-sized."""
+    so every shard is equal-sized — and to a multiple of 8 rows, which
+    the packed program folds [N] -> [N/8, 8] (fe25519_packed.batch_in).
+    Every ladder rung is one already on 1, 2, 4 or 8 devices; at the
+    rungs a mesh shards by default a shard is whole groups of 8 too, so
+    its rows stay on its chip."""
     b = max(_dev._bucket(n), pad_to_multiple(n, n_dev))
-    return pad_to_multiple(b, n_dev)
+    return pad_to_multiple(b, math.lcm(8, n_dev))
 
 
 def device_ids(mesh: Mesh) -> tuple:

@@ -153,6 +153,39 @@ def points():
     return rand + tors + [ref.pt_add(rand[0], tors[3])]
 
 
+# The two helpers every case goes through, whatever a backend's layout
+# (fe.LIMB_AXIS: -1 for int64's [*batch, NLIMBS], 0 for packed's
+# [NLIMBS, *batch]): `elems`, ints → a batch of elements, and `ints_of`,
+# elements → ints; `stack_limbs` / `raw_ints` are the same two for limb
+# patterns that are not reduced (the at-the-bound cases).
+
+def stack_limbs(fe, vectors):
+    """Limb vectors [NLIMBS] → one batch of elements, as they are (not
+    reduced: the at-the-bound cases put any pattern in a limb)."""
+    return jnp.asarray(np.stack(vectors, axis=0 if fe.LIMB_AXIS == -1 else -1))
+
+
+def elems(fe, vals):
+    """Python ints below 2^255 → one batch of elements."""
+    return stack_limbs(fe, [fe.limbs_from_int(v) for v in vals])
+
+
+def limb_rows(fe, elem):
+    """A batch of elements → numpy [n, NLIMBS], the limbs as stored."""
+    a = np.moveaxis(np.asarray(elem), fe.LIMB_AXIS, -1)
+    return a.reshape(-1, fe.NLIMBS)
+
+
+def raw_ints(fe, elem):
+    """The value each element's limbs stand for, unreduced."""
+    return [fe.int_from_limbs(row) for row in limb_rows(fe, elem)]
+
+
+def ints_of(fe, elem):
+    """Elements → their canonical values mod p."""
+    return [v % P for v in raw_ints(fe, fe.fe_canonical(jnp.asarray(elem)))]
+
+
 def to_dev(fe, pts, z=1):
     """Big-int points → one batched fe.Pt: (x·z, y·z, z, x·y·z), the
     affine representative for z = 1."""
@@ -160,13 +193,8 @@ def to_dev(fe, pts, z=1):
     for q in pts:
         x, y = affine(q)
         for col, v in zip(cols, (x * z, y * z, z, x * y * z)):
-            col.append(fe.limbs_from_int(v % P))
-    return fe.Pt(*(jnp.asarray(np.stack(c)) for c in cols))
-
-
-def ints_of(fe, limbs):
-    out = np.asarray(fe.fe_canonical(jnp.asarray(limbs)))
-    return [fe.int_from_limbs(row) % P for row in out]
+            col.append(v % P)
+    return fe.Pt(*(elems(fe, c) for c in cols))
 
 
 def assert_points_equal(fe, got, want, what):
@@ -187,8 +215,8 @@ def niels_of(fe, pts):
         x, y = affine(q)
         for col, v in zip(cols, ((y + x) % P, (y - x) % P,
                                  2 * ref.D * x * y % P)):
-            col.append(fe.limbs_from_int(v))
-    return tuple(jnp.asarray(np.stack(c)) for c in cols)
+            col.append(v)
+    return tuple(elems(fe, c) for c in cols)
 
 
 def _pairs():
@@ -351,7 +379,7 @@ def check_products_at_bounds(fe, monkeypatch, patterns, contract_ok):
 
     def checked_mul(a, b):
         assert contract_ok(np.asarray(a), np.asarray(b)), (
-            np.abs(np.asarray(a)).max(), np.abs(np.asarray(b)).max())
+            np.asarray(a).max(), np.asarray(b).max())
         seen.append(1)
         return real_mul(a, b)
 
@@ -362,15 +390,15 @@ def check_products_at_bounds(fe, monkeypatch, patterns, contract_ok):
     idx = [(i, j, s) for i in range(n) for j in range(n) for s in (False, True)]
 
     def col(k):  # coordinate k of point `which` takes pattern (i + k) % n
-        return lambda which: jnp.asarray(np.stack(
-            [patterns[(t[which] + k) % n] for t in idx]))
+        return lambda which: stack_limbs(
+            fe, [patterns[(t[which] + k) % n] for t in idx])
 
     p = fe.Pt(*(col(k)(0) for k in range(4)))
     q = fe.Pt(*(col(k + 1)(1) for k in range(4)))
     neg = jnp.asarray([t[2] for t in idx])
 
-    def val(limbs):
-        return [fe.int_from_limbs(r) % P for r in np.asarray(limbs)]
+    def val(elem):
+        return [v % P for v in raw_ints(fe, elem)]
 
     px, py, pz, pt = (val(c) for c in p.astuple())
     qx, qy, qz, qt = (val(c) for c in q.astuple())

@@ -91,6 +91,25 @@ def test_decide_policy(monkeypatch):
     assert md.decide(63, 8) == ("pinned", 1)
 
 
+@pytest.mark.parametrize("n_dev", [1, 2, 3, 4, 6, 8])
+def test_sharded_bucket_is_whole_groups_of_eight(n_dev):
+    """The packed program folds its rows [N] -> [N/8, 8]: a sharded
+    bucket is a multiple of the device count AND of 8 on any mesh (a 3-
+    or 6-device one used to get 10,242 rows for a 10k commit), and on
+    the meshes a host has (1, 2, 4, 8) it is the size it always was."""
+    from tendermint_tpu.ops import ed25519_jax as dev
+    from tendermint_tpu.parallel.sharding import pad_to_multiple, sharded_bucket
+
+    for n in (1, 63, 65, 257, 667, 1000, 10_000, 16_134, 20_000):
+        b = sharded_bucket(n, n_dev)
+        assert b >= n and b % n_dev == 0 and b % 8 == 0, (n, b)
+        if n_dev in (1, 2, 4, 8):
+            assert b == pad_to_multiple(
+                max(dev._bucket(n), pad_to_multiple(n, n_dev)), n_dev)
+    # where a mesh shards by default, a shard is whole groups of 8 rows
+    assert sharded_bucket(10_000, 4) // 4 % 8 == 0
+
+
 def test_dispatcher_shards_large_flush(monkeypatch):
     """A 64-row mixed-validity flush on the 8-device mesh takes the
     sharded route with verdicts identical to the single-device program."""
