@@ -19,6 +19,8 @@ from fractions import Fraction
 
 from tendermint_tpu.types.basic import now_ns as _now_ns
 from tendermint_tpu.types.light import LightBlock
+from tendermint_tpu.utils import trace as _trace
+from tendermint_tpu.utils.metrics import Counter
 
 from . import verifier
 from .detector import detect_divergence
@@ -40,6 +42,21 @@ SKIPPING = "skipping"
 DEFAULT_PRUNING_SIZE = 1000  # reference client.go:40
 DEFAULT_MAX_CLOCK_DRIFT_NS = 10 * 1_000_000_000  # client.go:46
 SEQUENTIAL_BATCH_WINDOW = 64  # blocks per batched device call
+
+# Bumped where a skipping client tries a jump and where any client
+# fetches a light block (process-wide; registered by node/metrics.py).
+HOPS_TOTAL = Counter(
+    "hops_total",
+    "Jumps tried by skipping verification: accepted (the candidate became "
+    "trusted), refused (too little trusted power: a pivot), failed",
+    namespace="tendermint", subsystem="light", label_names=("outcome",),
+)
+FETCHES_TOTAL = Counter(
+    "fetches_total", "Light blocks fetched from a provider and validated",
+    namespace="tendermint", subsystem="light",
+)
+LIGHT_COUNTERS = (HOPS_TOTAL, verifier.TRUSTING_ROWS_TOTAL,
+                  verifier.REFUSED_ROWS_TOTAL, FETCHES_TOTAL)
 
 
 @dataclass
@@ -94,6 +111,9 @@ class Client:
         # a gateway-driven client points this at the cross-client verify
         # coalescer so N clients syncing one chain share device flushes
         self.commit_verifier = commit_verifier
+        # what the verification under way did so far: the attributes of
+        # its `light.verify_to_height` span
+        self._walk = {"hops": 0, "refused": 0, "fetched": 0}
         self.latest_trusted: LightBlock | None = self.store.latest_light_block()
         self._initialize(trust_options)
 
@@ -161,8 +181,17 @@ class Client:
             raise LightClientError("no trusted state")
         if height < self.latest_trusted.height:
             return self._backwards(height, now)
-        target = self._light_block_from_primary(height)
-        self._verify_light_block(target, now)
+        # spans (utils/trace): the whole forward verification, then one
+        # `light.hop` a jump tried, one `light.fetch` a block fetched and
+        # one `light.store` for the saves (docs/observability.md)
+        self._walk = walk = {"hops": 0, "refused": 0, "fetched": 0}
+        with _trace.span("light.verify_to_height", mode=self.mode, target=height,
+                         **{"from": self.latest_trusted.height}) as sp:
+            try:
+                target = self._light_block_from_primary(height)
+                self._verify_light_block(target, now)
+            finally:
+                sp.set(**walk)
         return target
 
     # -- forward verification -------------------------------------------
@@ -184,9 +213,10 @@ class Client:
         # client.go:551-581).
         if self.witnesses:
             detect_divergence(self, trace, now)
-        for lb in trace[1:]:
-            self.store.save_light_block(lb)
-        self._update_trusted_light_block(trace[-1] if trace else new_lb)
+        with _trace.span("light.store", blocks=len(trace) - 1):
+            for lb in trace[1:]:
+                self.store.save_light_block(lb)
+            self._update_trusted_light_block(trace[-1] if trace else new_lb)
 
     def _verify_sequential(
         self, trusted: LightBlock, target: LightBlock, now: int
@@ -286,21 +316,39 @@ class Client:
         depth = 0
         verified = trusted
         trace = [trusted]
+        walk = self._walk
         while True:
             candidate = cache[depth]
-            try:
-                verifier.verify(
-                    verified.signed_header,
-                    verified.validator_set,
-                    candidate.signed_header,
-                    candidate.validator_set,
-                    self.trusting_period_ns,
-                    now,
-                    self.max_clock_drift_ns,
-                    self.trust_level,
-                    commit_verifier=self.commit_verifier,
-                )
-            except ErrNewValSetCantBeTrusted:
+            failure = None
+            with _trace.span("light.hop", trusted=verified.height,
+                             candidate=candidate.height) as sp:
+                try:
+                    verifier.verify(
+                        verified.signed_header,
+                        verified.validator_set,
+                        candidate.signed_header,
+                        candidate.validator_set,
+                        self.trusting_period_ns,
+                        now,
+                        self.max_clock_drift_ns,
+                        self.trust_level,
+                        commit_verifier=self.commit_verifier,
+                    )
+                    outcome = "accepted"
+                except ErrNewValSetCantBeTrusted:
+                    outcome = "refused"
+                except (LightClientError, ValueError) as e:
+                    # a wrong signature or a double vote in the trusting
+                    # check arrives as the ValueError it is: a failed
+                    # verification, never a pivot (reference client.go:744)
+                    outcome, failure = "failed", e
+                sp.set(outcome=outcome)
+            HOPS_TOTAL.inc(outcome=outcome)
+            if failure is not None:
+                raise ErrVerificationFailed(
+                    verified.height, candidate.height, failure) from failure
+            if outcome == "refused":
+                walk["refused"] += 1
                 if depth == len(cache) - 1:
                     pivot = (candidate.height + verified.height) // 2
                     if pivot in (verified.height, candidate.height):
@@ -311,9 +359,8 @@ class Client:
                         )
                     cache.append(self._light_block_from(source, pivot))
                 depth += 1
-            except LightClientError as e:
-                raise ErrVerificationFailed(verified.height, candidate.height, e)
             else:
+                walk["hops"] += 1
                 verified = candidate
                 trace.append(verified)
                 if depth == 0:
@@ -362,8 +409,11 @@ class Client:
     # -- provider management --------------------------------------------
 
     def _light_block_from(self, source: Provider, height: int) -> LightBlock:
-        lb = source.light_block(height)
-        lb.validate_basic(self.chain_id)
+        with _trace.span("light.fetch", height=height):
+            lb = source.light_block(height)
+            lb.validate_basic(self.chain_id)
+        self._walk["fetched"] += 1
+        FETCHES_TOTAL.inc()
         return lb
 
     def _light_block_from_primary(self, height: int) -> LightBlock:

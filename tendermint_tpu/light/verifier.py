@@ -19,9 +19,11 @@ from fractions import Fraction
 from tendermint_tpu.types.light import LightBlock, SignedHeader
 from tendermint_tpu.types.validator import (
     CommitVerifyJob,
+    ErrNotEnoughVotingPowerSigned,
     ValidatorSet,
     batch_verify_commits,
 )
+from tendermint_tpu.utils.metrics import Counter
 
 from .errors import (
     ErrInvalidHeader,
@@ -30,6 +32,21 @@ from .errors import (
 )
 
 DEFAULT_TRUST_LEVEL = Fraction(1, 3)
+
+# Bumped once a trusting check, in `verify_non_adjacent` (process-wide;
+# registered by node/metrics.py with light/client.py's LIGHT_COUNTERS):
+# the rows of accepted jumps, and the rows a refused jump verified before
+# it ran out of trusted power (0 where a refused jump shares no validator).
+TRUSTING_ROWS_TOTAL = Counter(
+    "trusting_rows_total",
+    "Commit rows verified by trusting checks that found enough trusted power",
+    namespace="tendermint", subsystem="light",
+)
+REFUSED_ROWS_TOTAL = Counter(
+    "refused_rows_total",
+    "Commit rows verified by trusting checks that ran out of trusted power",
+    namespace="tendermint", subsystem="light",
+)
 
 
 def validate_trust_level(lvl: Fraction) -> None:
@@ -130,7 +147,11 @@ def verify_non_adjacent(
 
     Raises ErrNewValSetCantBeTrusted if less than trust_level of the
     trusted set signed the new header (→ bisection pivot), ErrInvalidHeader
-    if the new set's own commit does not carry +2/3.
+    if the new set's own commit does not carry +2/3.  Any other failure
+    of the trusting check — a wrong signature, a double vote — passes
+    through as the ValueError it is (reference verifier.go:73-80 maps
+    only ErrNotEnoughVotingPowerSigned): the caller fails the
+    verification and does not pivot.
     """
     if untrusted_header.height == trusted_header.height + 1:
         raise ValueError("headers must be non adjacent in height")
@@ -144,11 +165,13 @@ def verify_non_adjacent(
 
     chain_id = trusted_header.header.chain_id
     try:
-        trusted_vals.verify_commit_light_trusting(
+        rows = trusted_vals.verify_commit_light_trusting(
             chain_id, untrusted_header.commit, trust_level
         )
-    except ValueError as e:
+    except ErrNotEnoughVotingPowerSigned as e:
+        REFUSED_ROWS_TOTAL.inc(e.rows)
         raise ErrNewValSetCantBeTrusted(str(e)) from e
+    TRUSTING_ROWS_TOTAL.inc(rows)
 
     try:
         _verify_commit_light(untrusted_vals, chain_id, untrusted_header,

@@ -509,13 +509,14 @@ class NodeMetrics:
             WINDOW_COUNTERS as _bsync_window_counters,
         )
         from tendermint_tpu.consensus.state import STEP_DURATION_SECONDS
+        from tendermint_tpu.light.client import LIGHT_COUNTERS as _light_counters
         from tendermint_tpu.rpc.server import (
             REQUEST_DURATION_SECONDS as _rpc_hist,
         )
 
         self.step_duration = reg.register(STEP_DURATION_SECONDS)
         self.blocksync_request_duration = reg.register(_bsync_hist)
-        for counter in _bsync_window_counters:
+        for counter in _bsync_window_counters + _light_counters:
             reg.register(counter)
         self.rpc_request_duration = reg.register(_rpc_hist)
         for hist in _av.PIPELINE_HISTOGRAMS:

@@ -43,6 +43,19 @@ def _clip(v: int) -> int:
     return max(_I64_MIN, min(_I64_MAX, v))
 
 
+class ErrNotEnoughVotingPowerSigned(ValueError):
+    """The signatures a commit check consulted were all valid and carry
+    too little power (reference types/errors.go
+    ErrNotEnoughVotingPowerSigned).  A class of its own because the light
+    client's skipping verification answers THIS failure with a bisection
+    pivot and every other one (a wrong signature, a double vote) with a
+    failed verification.  `rows`: the signatures verified on the way."""
+
+    def __init__(self, msg: str, got: int, needed: int, rows: int):
+        super().__init__(msg)
+        self.got, self.needed, self.rows = got, needed, rows
+
+
 _PK_PROTO_CACHE: dict[bytes, bytes] = {}
 
 
@@ -370,9 +383,11 @@ class ValidatorSet:
             [CommitVerifyJob(self, chain_id, block_id, height, commit, mode="light")]
         )
 
-    def verify_commit_light_trusting(self, chain_id: str, commit, trust_level: Fraction) -> None:
+    def verify_commit_light_trusting(self, chain_id: str, commit, trust_level: Fraction) -> int:
         """Address-matched verification to trust_level of this set's power
-        (light-client skipping verification, reference :776-830)."""
+        (light-client skipping verification, reference :776-830).  Returns
+        the number of signatures it verified; raises
+        ErrNotEnoughVotingPowerSigned when they were valid and too few."""
         if trust_level.denominator == 0:
             raise ValueError("trustLevel has zero denominator")
         if commit is None:
@@ -384,6 +399,7 @@ class ValidatorSet:
         entries = []
         seen: dict[int, int] = {}
         running = 0
+        double_vote = None
         # the same commit.* spans as batch_verify_commits, one per phase
         with _trace.span("commit.select", mode="trusting") as sp:
             for idx, cs in enumerate(commit.signatures):
@@ -393,9 +409,11 @@ class ValidatorSet:
                 if val is None:
                     continue
                 if val_idx in seen:
-                    raise ValueError(
-                        f"double vote from validator {val_idx} ({seen[val_idx]} and {idx})"
-                    )
+                    # the reference verifies row by row, so a wrong
+                    # signature BEFORE the second vote is what it reports:
+                    # the rows selected so far are verified first
+                    double_vote = (val_idx, seen[val_idx], idx)
+                    break
                 seen[val_idx] = idx
                 entries.append((idx, val, val.voting_power))
                 running += val.voting_power
@@ -418,8 +436,15 @@ class ValidatorSet:
                     raise ValueError(f"wrong signature (#{idx})")
                 tallied += power
                 if tallied > needed:
-                    return
-            raise ValueError(f"insufficient voting power: got {tallied}, needed >{needed}")
+                    return len(entries)
+            if double_vote is not None:
+                raise ValueError(
+                    "double vote from validator %d (%d and %d)" % double_vote
+                )
+            raise ErrNotEnoughVotingPowerSigned(
+                f"insufficient voting power: got {tallied}, needed >{needed}",
+                tallied, needed, len(entries),
+            )
 
     def _check_commit_basics(self, chain_id: str, block_id: BlockID, height: int, commit) -> None:
         if commit is None:
@@ -575,7 +600,8 @@ def batch_verify_commits(jobs: list[CommitVerifyJob]) -> None:
                 if job.mode == "light" or job.commit.signatures[idx].for_block():
                     tallied += power
             if tallied <= needed:
-                raise ValueError(
+                raise ErrNotEnoughVotingPowerSigned(
                     f"insufficient voting power for height {job.height}: "
-                    f"got {tallied}, needed >{needed}"
+                    f"got {tallied}, needed >{needed}",
+                    tallied, needed, len(entries),
                 )

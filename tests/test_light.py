@@ -646,3 +646,105 @@ def test_byzantine_validators_amnesia_not_attributable(chain):
         chain.blocks[5].validator_set, real.signed_header
     )
     assert byz == []
+
+
+# -- the error mapping of skipping verification (reference verifier.go:73-80,
+#    client.go verifySkipping) ---------------------------------------------
+
+
+class _CountingProvider(MemoryProvider):
+    """Records the heights it was asked for: a pivot shows as a fetch."""
+
+    def __init__(self, chain_id, blocks):
+        super().__init__(chain_id, blocks)
+        self.asked = []
+
+    def light_block(self, height):
+        self.asked.append(height)
+        return super().light_block(height)
+
+
+def _with_commit_rows(lb, rows):
+    """`lb` with its commit's rows replaced (the header, and so the block
+    id the rows sign, kept)."""
+    commit = Commit(height=lb.commit.height, round=lb.commit.round,
+                    block_id=lb.commit.block_id, signatures=rows)
+    return LightBlock(signed_header=SignedHeader(header=lb.header, commit=commit),
+                      validator_set=lb.validator_set)
+
+
+def _skipping_client_on(blocks, target):
+    provider = _CountingProvider(CHAIN_ID, blocks)
+    client = Client(
+        CHAIN_ID, TrustOptions(period_ns=PERIOD, height=1, hash=blocks[1].hash()),
+        provider, [], mode=SKIPPING, now_fn=lambda: now_at(target))
+    provider.asked.clear()
+    return client, provider
+
+
+def test_too_little_trusted_power_is_a_pivot():
+    from tendermint_tpu.types.validator import ErrNotEnoughVotingPowerSigned
+
+    c = LightChain().extend(3)
+    c.extend(1, next_keys=_keys([21, 22, 23, 24]))  # a full rotation at height 5
+    c.extend(5)
+    with pytest.raises(ErrNotEnoughVotingPowerSigned) as e:
+        c.blocks[1].validator_set.verify_commit_light_trusting(
+            CHAIN_ID, c.blocks[8].commit, Fraction(1, 3))
+    assert isinstance(e.value, ValueError)
+    assert (e.value.got, e.value.needed, e.value.rows) == (0, 13, 0)
+    with pytest.raises(ErrNewValSetCantBeTrusted):
+        verify_non_adjacent(
+            c.blocks[1].signed_header, c.blocks[1].validator_set,
+            c.blocks[8].signed_header, c.blocks[8].validator_set,
+            PERIOD, now_at(8), DRIFT)
+    client, provider = _skipping_client_on(c.blocks, 8)
+    assert client.verify_light_block_at_height(8, now_at(8)).hash() == c.blocks[8].hash()
+    assert provider.asked[0] == 8 and len(provider.asked) > 1     # it pivoted
+
+
+def test_wrong_signature_in_the_trusting_check_fails_and_does_not_pivot(chain):
+    from tendermint_tpu.light import ErrVerificationFailed
+
+    rows = list(chain.blocks[9].commit.signatures)
+    rows[0] = CommitSig(rows[0].block_id_flag, rows[0].validator_address,
+                        rows[0].timestamp_ns, b"\x05" * 64)
+    blocks = {**chain.blocks, 9: _with_commit_rows(chain.blocks[9], rows)}
+    with pytest.raises(ValueError, match=r"wrong signature \(#0\)") as e:
+        verify_non_adjacent(
+            blocks[1].signed_header, blocks[1].validator_set,
+            blocks[9].signed_header, blocks[9].validator_set,
+            PERIOD, now_at(9), DRIFT)
+    assert not isinstance(e.value, LightClientError)
+    client, provider = _skipping_client_on(blocks, 9)
+    with pytest.raises(ErrVerificationFailed) as failed:
+        client.verify_light_block_at_height(9, now_at(9))
+    assert (failed.value.from_height, failed.value.to_height) == (1, 9)
+    assert "wrong signature (#0)" in str(failed.value.reason)
+    assert provider.asked == [9]                  # the target, and no pivot
+    assert client.last_trusted_height() == 1
+
+
+def test_double_vote_in_the_trusting_check_fails_and_does_not_pivot(chain):
+    from tendermint_tpu.light import ErrVerificationFailed
+
+    rows = list(chain.blocks[9].commit.signatures)
+    rows[1] = rows[0]                             # validator 0 votes twice
+    blocks = {**chain.blocks, 9: _with_commit_rows(chain.blocks[9], rows)}
+    with pytest.raises(ValueError, match=r"double vote from validator 0 \(0 and 1\)") as e:
+        verify_non_adjacent(
+            blocks[1].signed_header, blocks[1].validator_set,
+            blocks[9].signed_header, blocks[9].validator_set,
+            PERIOD, now_at(9), DRIFT)
+    assert not isinstance(e.value, LightClientError)
+    client, provider = _skipping_client_on(blocks, 9)
+    with pytest.raises(ErrVerificationFailed) as failed:
+        client.verify_light_block_at_height(9, now_at(9))
+    assert "double vote" in str(failed.value.reason) and provider.asked == [9]
+    # a wrong signature BEFORE the second vote is what the reference reports
+    rows[0] = CommitSig(rows[0].block_id_flag, rows[0].validator_address,
+                        rows[0].timestamp_ns, b"\x05" * 64)
+    rows[1] = rows[0]
+    with pytest.raises(ValueError, match=r"wrong signature \(#0\)"):
+        blocks[1].validator_set.verify_commit_light_trusting(
+            CHAIN_ID, _with_commit_rows(chain.blocks[9], rows).commit, Fraction(1, 3))
