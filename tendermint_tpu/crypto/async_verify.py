@@ -12,13 +12,17 @@ iteration-level scheduling of inference serving, applied to signature
 verification; PAPERS.md):
 
   * `submit_many(items) -> Future[list[bool]]` (and `submit(pub, msg,
-    sig) -> Future[bool]`, a submit of one row) never blocks.  The unit
-    the service queues, accounts and resolves is the SUBMIT — a group of
-    rows with one future — not the row: a 10,000-row commit costs one
-    future, one queue entry, one cache pass each way and a handful of
-    histogram observes with a count.  Groups from independent callers
-    land in ONE submission queue; a daemon worker coalesces them into a
-    single batch (cutting a group that is wider than what a flush has
+    sig) -> Future[bool]`, a submit of one row) never blocks.
+    `submit_columns(pubs, msgs, sigs)` is the same entry for a caller
+    that holds its rows as three columns (the commit surfaces, through
+    `ServiceBatchVerifier.add_many`): an items submit is unzipped into
+    columns and shares everything with it from the key pass on.  The
+    unit the service queues, accounts and resolves is the SUBMIT — a
+    group of rows with one future — not the row: a 10,000-row commit
+    costs one future, one queue entry, one cache pass each way and a
+    handful of histogram observes with a count.  Groups from
+    independent callers land in ONE submission queue; a daemon worker
+    coalesces them into a single batch (cutting a group that is wider than what a flush has
     left) and dispatches when the queued rows reach a size rung from
     the `_bucket` ladder or when a linger deadline (`TM_TPU_LINGER_MS`)
     expires.  Below-threshold flushes route to the host path exactly as
@@ -90,7 +94,7 @@ from tendermint_tpu.utils.metrics import Histogram
 from . import ed25519 as _ed
 from . import batch as _batch
 from . import mesh_dispatch as _mesh
-from .batch import _pub_bytes, _split_verify
+from .batch import _BaseBatch, _bytes_column, _pub_bytes, _split_verify
 
 _log = logging.getLogger("tendermint_tpu.crypto.async_verify")
 
@@ -374,24 +378,38 @@ class VerifyService:
         """Queue one verification; resolves to bool — a submit of one
         row, its result unwrapped.  A cache hit resolves immediately
         without queueing."""
-        return self._submit([(pub, msg, sig)], single=True).future
+        return self._submit((pub,), (msg,), (sig,), single=True).future
 
     def submit_many(self, items) -> Future:
         """Bulk submit: ONE future for the whole submit, resolving to
         the list of verdicts in input order (immediately if every row
         hit the cache)."""
-        return self._submit(items).future
+        return self._submit_items(items).future
 
-    def _submit(self, items, single: bool = False) -> _Group:
+    def submit_columns(self, pubs, msgs, sigs) -> Future:
+        """`submit_many` for a caller that holds its rows as three
+        columns of equal length (the commit surfaces): nothing is zipped
+        into tuples to be taken apart again."""
+        return self._submit(pubs, msgs, sigs).future
+
+    def _submit_items(self, items) -> _Group:
+        t_sub = time.perf_counter()  # the unzip is part of the submit
+        pubs, msgs, sigs = tuple(zip(*items)) or ((), (), ())
+        return self._submit(pubs, msgs, sigs, t_sub=t_sub)
+
+    def _submit(self, pubs, msgs, sigs, single: bool = False,
+                t_sub: float | None = None) -> _Group:
         """Build and queue the group of one submit: every pass is bulk —
         the key hashes in one comprehension, the cache probe and the
         queue append under one lock acquisition each — so a 10k commit
         pays nothing per row but the hashing itself."""
-        t_sub = time.perf_counter()  # one stamp per submit
-        cols = tuple(zip(*items)) or ((), (), ())
-        pubs = _bytes_column(cols[0], _pub_bytes)
-        msgs = _bytes_column(cols[1])
-        sigs = _bytes_column(cols[2])
+        if t_sub is None:
+            t_sub = time.perf_counter()  # one stamp per submit
+        if not len(pubs) == len(msgs) == len(sigs):
+            raise ValueError("submit: columns of unequal length")
+        pubs = _bytes_column(pubs, _pub_bytes)
+        msgs = _bytes_column(msgs)
+        sigs = _bytes_column(sigs)
         t_keys = time.perf_counter()
         keys = VerifiedSigCache.keys(pubs, msgs, sigs)
         t_probe = time.perf_counter()
@@ -432,7 +450,14 @@ class VerifyService:
         future.  Blocks only on verification work the host path could
         also perform — never on device warmup (the worker routes around
         a cold or wedged device)."""
-        group = self._submit(items)
+        return self._wait(self._submit_items(items))
+
+    def verify_columns(self, pubs, msgs, sigs) -> list[bool]:
+        """`verify_many` over three columns (see `submit_columns`)."""
+        return self._wait(self._submit(pubs, msgs, sigs))
+
+    @staticmethod
+    def _wait(group: _Group) -> list[bool]:
         # the caller sleeps until the flush that lands the group's last
         # row has resolved it: what of this span lies past that flush's
         # `verify.resolve` is the caller's wake-up alone
@@ -803,38 +828,32 @@ class VerifyService:
                 self._land([seg], oks)
 
 
-def _bytes_column(col, convert=bytes) -> list[bytes]:
-    # a type test per row, not a call: the surfaces hand in bytes already
-    return [x if type(x) is bytes else convert(x) for x in col]
-
-
 def _verify_rows(batch: _Batch, ed_batch_fn) -> list[bool]:
     return list(map(bool, _split_verify(batch.pubs, batch.msgs, batch.sigs,
                                         ed_batch_fn)))
 
 
-class ServiceBatchVerifier:
+class ServiceBatchVerifier(_BaseBatch):
     """BatchVerifier-protocol adapter over the shared service: existing
     call sites keep their add/count/verify shape, but the actual crypto
     is submitted to the cross-caller queue — concurrent verifiers'
     batches coalesce into one device dispatch, and duplicates resolve
-    from the verified-signature cache."""
+    from the verified-signature cache.  The rows are kept as three
+    columns and handed to the service's column entry as they are."""
 
     def __init__(self, service: "VerifyService | None" = None):
+        super().__init__()
         self._svc = service or get_service()
-        self._items: list[tuple[bytes, bytes, bytes]] = []
 
-    def add(self, pub_key, msg: bytes, sig: bytes) -> None:
-        self._items.append((_pub_bytes(pub_key), bytes(msg), bytes(sig)))
-
-    def count(self) -> int:
-        return len(self._items)
+    def add_many(self, pubs, msgs, sigs) -> None:
+        # no type test here: the service's column entry makes it
+        self._extend(pubs, msgs, sigs)
 
     def verify(self) -> tuple[bool, list[bool]]:
-        items, self._items = self._items, []
-        if not items:
+        pubs, msgs, sigs = self._take()
+        if not pubs:
             return False, []
-        oks = self._svc.verify_many(items)
+        oks = self._svc.verify_columns(pubs, msgs, sigs)
         return all(oks), oks
 
 

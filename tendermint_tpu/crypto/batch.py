@@ -24,6 +24,8 @@ import os
 import traceback
 from typing import Protocol, runtime_checkable
 
+from tendermint_tpu.utils.metrics import Counter
+
 from . import ed25519 as _ed
 
 
@@ -34,9 +36,28 @@ def _pub_bytes(pub) -> bytes:
     return pub.bytes_() if hasattr(pub, "bytes_") else bytes(pub)
 
 
+def _bytes_column(col, convert=bytes) -> list[bytes]:
+    # a type test per row, not a call: the surfaces hand in bytes already
+    return [x if type(x) is bytes else convert(x) for x in col]
+
+
+# How a verifier's rows came in: `bulk` by add_many (a column a job —
+# the commit surfaces), `row` by add (vote slices, evidence).  Bumped
+# once a verify(), never per row; node/metrics.py registers it.
+ROWS_ADDED_TOTAL = Counter(
+    "rows_added_total",
+    "Rows handed to a batch verifier, by how they came in",
+    namespace="tendermint", subsystem="verify", label_names=("how",),
+)
+
+
 @runtime_checkable
 class BatchVerifier(Protocol):
     def add(self, pub_key, msg: bytes, sig: bytes) -> None: ...
+
+    def add_many(self, pubs, msgs, sigs) -> None:
+        """Three columns of equal length, appended in row order."""
+        ...
 
     def count(self) -> int: ...
 
@@ -46,22 +67,43 @@ class BatchVerifier(Protocol):
 
 
 class _BaseBatch:
+    """Three columns — public keys, messages, signatures — in the order
+    the rows came in, by `add` (one row) or `add_many` (a column each)."""
+
     def __init__(self) -> None:
         self._pubs: list[bytes] = []
         self._msgs: list[bytes] = []
         self._sigs: list[bytes] = []
+        self._bulk = 0  # of the rows, those that came by add_many
 
     def add(self, pub_key, msg: bytes, sig: bytes) -> None:
         self._pubs.append(_pub_bytes(pub_key))
         self._msgs.append(bytes(msg))
         self._sigs.append(bytes(sig))
 
+    def add_many(self, pubs, msgs, sigs) -> None:
+        self._extend(_bytes_column(pubs, _pub_bytes), _bytes_column(msgs),
+                     _bytes_column(sigs))
+
+    def _extend(self, pubs, msgs, sigs) -> None:
+        if not len(pubs) == len(msgs) == len(sigs):
+            raise ValueError("add_many: columns of unequal length")
+        self._pubs.extend(pubs)
+        self._msgs.extend(msgs)
+        self._sigs.extend(sigs)
+        self._bulk += len(pubs)
+
     def count(self) -> int:
         return len(self._pubs)
 
     def _take(self):
         batch = (self._pubs, self._msgs, self._sigs)
-        self._pubs, self._msgs, self._sigs = [], [], []
+        bulk, single = self._bulk, len(self._pubs) - self._bulk
+        if bulk:
+            ROWS_ADDED_TOTAL.inc(bulk, how="bulk")
+        if single:
+            ROWS_ADDED_TOTAL.inc(single, how="row")
+        self._pubs, self._msgs, self._sigs, self._bulk = [], [], [], 0
         return batch
 
 

@@ -509,6 +509,7 @@ class NodeMetrics:
             WINDOW_COUNTERS as _bsync_window_counters,
         )
         from tendermint_tpu.consensus.state import STEP_DURATION_SECONDS
+        from tendermint_tpu.crypto.batch import ROWS_ADDED_TOTAL as _rows_added
         from tendermint_tpu.light.client import LIGHT_COUNTERS as _light_counters
         from tendermint_tpu.rpc.server import (
             REQUEST_DURATION_SECONDS as _rpc_hist,
@@ -521,6 +522,7 @@ class NodeMetrics:
         self.rpc_request_duration = reg.register(_rpc_hist)
         for hist in _av.PIPELINE_HISTOGRAMS:
             reg.register(hist)
+        reg.register(_rows_added)
 
         # -- transaction lifecycle (utils/txlife.py) --------------------
         # the user-facing latency signal: time-to-finality (rpc ingress →

@@ -20,6 +20,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass, field
 from fractions import Fraction
+from itertools import compress
 
 from tendermint_tpu.crypto import merkle
 from tendermint_tpu.crypto.keys import PubKey
@@ -30,7 +31,7 @@ from tendermint_tpu.wire.proto import (
     encode_varint_signed,
 )
 
-from .basic import BlockID
+from .basic import BlockID, BlockIDFlag
 
 MAX_TOTAL_VOTING_POWER = (1 << 63) - 1 >> 3  # reference: MaxTotalVotingPower int64/8
 PRIORITY_WINDOW_SIZE_FACTOR = 2
@@ -191,6 +192,10 @@ class ValidatorSet:
         # the memoized wire form IS priority-sensitive, so it is also
         # invalidated at every mutator (rotation, updates, get_proposer)
         self._enc: bytes | None = None
+        # the verify columns cover (pub_key, power) like the hash, and are
+        # dropped here and nowhere else: the proposer-priority methods
+        # change neither a key nor a power, so they keep them
+        self._cols: tuple[list[bytes], list[int]] | None = None
 
     # -- bookkeeping ---------------------------------------------------
     def _update_total_voting_power(self) -> None:
@@ -225,6 +230,7 @@ class ValidatorSet:
         #                       each thousand-slot set once per rotation
         #                       instead of once per save that sees it
         #                       (validators/next/last share lineage).
+        c._cols = self._cols  # same keys and powers; never edited in place
         c.proposer = self.proposer.copy() if self.proposer else None
         return c
 
@@ -316,6 +322,19 @@ class ValidatorSet:
             )
         return self._hash
 
+    def verify_columns(self) -> tuple[list[bytes], list[int]]:
+        """(public-key bytes, voting powers) by validator index: what a
+        commit check reads of the set, built by one pass each and
+        memoised like hash() — a 10,000-validator set is verified
+        against every block and changes at validator-update heights.
+        Callers must not edit the lists."""
+        cols = self._cols
+        if cols is None:
+            vals = self.validators
+            cols = self._cols = ([v.pub_key.bytes_() for v in vals],
+                                 [v.voting_power for v in vals])
+        return cols
+
     # -- validator-set updates (ABCI EndBlock) -------------------------
     def update_with_change_set(self, changes: list[Validator]) -> None:
         """Apply updates/removals (voting_power 0 = remove), then recompute
@@ -396,17 +415,20 @@ class ValidatorSet:
         from tendermint_tpu.crypto.async_verify import new_service_batch_verifier
 
         bv = new_service_batch_verifier()
-        entries = []
+        pubs, powers = self.verify_columns()
+        by_address = self._by_address
+        for_block = BlockIDFlag.COMMIT
+        sel, matched = [], []  # the commit's rows selected, their validators
         seen: dict[int, int] = {}
         running = 0
         double_vote = None
         # the same commit.* spans as batch_verify_commits, one per phase
         with _trace.span("commit.select", mode="trusting") as sp:
             for idx, cs in enumerate(commit.signatures):
-                if not cs.for_block():
+                if cs.block_id_flag != for_block:
                     continue
-                val_idx, val = self.get_by_address(cs.validator_address)
-                if val is None:
+                val_idx = by_address.get(cs.validator_address)
+                if val_idx is None:
                     continue
                 if val_idx in seen:
                     # the reference verifies row by row, so a wrong
@@ -415,35 +437,38 @@ class ValidatorSet:
                     double_vote = (val_idx, seen[val_idx], idx)
                     break
                 seen[val_idx] = idx
-                entries.append((idx, val, val.voting_power))
-                running += val.voting_power
+                sel.append(idx)
+                matched.append(val_idx)
+                running += powers[val_idx]
                 if running > needed:
                     break
-            sp.set(n_sigs=len(commit.signatures), selected=len(entries))
+            rows = [commit.signatures[i] for i in sel]
+            sp.set(n_sigs=len(commit.signatures), selected=len(rows))
         # assemble all selected sign-bytes in one (native) call, same as
         # batch_verify_commits
-        with _trace.span("commit.sign_bytes", n=len(entries)):
-            msgs = commit.vote_sign_bytes_batch(chain_id, [e[0] for e in entries])
-        with _trace.span("commit.add", n=len(entries)):
-            for (idx, val, _power), msg in zip(entries, msgs):
-                bv.add(val.pub_key, msg, commit.signatures[idx].signature)
-        with _trace.span("commit.verify", n=len(entries)):
+        with _trace.span("commit.sign_bytes", n=len(rows)) as sp:
+            msgs, path = commit.sign_bytes_of(chain_id, rows)
+            sp.set(path=path)
+        with _trace.span("commit.add", n=len(rows), bulk=1):
+            bv.add_many([pubs[i] for i in matched], msgs,
+                        [cs.signature for cs in rows])
+        with _trace.span("commit.verify", n=len(rows)):
             _, oks = bv.verify()
-        with _trace.span("commit.tally", n=len(entries)):
-            tallied = 0
-            for ok, (idx, _val, power) in zip(oks, entries):
-                if not ok:
-                    raise ValueError(f"wrong signature (#{idx})")
-                tallied += power
-                if tallied > needed:
-                    return len(entries)
+        with _trace.span("commit.tally", n=len(rows)):
+            if not all(oks):
+                # the walk stopped at the row that crossed `needed`, so a
+                # row-by-row tally would meet the first bad row before it
+                # could accept
+                raise ValueError(f"wrong signature (#{sel[_first_false(oks)]})")
+            if running > needed:
+                return len(rows)
             if double_vote is not None:
                 raise ValueError(
                     "double vote from validator %d (%d and %d)" % double_vote
                 )
             raise ErrNotEnoughVotingPowerSigned(
-                f"insufficient voting power: got {tallied}, needed >{needed}",
-                tallied, needed, len(entries),
+                f"insufficient voting power: got {running}, needed >{needed}",
+                running, needed, len(rows),
             )
 
     def _check_commit_basics(self, chain_id: str, block_id: BlockID, height: int, commit) -> None:
@@ -547,61 +572,90 @@ def batch_verify_commits(jobs: list[CommitVerifyJob]) -> None:
     from tendermint_tpu.crypto.async_verify import new_service_batch_verifier
 
     bv = new_service_batch_verifier()
-    plans = []  # (job, entries=[(sig_batch_idx, val_idx, power)], needed)
+    plans = []  # (job, start in the batch, row count, sel or None = every row, power, needed)
     n = 0
     # spans (utils/trace): one per phase per job, never inside a per-row
     # loop — where a call's host time goes around the service's own
-    # verify.* spans (docs/observability.md)
+    # verify.* spans (docs/observability.md).  Between a commit's
+    # signatures and the service's queue the rows travel as three columns
+    # built by one bulk pass each: nothing is called per row.
     for job in jobs:
         vs, commit = job.val_set, job.commit
         with _trace.span("commit.select", mode=job.mode) as sp:
             vs._check_commit_basics(job.chain_id, job.block_id, job.height, commit)
             needed = vs.total_voting_power() * 2 // 3
-            # select indices first, then assemble all sign-bytes in one
-            # native call (the per-row Python path is ~4 µs — 40 ms on a
-            # 10k commit, 20x the BASELINE end-to-end budget)
-            sel = []
-            running = 0
-            for idx, cs in enumerate(commit.signatures):
-                if job.mode == "light":
-                    if not cs.for_block():
-                        continue
-                elif cs.absent():
-                    continue
-                sel.append(idx)
-                if job.mode == "light":
-                    running += vs.validators[idx].voting_power
-                    if running > needed:
-                        break
-            sp.set(n_sigs=len(commit.signatures), selected=len(sel))
-        with _trace.span("commit.sign_bytes", n=len(sel)):
-            msgs = commit.vote_sign_bytes_batch(job.chain_id, sel)
-        entries = []
-        with _trace.span("commit.add", n=len(sel)):
-            for idx, msg in zip(sel, msgs):
-                val = vs.validators[idx]
-                bv.add(val.pub_key, msg, commit.signatures[idx].signature)
-                entries.append((n, idx, val.voting_power))
-                n += 1
-        plans.append((job, entries, needed))
+            pubs, powers = vs.verify_columns()
+            sigs = commit.signatures
+            sel, power = _select_rows(sigs, powers, needed, job.mode == "light")
+            rows = sigs if sel is None else [sigs[i] for i in sel]
+            sp.set(n_sigs=len(sigs), selected=len(rows))
+        # all sign-bytes of a job in one native call (the per-row Python
+        # path is ~4 µs — 40 ms on a 10k commit, 20x the BASELINE
+        # end-to-end budget)
+        with _trace.span("commit.sign_bytes", n=len(rows)) as sp:
+            msgs, path = commit.sign_bytes_of(job.chain_id, rows)
+            sp.set(path=path)
+        with _trace.span("commit.add", n=len(rows), bulk=1):
+            bv.add_many(pubs if sel is None else [pubs[i] for i in sel],
+                        msgs, [cs.signature for cs in rows])
+        plans.append((job, n, len(rows), sel, power, needed))
+        n += len(rows)
     with _trace.span("commit.verify", n=n):
         _, oks = bv.verify() if n else (True, [])
-    for job, entries, needed in plans:
-        with _trace.span("commit.tally", n=len(entries)):
-            tallied = 0
-            for sig_i, idx, power in entries:
-                if not oks[sig_i]:
-                    raise ValueError(
-                        f"wrong signature (#{idx}) in commit for height {job.height}"
-                    )
-                # light entries stop at the +2/3 cutoff by construction,
-                # so every collected signature counts; full mode tallies
-                # ForBlock
-                if job.mode == "light" or job.commit.signatures[idx].for_block():
-                    tallied += power
-            if tallied <= needed:
+    for job, start, k, sel, power, needed in plans:
+        with _trace.span("commit.tally", n=k):
+            mine = oks[start:start + k]
+            if not all(mine):
+                bad = _first_false(mine)
+                raise ValueError(
+                    f"wrong signature (#{bad if sel is None else sel[bad]}) "
+                    f"in commit for height {job.height}"
+                )
+            if power <= needed:
                 raise ErrNotEnoughVotingPowerSigned(
                     f"insufficient voting power for height {job.height}: "
-                    f"got {tallied}, needed >{needed}",
-                    tallied, needed, len(entries),
+                    f"got {power}, needed >{needed}",
+                    power, needed, k,
                 )
+
+
+def _first_false(oks) -> int:
+    """The first row, in order, that a verify path refused."""
+    return next(i for i, ok in enumerate(oks) if not ok)
+
+
+def _select_rows(sigs, powers, needed: int, light: bool):
+    """Which rows of a commit a check consults, and the ForBlock power
+    they carry if every one of them verifies: `(sel, power)`, `sel` the
+    row indices in order or None for every row.  The flags are read by
+    one comprehension and counted in bulk; nothing is called per row.
+
+    full:  every non-absent row; power = the ForBlock rows'.
+    light: the ForBlock rows in order until the running power EXCEEDS
+           `needed` — rows past that cut are not selected, so a bad
+           signature there is never seen (reference :720-766); power =
+           the running power at the cut (all of them when it is never
+           reached)."""
+    for_block = BlockIDFlag.COMMIT
+    flags = [cs.block_id_flag for cs in sigs]
+    n_for_block = flags.count(for_block)
+    if light:
+        if n_for_block == len(flags):
+            sel, mine = None, powers
+        else:
+            sel = [i for i, f in enumerate(flags) if f == for_block]
+            mine = [powers[i] for i in sel]
+        running = 0
+        for k, p in enumerate(mine, 1):
+            running += p
+            if running > needed:
+                if k < len(flags):
+                    sel = list(range(k)) if sel is None else sel[:k]
+                break
+        return sel, running
+    if n_for_block == len(flags):
+        return None, sum(powers)
+    absent = BlockIDFlag.ABSENT
+    sel = ([i for i, f in enumerate(flags) if f != absent]
+           if absent in flags else None)
+    return sel, sum(compress(powers, [f == for_block for f in flags]))

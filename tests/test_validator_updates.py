@@ -8,6 +8,7 @@ test/e2e validator_update schedules + persistent_kvstore ValSetChange.
 
 import asyncio
 from collections import Counter
+from fractions import Fraction
 
 import pytest
 
@@ -74,6 +75,112 @@ def test_priorities_stay_centered_and_bounded():
         # centering: sum stays near zero; bound: |pri| <= 2*total
         assert abs(sum(pris)) <= total, pris
         assert all(abs(p) <= 2 * total for p in pris), pris
+
+
+# ---------------------------------------------------------------------------
+# the memoised verify columns (public keys, powers) follow every change
+# ---------------------------------------------------------------------------
+
+COL_CHAIN = "columns-update-chain"
+
+
+def _signed_by(vals, key_of, height=9):
+    """A commit for `height` in which every validator of `vals` signs
+    with the key `key_of` gives for its address."""
+    from tendermint_tpu.types.basic import BlockID, PartSetHeader
+    from helpers import sign_commit
+
+    block_id = BlockID(hash=b"\x51" * 32,
+                       part_set_header=PartSetHeader(total=1, hash=b"\x52" * 32))
+    by_addr = {v.address: key_of[v.address] for v in vals.validators}
+    return block_id, sign_commit(COL_CHAIN, height, 0, block_id, vals, by_addr,
+                                 1_700_000_000 * 10**9 + height)
+
+
+def _assert_columns_are_the_sets(vals):
+    pubs, powers = vals.verify_columns()
+    assert pubs == [v.pub_key.bytes_() for v in vals.validators]
+    assert powers == [v.voting_power for v in vals.validators]
+    assert sum(powers) == vals.total_voting_power()
+
+
+CHANGES = ("key_replaced", "power_changed", "validator_removed", "validator_added",
+           "copy_then_change", "priorities_moved")
+
+
+@pytest.mark.parametrize("change", CHANGES)
+def test_verify_columns_follow_the_set(change):
+    """A commit signed by the NEW set verifies; one signed with a replaced
+    key is refused with the row named.  Each check reads the columns
+    first, so a memo that outlived a change would answer for the old set."""
+    vals, keys = _mkset([10] * 70)           # 70 rows: the bulk sign-bytes path
+    key_of = {k.pub_key().address(): k for k in keys}
+    old_key_of = dict(key_of)
+    block_id, commit = _signed_by(vals, key_of)
+    vals.verify_commit(COL_CHAIN, block_id, 9, commit)
+    vals.verify_commit_light(COL_CHAIN, block_id, 9, commit)
+    _assert_columns_are_the_sets(vals)
+    victim = vals.validators[3]
+    target = vals.copy() if change == "copy_then_change" else vals
+    newcomer = priv_key_from_seed(b"\xee" * 32)
+
+    if change in ("key_replaced", "copy_then_change"):
+        # the same address, another key (what an ABCI update of a key is)
+        target.update_with_change_set([Validator(
+            pub_key=newcomer.pub_key(), voting_power=10, address=victim.address)])
+        key_of[victim.address] = newcomer
+    elif change == "power_changed":
+        target.update_with_change_set([Validator(
+            pub_key=victim.pub_key, voting_power=500, address=victim.address)])
+    elif change == "validator_removed":
+        target.update_with_change_set([Validator(
+            pub_key=victim.pub_key, voting_power=0, address=victim.address)])
+    elif change == "validator_added":
+        target.update_with_change_set([Validator(pub_key=newcomer.pub_key(), voting_power=7)])
+        key_of[newcomer.pub_key().address()] = newcomer
+    else:
+        before = vals.verify_columns()
+        target.increment_proposer_priority(3)
+        target.get_proposer()
+        rotated = target.copy_increment_proposer_priority(2)
+        # neither a key nor a power moved: the memo is kept, and shared
+        assert target.verify_columns() is before and rotated.verify_columns() is before
+        _assert_columns_are_the_sets(rotated)
+
+    _assert_columns_are_the_sets(target)
+    assert len(target.validators) == {"validator_removed": 69, "validator_added": 71}.get(change, 70)
+    new_block_id, new_commit = _signed_by(target, key_of)
+    target.verify_commit(COL_CHAIN, new_block_id, 9, new_commit)
+    target.verify_commit_light(COL_CHAIN, new_block_id, 9, new_commit)
+    assert target.verify_commit_light_trusting(COL_CHAIN, new_commit, Fraction(1, 3)) >= 1
+
+    if change == "copy_then_change":
+        # the original still verifies its own commit and refuses the copy's
+        _assert_columns_are_the_sets(vals)
+        vals.verify_commit(COL_CHAIN, block_id, 9, commit)
+    if change in ("key_replaced", "copy_then_change"):
+        # signed by the OLD key at the replaced row: refused, the row named
+        row = [v.address for v in target.validators].index(victim.address)
+        _, stale = _signed_by(target, {**key_of, victim.address: old_key_of[victim.address]})
+        with pytest.raises(ValueError, match=rf"wrong signature \(#{row}\) in commit for height 9"):
+            target.verify_commit(COL_CHAIN, new_block_id, 9, stale)
+        with pytest.raises(ValueError, match=rf"wrong signature \(#{row}\) in commit for height 9"):
+            target.verify_commit_light(COL_CHAIN, new_block_id, 9, stale)
+        with pytest.raises(ValueError, match=rf"wrong signature \(#{row}\)$"):
+            target.verify_commit_light_trusting(COL_CHAIN, stale, Fraction(1, 3))
+    if change == "power_changed":
+        # the light cut moved with the power: 500 of 1,190 and 30 more rows
+        # of 10 carry more than two thirds, so row 31 is never consulted
+        # and row 30 is (stale powers of 10 a row would never reach the cut)
+        assert target.validators[0].address == victim.address
+        for row, refused in ((31, False), (30, True)):
+            sig = new_commit.signatures[row].signature
+            new_commit.signatures[row].signature = sig[:-1] + bytes([sig[-1] ^ 1])
+            if refused:
+                with pytest.raises(ValueError, match=r"wrong signature \(#30\)"):
+                    target.verify_commit_light(COL_CHAIN, new_block_id, 9, new_commit)
+            else:
+                target.verify_commit_light(COL_CHAIN, new_block_id, 9, new_commit)
 
 
 # ---------------------------------------------------------------------------

@@ -77,9 +77,12 @@ def test_one_call_emits_every_span_with_its_attrs_and_ties(mode, selected):
 
     n = selected
     assert by["commit.select"]["attrs"] == {"mode": mode, "n_sigs": 48, "selected": n}
-    for name in ("commit.sign_bytes", "commit.add", "commit.verify",
-                 "verify.wait", "commit.tally"):
+    for name in ("commit.verify", "verify.wait", "commit.tally"):
         assert by[name]["attrs"] == {"n": n}, name
+    # under 64 rows the sign-bytes are the Python row form; the job's
+    # three columns go in by one add_many
+    assert by["commit.sign_bytes"]["attrs"] == {"n": n, "path": "template"}
+    assert by["commit.add"]["attrs"] == {"n": n, "bulk": 1}
     assert by["verify.submit"]["attrs"] == {"n": n, "fresh": n, "hits": 0}
 
     # the caller's side: commit.* are roots on one thread, and what the
