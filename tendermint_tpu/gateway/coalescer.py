@@ -15,6 +15,10 @@ per-signature work happens.
     `types.validator.batch_verify_commits` (raises ValueError naming
     the first failing height) so it drops into the light verifier's
     `verify_fn` seam unchanged.
+  * A flush is ONE verify call (`types.validator.commit_job_outcomes`)
+    and every job's future resolves from its OWN outcome: a refused
+    header costs the clients that shared its flush nothing — no second
+    verification, no row submitted twice.
   * Jobs are keyed by (chain_id, height, mode, block hash, commit
     digest).  The FIRST submitter of a key owns it; every concurrent
     duplicate — a different client verifying the same height — waits on
@@ -25,7 +29,11 @@ per-signature work happens.
     distinct heights from many clients merge into ONE
     batch_verify_commits flush — the PR 1 cross-caller micro-batching
     trick one level up, so device flushes scale with DISTINCT heights,
-    not clients×blocks.
+    not clients×blocks.  A flush is cut by ROWS as blocksync's window is
+    (`blocksync.reactor.window_jobs`): the leading jobs that, each
+    counted at its commit's signature count, fit one flush of the verify
+    service (`MAX_COALESCE`), never fewer than one; the rest head the
+    next flush.
   * Graceful degradation: when `shed_fn()` reports a non-zero level
     (wired to the remediation controller's verify-queue-saturation
     shed level), submissions raise `GatewayBackpressureError` with a
@@ -37,6 +45,14 @@ Thread model: client threads call `verify_jobs`/`submit_jobs`; one
 daemon worker drains the queue and runs the flush (which itself blocks
 on the async-verify service).  All shared state lives under one
 condition variable.
+
+Spans (utils/trace, off = one branch a site; docs/observability.md):
+`gateway.submit` and `gateway.wait` on the client's thread,
+`gateway.linger`, `gateway.flush` (the parent of that flush's
+`commit.*` / `verify.submit` / `verify.wait`) and `gateway.resolve` on
+the worker; `flush` is this coalescer's sequence number and ties a
+client's wait to the flush that answered it.  Series beside the
+`tendermint_gateway_*` counters: `GATEWAY_SERIES` below.
 """
 
 from __future__ import annotations
@@ -48,11 +64,32 @@ import time
 from collections import deque
 from concurrent.futures import Future
 
+from tendermint_tpu.utils import trace as _trace
+from tendermint_tpu.utils.metrics import Counter, Histogram
+
 from .errors import GatewayBackpressureError
 
 DEFAULT_LINGER_MS = 2.0
-MAX_FLUSH_JOBS = 1024   # per-flush job cap; a flush this large already
-                        # saturates the verify service's top rung
+
+# Observed by the worker, once a flush (process-wide; registered by
+# node/metrics.py beside the tendermint_gateway_* callback series).
+FLUSH_JOBS = Histogram(
+    "flush_jobs", "Commit-verify jobs one coalesced gateway flush carried",
+    namespace="tendermint", subsystem="gateway",
+    buckets=(1, 2, 3, 4, 5, 6, 8, 12, 16, 24, 32, 64, 128))
+JOB_WAIT_SECONDS = Histogram(
+    "job_wait_seconds",
+    "Time a job waited in the gateway for its flush: submit to the start "
+    "of the verify call that carries it (queue and linger)",
+    namespace="tendermint", subsystem="gateway",
+    buckets=(0.00025, 0.0005, 0.001, 0.002, 0.003, 0.005, 0.01, 0.025,
+             0.05, 0.1, 0.25, 1.0))
+REFUSED_JOBS_TOTAL = Counter(
+    "refused_jobs_total",
+    "Jobs whose own verification failed (every other job of the flush "
+    "was answered by the same verify call)",
+    namespace="tendermint", subsystem="gateway")
+GATEWAY_SERIES = (FLUSH_JOBS, JOB_WAIT_SECONDS, REFUSED_JOBS_TOTAL)
 
 
 def _commit_digest(commit) -> bytes:
@@ -88,13 +125,37 @@ def job_key(job) -> tuple:
 
 
 class _Entry:
-    __slots__ = ("key", "job", "future", "t_submit")
+    __slots__ = ("key", "job", "future", "t_submit", "rows", "flush")
 
     def __init__(self, key, job, t_submit: float):
         self.key = key
         self.job = job
         self.future: Future = Future()
         self.t_submit = t_submit
+        # what the job counts for when a flush is cut: its commit's
+        # signature count, as blocksync's window counts a block
+        self.rows = len(job.commit.signatures)
+        self.flush = None   # the sequence number of the flush that took it
+
+
+def _raise_only_outcomes(verify, jobs) -> list:
+    """`commit_job_outcomes` from a verifier of the raise-only contract
+    (`batch_verify_commits`' own; what an injected `verify_fn` has): it
+    names the FIRST failure and says nothing of the rest, so a refused
+    batch is verified again job by job."""
+    try:
+        verify(jobs)
+        return [None] * len(jobs)
+    except BaseException:  # noqa: BLE001 — isolate per job below
+        pass
+    outcomes = []
+    for job in jobs:
+        try:
+            verify([job])
+            outcomes.append(None)
+        except BaseException as err:  # noqa: BLE001
+            outcomes.append(err)
+    return outcomes
 
 
 def _env_float(name: str, default: float) -> float:
@@ -106,8 +167,11 @@ def _env_float(name: str, default: float) -> float:
 
 class VerifyCoalescer:
     """The gateway's cross-client verify funnel; see the module
-    docstring.  `verify_fn` defaults to types.validator's
-    batch_verify_commits (injectable for tests)."""
+    docstring.  A flush goes through types.validator's
+    commit_job_outcomes: one verify call, an outcome a job.  `verify_fn`
+    (injectable for tests) is a verifier of batch_verify_commits'
+    raise-only contract; a flush it refuses is verified again job by job
+    (`_raise_only_outcomes`)."""
 
     def __init__(self, *, linger_ms: float | None = None,
                  verify_fn=None, shed_fn=None, remediate=None,
@@ -122,6 +186,8 @@ class VerifyCoalescer:
         self._cv = threading.Condition()
         self._pending: dict[tuple, _Entry] = {}   # queued OR in-flight
         self._queue: deque[_Entry] = deque()
+        self._queued_rows = 0    # sum of the queued entries' `rows`
+        self._flush_seq = 0
         self._worker: threading.Thread | None = None
         self._closed = False
         self.stats = {
@@ -146,6 +212,9 @@ class VerifyCoalescer:
         """Queue jobs for coalesced verification; never blocks.  Each
         future resolves to True or raises the job's verification error.
         Raises GatewayBackpressureError immediately under shed."""
+        return [e.future for e in self._submit(jobs)]
+
+    def _submit(self, jobs) -> list[_Entry]:
         level = self.shed_level()
         if level > 0:
             rm = self._remediate
@@ -157,26 +226,31 @@ class VerifyCoalescer:
                           f"level {level}")
             raise GatewayBackpressureError(level, self.retry_after_ms)
         t_sub = time.perf_counter()
-        futures: list[Future] = []
-        with self._cv:
-            if self._closed:
-                raise RuntimeError("gateway coalescer is closed")
-            self.stats["verify_jobs"] += len(jobs)
-            for job in jobs:
-                key = job_key(job)
-                entry = self._pending.get(key)
-                if entry is not None:
-                    # single-flight: another client already owns this
-                    # exact job (queued or mid-flush) — share its verdict
-                    self.stats["verify_coalesced"] += 1
-                else:
-                    entry = _Entry(key, job, t_sub)
-                    self._pending[key] = entry
-                    self._queue.append(entry)
-                futures.append(entry.future)
-            self._ensure_worker_locked()
-            self._cv.notify()
-        return futures
+        entries: list[_Entry] = []
+        with _trace.span("gateway.submit", jobs=len(jobs)) as sp:
+            keys = [job_key(job) for job in jobs]
+            joined = 0
+            with self._cv:
+                if self._closed:
+                    raise RuntimeError("gateway coalescer is closed")
+                self.stats["verify_jobs"] += len(jobs)
+                for key, job in zip(keys, jobs):
+                    entry = self._pending.get(key)
+                    if entry is not None:
+                        # single-flight: another client already owns this
+                        # exact job (queued or mid-flush) — share its verdict
+                        joined += 1
+                    else:
+                        entry = _Entry(key, job, t_sub)
+                        self._pending[key] = entry
+                        self._queue.append(entry)
+                        self._queued_rows += entry.rows
+                    entries.append(entry)
+                self.stats["verify_coalesced"] += joined
+                self._ensure_worker_locked()
+                self._cv.notify()
+            sp.set(joined=joined)
+        return entries
 
     def verify_jobs(self, jobs) -> None:
         """batch_verify_commits-compatible surface: submit, wait, raise
@@ -184,8 +258,14 @@ class VerifyCoalescer:
         `commit_verifier` seam points at."""
         if not jobs:
             return
-        for fut in self.submit_jobs(list(jobs)):
-            fut.result()   # re-raises the flush's per-job error
+        entries = self._submit(list(jobs))
+        with _trace.span("gateway.wait", jobs=len(entries)) as sp:
+            e = entries[0]
+            try:
+                for e in entries:
+                    e.future.result()   # re-raises the job's own error
+            finally:
+                sp.set(flush=e.flush)   # of the job last waited for
 
     def close(self) -> None:
         with self._cv:
@@ -201,64 +281,83 @@ class VerifyCoalescer:
             self._worker.start()
 
     def _run(self) -> None:
+        # the most rows one flush of the verify service holds (its top
+        # rung); read when the worker starts, as the service reads it
+        from tendermint_tpu.crypto.async_verify import MAX_COALESCE as max_rows
+
         while True:
             with self._cv:
                 while not self._queue and not self._closed:
                     self._cv.wait()
                 if not self._queue:
                     return   # closed and drained
+                t_first = time.perf_counter()
                 if self.linger_s > 0:
                     # linger so concurrent clients' distinct heights
                     # merge into one flush
                     deadline = time.monotonic() + self.linger_s
-                    while (len(self._queue) < MAX_FLUSH_JOBS
-                           and not self._closed):
+                    while self._queued_rows < max_rows and not self._closed:
                         remaining = deadline - time.monotonic()
                         if remaining <= 0:
                             break
                         self._cv.wait(remaining)
-                batch = [self._queue.popleft()
-                         for _ in range(min(len(self._queue),
-                                            MAX_FLUSH_JOBS))]
+                # the cut, as blocksync.reactor.window_jobs makes it: the
+                # leading jobs whose counted rows fit one flush, never
+                # fewer than one; the rest head the next flush
+                batch = [self._queue.popleft()]
+                rows = batch[0].rows
+                while self._queue and rows + self._queue[0].rows <= max_rows:
+                    rows += self._queue[0].rows
+                    batch.append(self._queue.popleft())
+                self._queued_rows -= rows
+                self._flush_seq += 1
+                seq = self._flush_seq
+                for e in batch:
+                    e.flush = seq
                 self.stats["verify_flushes"] += 1
                 self.stats["verify_flushed_jobs"] += len(batch)
-            self._flush(batch)
+            _trace.record("gateway.linger", t_first,
+                          time.perf_counter() - t_first, flush=seq,
+                          jobs=len(batch))
+            self._flush(batch, seq, rows)
 
-    def _resolve_verify_fn(self):
+    def _outcomes(self, jobs) -> list:
         if self._verify_fn is not None:
-            return self._verify_fn
-        from tendermint_tpu.types.validator import batch_verify_commits
+            return _raise_only_outcomes(self._verify_fn, jobs)
+        from tendermint_tpu.types.validator import commit_job_outcomes
 
-        self._verify_fn = batch_verify_commits  # tmsan: shared=idempotent lazy bind; racing writers store the same callable
-        return self._verify_fn
+        return commit_job_outcomes(jobs)
 
-    def _flush(self, batch: list[_Entry]) -> None:
-        """One coalesced batch_verify_commits call.  On failure, fall
-        back to per-job verification so one bad height poisons only its
-        own waiters (batch_verify_commits raises on the FIRST failure
-        without telling which other jobs passed)."""
-        verify = self._resolve_verify_fn()
+    def _flush(self, batch: list[_Entry], seq: int, rows: int) -> None:
+        """One coalesced verify call; each entry's future is resolved
+        from its own job's outcome, so one bad height fails only its own
+        waiters.  A verifier that itself breaks fails them all."""
+        t_flush = time.perf_counter()
+        waits = [t_flush - e.t_submit for e in batch]
+        FLUSH_JOBS.observe(len(batch))
+        for w in waits:
+            JOB_WAIT_SECONDS.observe(w)
         try:
-            verify([e.job for e in batch])
-        except BaseException:  # noqa: BLE001 — isolate per job below
-            self._flush_individually(batch, verify)
-            return
-        finally:
-            # entries leave the dedup window only once their verdict is
-            # decided; late duplicates fall through to the sig LRU
-            with self._cv:
-                for e in batch:
-                    self._pending.pop(e.key, None)
-        for e in batch:
-            e.future.set_result(True)
-
-    def _flush_individually(self, batch: list[_Entry], verify) -> None:
-        for e in batch:
-            try:
-                verify([e.job])
-                e.future.set_result(True)
-            except BaseException as err:  # noqa: BLE001
-                e.future.set_exception(err)
+            with _trace.span("gateway.flush", flush=seq, jobs=len(batch),
+                             rows=rows, wait_sum_ns=int(sum(waits) * 1e9)):
+                outcomes = self._outcomes([e.job for e in batch])
+        except BaseException as err:  # noqa: BLE001 — the verifier broke
+            outcomes = [err] * len(batch)
+        # entries leave the dedup window only once their verdict is
+        # decided; late duplicates fall through to the sig LRU
+        with self._cv:
+            for e in batch:
+                self._pending.pop(e.key, None)
+        refused = sum(err is not None for err in outcomes)
+        with _trace.span("gateway.resolve", flush=seq, jobs=len(batch),
+                         refused=refused):
+            for e, err in zip(batch, outcomes):
+                if err is None:
+                    e.future.set_result(True)
+                else:
+                    e.future.set_exception(err)
+        if refused:
+            REFUSED_JOBS_TOTAL.inc(refused)
 
     # -- views -----------------------------------------------------------
 

@@ -510,6 +510,7 @@ class NodeMetrics:
         )
         from tendermint_tpu.consensus.state import STEP_DURATION_SECONDS
         from tendermint_tpu.crypto.batch import ROWS_ADDED_TOTAL as _rows_added
+        from tendermint_tpu.gateway.coalescer import GATEWAY_SERIES as _gw_series
         from tendermint_tpu.light.client import LIGHT_COUNTERS as _light_counters
         from tendermint_tpu.rpc.server import (
             REQUEST_DURATION_SECONDS as _rpc_hist,
@@ -517,8 +518,8 @@ class NodeMetrics:
 
         self.step_duration = reg.register(STEP_DURATION_SECONDS)
         self.blocksync_request_duration = reg.register(_bsync_hist)
-        for counter in _bsync_window_counters + _light_counters:
-            reg.register(counter)
+        for series in _bsync_window_counters + _light_counters + _gw_series:
+            reg.register(series)
         self.rpc_request_duration = reg.register(_rpc_hist)
         for hist in _av.PIPELINE_HISTOGRAMS:
             reg.register(hist)

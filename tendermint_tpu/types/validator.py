@@ -551,8 +551,13 @@ class CommitVerifyJob:
     mode: str = "full"  # 'full' | 'light'
 
 
-def batch_verify_commits(jobs: list[CommitVerifyJob]) -> None:
-    """Verify many commits as ONE batched device call.
+def commit_job_outcomes(jobs: list[CommitVerifyJob]) -> list[Exception | None]:
+    """Verify many commits as ONE batched device call and answer for
+    EVERY job: `None` where the job's commit verifies, else the exception
+    the job would have raised alone — `ValueError("wrong signature (#row)
+    in commit for height h")`, `ErrNotEnoughVotingPowerSigned`, or what
+    `_check_commit_basics` raises (such a job adds no row to the batch and
+    costs the others nothing).  In job order.
 
     The TPU-native redesign of the reference's per-block sequential
     verify loops (blockchain/v0/reactor.go:517 fast sync,
@@ -560,29 +565,36 @@ def batch_verify_commits(jobs: list[CommitVerifyJob]) -> None:
     — thousands of signatures — is shipped to the device as a single
     XLA program invocation instead of one host call per commit.
     Accept/reject semantics per commit are identical to calling
-    verify_commit / verify_commit_light individually; raises ValueError
-    naming the first failing job's height.
+    verify_commit / verify_commit_light individually.
 
     Submits through the async verification service (crypto.async_verify)
     by default, so a blocksync window, a light-client range, and a
     consensus VerifyCommit arriving concurrently coalesce into one
     device dispatch, and replayed commits resolve from the
     verified-signature cache.
+
+    `batch_verify_commits` raises the first of these; the gateway's
+    coalescer (gateway/coalescer.py) hands each client its own.
     """
     from tendermint_tpu.crypto.async_verify import new_service_batch_verifier
 
     bv = new_service_batch_verifier()
-    plans = []  # (job, start in the batch, row count, sel or None = every row, power, needed)
+    outcomes: list[Exception | None] = [None] * len(jobs)
+    plans = []  # (job's index, start in the batch, row count, sel or None = every row, power, needed)
     n = 0
     # spans (utils/trace): one per phase per job, never inside a per-row
     # loop — where a call's host time goes around the service's own
     # verify.* spans (docs/observability.md).  Between a commit's
     # signatures and the service's queue the rows travel as three columns
     # built by one bulk pass each: nothing is called per row.
-    for job in jobs:
+    for j, job in enumerate(jobs):
         vs, commit = job.val_set, job.commit
         with _trace.span("commit.select", mode=job.mode) as sp:
-            vs._check_commit_basics(job.chain_id, job.block_id, job.height, commit)
+            try:
+                vs._check_commit_basics(job.chain_id, job.block_id, job.height, commit)
+            except ValueError as err:
+                outcomes[j] = err
+                continue
             needed = vs.total_voting_power() * 2 // 3
             pubs, powers = vs.verify_columns()
             sigs = commit.signatures
@@ -598,25 +610,36 @@ def batch_verify_commits(jobs: list[CommitVerifyJob]) -> None:
         with _trace.span("commit.add", n=len(rows), bulk=1):
             bv.add_many(pubs if sel is None else [pubs[i] for i in sel],
                         msgs, [cs.signature for cs in rows])
-        plans.append((job, n, len(rows), sel, power, needed))
+        plans.append((j, n, len(rows), sel, power, needed))
         n += len(rows)
     with _trace.span("commit.verify", n=n):
         _, oks = bv.verify() if n else (True, [])
-    for job, start, k, sel, power, needed in plans:
+    for j, start, k, sel, power, needed in plans:
         with _trace.span("commit.tally", n=k):
             mine = oks[start:start + k]
             if not all(mine):
                 bad = _first_false(mine)
-                raise ValueError(
+                outcomes[j] = ValueError(
                     f"wrong signature (#{bad if sel is None else sel[bad]}) "
-                    f"in commit for height {job.height}"
+                    f"in commit for height {jobs[j].height}"
                 )
-            if power <= needed:
-                raise ErrNotEnoughVotingPowerSigned(
-                    f"insufficient voting power for height {job.height}: "
+            elif power <= needed:
+                outcomes[j] = ErrNotEnoughVotingPowerSigned(
+                    f"insufficient voting power for height {jobs[j].height}: "
                     f"got {power}, needed >{needed}",
                     power, needed, k,
                 )
+    return outcomes
+
+
+def batch_verify_commits(jobs: list[CommitVerifyJob]) -> None:
+    """`commit_job_outcomes` with the raise-only contract blocksync, the
+    light verifier and state validation use: one batched device call,
+    then the first failing job's exception (a ValueError naming its
+    height), in job order."""
+    for err in commit_job_outcomes(jobs):
+        if err is not None:
+            raise err
 
 
 def _first_false(oks) -> int:

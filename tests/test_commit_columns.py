@@ -39,6 +39,7 @@ from tendermint_tpu.types.validator import (
     Validator,
     ValidatorSet,
     batch_verify_commits,
+    commit_job_outcomes,
 )
 from tendermint_tpu.types.vote import SignedMsgType, vote_sign_bytes_raw
 from tendermint_tpu.utils import trace
@@ -330,9 +331,11 @@ def test_a_failing_job_second_of_three_is_the_one_named(second):
     assert _answer(raised) == wants[1]
     assert "height 21" in str(raised)
     _check_spans(spans, 3, selected)
-    # the first job's tally ran and passed, the second's raised, the third's never ran
+    # every job is tallied (each has an outcome of its own, ISSUE 34:
+    # `commit_job_outcomes`); what is raised is the first of them
     assert [s["attrs"]["n"] for s in _commit_spans(spans)
-            if s["name"] == "commit.tally"] == selected[:2]
+            if s["name"] == "commit.tally"] == selected
+    assert [_answer(e) for e in commit_job_outcomes(jobs)] == wants
 
 
 TRUSTING = ("plain", "bad_first", "bad_middle", "bad_last", "bad_past_cut",
