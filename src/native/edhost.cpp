@@ -18,10 +18,13 @@
 //     msgbuf + offsets: messages concatenated; offsets is uint64[n+1]
 //     out32cat: n*32 bytes, little-endian k rows
 //   tmed_sha512(data, len, out64): single hash (for tests)
+//   tmed_row_keys, tmed_sigcache_*: the verified-signature cache's key
+//     pass and LRU store (the last section)
 
 #include <cstdint>
 #include <cstring>
 #include <dlfcn.h>
+#include <random>
 #include <thread>
 #include <vector>
 
@@ -467,6 +470,288 @@ int tmed_batch_verify(uint64_t n, const uint8_t* pub32, const uint8_t* sig64,
   }
   for (auto& t : ts) t.join();
   return 0;
+}
+
+}  // extern "C"
+
+// ---------------------------------------------------------------------------
+// The verified-signature cache (crypto/async_verify.py VerifiedSigCache):
+// a key pass and an LRU store, so that a 10,000-row submit costs three C
+// calls and no Python object per row.
+//
+// Key of a row = SHA-256(u32le len(pub) || pub || u32le len(sig) || sig ||
+// msg): the lengths are fixed-width, so the encoding is injective.  The
+// SHA-256 is libcrypto's LOW-LEVEL SHA256_Init/Update/Final: OpenSSL 3's
+// one-shot SHA256() fetches the algorithm on every call, which costs more
+// than the hash of a 216-byte row.  Single-threaded; the GIL is released
+// by ctypes for the call.
+// ---------------------------------------------------------------------------
+
+struct Sha256Api {
+  // SHA256_CTX is 112 bytes in OpenSSL 1.1 and 3; no headers in the image,
+  // so the context is an opaque buffer larger than that
+  int (*init)(void*);
+  int (*update)(void*, const void*, size_t);
+  int (*final)(unsigned char*, void*);
+  bool ok;
+};
+
+static Sha256Api load_sha256_api() {
+  Sha256Api a;
+  memset(&a, 0, sizeof(a));
+  void* h = dlopen("libcrypto.so.3", RTLD_NOW | RTLD_LOCAL);
+  if (!h) h = dlopen("libcrypto.so.1.1", RTLD_NOW | RTLD_LOCAL);
+  if (!h) return a;
+  a.init = (int (*)(void*))dlsym(h, "SHA256_Init");
+  a.update = (int (*)(void*, const void*, size_t))dlsym(h, "SHA256_Update");
+  a.final = (int (*)(unsigned char*, void*))dlsym(h, "SHA256_Final");
+  a.ok = a.init && a.update && a.final;
+  return a;
+}
+
+static const Sha256Api& sha256_api() {
+  static Sha256Api a = load_sha256_api();
+  return a;
+}
+
+static inline void put_u32le(uint8_t* p, uint32_t v) {
+  p[0] = (uint8_t)v;
+  p[1] = (uint8_t)(v >> 8);
+  p[2] = (uint8_t)(v >> 16);
+  p[3] = (uint8_t)(v >> 24);
+}
+
+// Open-addressing table of node indexes (linear probing, backward-shift
+// deletion: no tombstones) over a pool of 32-byte keys threaded on one
+// LRU list, head = least recently used.  A slot keeps the low 32 bits of
+// the key's hash beside the node, so a probe reads a key only on a tag
+// match and a deletion finds each neighbour's home slot without reading
+// its key.  Keys are SHA-256 digests; a slot is not taken from their
+// raw bits, which whoever can get rows stored could grind onto one probe
+// run, but from their first 8 bytes XORed with a seed drawn per table
+// and mixed (splitmix64's finaliser), so a run is not predictable
+// without the seed.
+struct SigCache {
+  static constexpr uint32_t NIL = 0xFFFFFFFFu;
+  struct Slot {
+    uint32_t tag;   // low 32 bits of the key's hash
+    uint32_t node;  // node index + 1; 0 = empty
+  };
+  std::vector<Slot> table;
+  std::vector<uint8_t> keys;  // 32 bytes a node
+  std::vector<uint32_t> prev, next, free_nodes;
+  uint32_t head = NIL, tail = NIL;
+  uint64_t count = 0;
+  uint64_t seed;
+
+  SigCache() : table(1024), seed((uint64_t)(uintptr_t)this) {
+    try {
+      std::random_device rd;
+      seed ^= ((uint64_t)rd() << 32) ^ rd();
+    } catch (...) {
+      // no entropy source: the table's address, which ASLR varies
+    }
+  }
+
+  inline uint64_t hash_of(const uint8_t* k) const {
+    uint64_t h;
+    memcpy(&h, k, 8);
+    h ^= seed;
+    h = (h ^ (h >> 30)) * 0xbf58476d1ce4e5b9ULL;
+    h = (h ^ (h >> 27)) * 0x94d049bb133111ebULL;
+    return h ^ (h >> 31);
+  }
+  inline size_t mask() const { return table.size() - 1; }
+
+  uint32_t find(const uint8_t* k, size_t* slot_out) const {
+    uint64_t h = hash_of(k);
+    uint32_t tag = (uint32_t)h;
+    size_t m = mask();
+    for (size_t i = (size_t)h & m;; i = (i + 1) & m) {
+      const Slot& s = table[i];
+      if (!s.node) {
+        *slot_out = i;
+        return NIL;
+      }
+      if (s.tag == tag && memcmp(&keys[32 * (size_t)(s.node - 1)], k, 32) == 0) {
+        *slot_out = i;
+        return s.node - 1;
+      }
+    }
+  }
+
+  void unlink(uint32_t x) {
+    uint32_t p = prev[x], q = next[x];
+    if (p != NIL) next[p] = q; else head = q;
+    if (q != NIL) prev[q] = p; else tail = p;
+  }
+
+  void push_tail(uint32_t x) {
+    prev[x] = tail;
+    next[x] = NIL;
+    if (tail != NIL) next[tail] = x; else head = x;
+    tail = x;
+  }
+
+  void touch(uint32_t x) {
+    if (x == tail) return;
+    unlink(x);
+    push_tail(x);
+  }
+
+  void grow_table() {
+    std::vector<Slot> old;
+    old.swap(table);
+    table.assign(old.size() * 2, Slot{0, 0});
+    size_t m = mask();
+    for (const Slot& s : old) {
+      if (!s.node) continue;
+      size_t i = (size_t)hash_of(&keys[32 * (size_t)(s.node - 1)]) & m;
+      while (table[i].node) i = (i + 1) & m;
+      table[i] = s;
+    }
+  }
+
+  // a key known to be absent, its empty slot from find(); the new node
+  // is the most recently used
+  void insert(const uint8_t* k, size_t slot) {
+    if (2 * (count + 1) > table.size()) {
+      grow_table();
+      find(k, &slot);
+    }
+    uint32_t x;
+    if (!free_nodes.empty()) {
+      x = free_nodes.back();
+      free_nodes.pop_back();
+    } else {
+      x = (uint32_t)prev.size();
+      prev.push_back(NIL);
+      next.push_back(NIL);
+      keys.resize(keys.size() + 32);
+    }
+    memcpy(&keys[32 * (size_t)x], k, 32);
+    table[slot] = Slot{(uint32_t)hash_of(k), x + 1};
+    push_tail(x);
+    count++;
+  }
+
+  void erase(uint32_t x) {
+    size_t m = mask();
+    size_t i = (size_t)hash_of(&keys[32 * (size_t)x]) & m;
+    while (table[i].node != x + 1) i = (i + 1) & m;
+    // backward shift: pull each later entry of the run into the hole
+    // unless its home slot lies cyclically in (hole, its slot]
+    for (size_t j = (i + 1) & m; table[j].node; j = (j + 1) & m) {
+      size_t home = (size_t)table[j].tag & m;
+      bool stays = (i <= j) ? (i < home && home <= j) : (i < home || home <= j);
+      if (!stays) {
+        table[i] = table[j];
+        i = j;
+      }
+    }
+    table[i] = Slot{0, 0};
+    unlink(x);
+    free_nodes.push_back(x);
+    count--;
+  }
+
+  void evict_to(uint64_t cap) {
+    while (count > cap) erase(head);
+  }
+};
+
+extern "C" {
+
+int tmed_have_sha256(void) { return sha256_api().ok ? 1 : 0; }
+
+// out32[i] = SHA-256 of row i's encoding (above).  Each column is its
+// rows concatenated with a uint32 length a row.  Returns 0, or -1 when
+// libcrypto's SHA-256 is unavailable (the caller hashes in Python).
+int tmed_row_keys(uint64_t n, const uint8_t* pubbuf, const uint32_t* publen,
+                  const uint8_t* sigbuf, const uint32_t* siglen,
+                  const uint8_t* msgbuf, const uint32_t* msglen,
+                  uint8_t* out32) {
+  const Sha256Api& a = sha256_api();
+  if (!a.ok) return -1;
+  alignas(16) unsigned char ctx[256];
+  uint8_t head[256];
+  for (uint64_t i = 0; i < n; i++) {
+    uint32_t lp = publen[i], ls = siglen[i], lm = msglen[i];
+    a.init(ctx);
+    if (8 + (size_t)lp + ls <= sizeof head) {
+      // the usual row: the two length words, pub and sig in one update
+      put_u32le(head, lp);
+      memcpy(head + 4, pubbuf, lp);
+      put_u32le(head + 4 + lp, ls);
+      memcpy(head + 8 + lp, sigbuf, ls);
+      a.update(ctx, head, 8 + (size_t)lp + ls);
+    } else {
+      uint8_t w[4];
+      put_u32le(w, lp);
+      a.update(ctx, w, 4);
+      a.update(ctx, pubbuf, lp);
+      put_u32le(w, ls);
+      a.update(ctx, w, 4);
+      a.update(ctx, sigbuf, ls);
+    }
+    a.update(ctx, msgbuf, lm);
+    a.final(out32 + 32 * i, ctx);
+    pubbuf += lp;
+    sigbuf += ls;
+    msgbuf += lm;
+  }
+  return 0;
+}
+
+void* tmed_sigcache_new(void) { return new SigCache(); }
+
+void tmed_sigcache_free(void* h) { delete static_cast<SigCache*>(h); }
+
+uint64_t tmed_sigcache_len(void* h) { return static_cast<SigCache*>(h)->count; }
+
+// found[i] = 1 where keys row i is cached (the row becomes the most
+// recently used), else 0; returns the hits.  `cap` first evicts down to
+// the capacity, so a shrink takes effect at the next probe.
+uint64_t tmed_sigcache_probe(void* h, uint64_t cap, uint64_t n,
+                             const uint8_t* keys32, uint8_t* found) {
+  SigCache* c = static_cast<SigCache*>(h);
+  c->evict_to(cap);
+  uint64_t hits = 0;
+  size_t slot;
+  for (uint64_t i = 0; i < n; i++) {
+    uint32_t x = c->find(keys32 + 32 * i, &slot);
+    found[i] = x != SigCache::NIL;
+    if (found[i]) {
+      c->touch(x);
+      hits++;
+    }
+  }
+  return hits;
+}
+
+// Stores the rows whose mask byte is non-zero (mask NULL: every row), in
+// row order: a present key is refreshed, and the least recently used is
+// evicted beyond `cap`.  Returns the rows stored.
+uint64_t tmed_sigcache_put(void* h, uint64_t cap, uint64_t n,
+                           const uint8_t* keys32, const uint8_t* mask) {
+  SigCache* c = static_cast<SigCache*>(h);
+  c->evict_to(cap);
+  if (cap == 0) return 0;
+  uint64_t stored = 0;
+  size_t slot;
+  for (uint64_t i = 0; i < n; i++) {
+    if (mask && !mask[i]) continue;
+    const uint8_t* k = keys32 + 32 * i;
+    uint32_t x = c->find(k, &slot);
+    if (x != SigCache::NIL) {
+      c->touch(x);
+    } else {
+      c->insert(k, slot);
+      if (c->count > cap) c->erase(c->head);
+    }
+    stored++;
+  }
+  return stored;
 }
 
 }  // extern "C"

@@ -32,12 +32,21 @@ verification; PAPERS.md):
     immediately starts host prep (sign-bytes SHA-512, s<L) for batch
     i+1; verdicts are drained when a second batch is in flight or the
     queue runs dry.  A flush is one program at its rung.
-  * A bounded verified-signature LRU cache keyed by
-    (pub, sha256(msg), sig) is consulted before enqueue — one bulk
-    probe per submit, under one lock acquisition — and populated ONLY
-    on success, one bulk put per flush — gossip duplicates and replay
-    re-verification never reach the device (and a corrupted signature
-    can never be cached as valid, by construction).
+  * A bounded verified-signature LRU cache is consulted before
+    enqueue — one bulk probe per submit, under one lock acquisition —
+    and populated ONLY on success, one bulk put per flush — gossip
+    duplicates and replay re-verification never reach the device (and a
+    corrupted signature can never be cached as valid, by construction).
+    A row's key is ONE 32-byte SHA-256 over an injective encoding of
+    the row, `u32le len(pub) || pub || u32le len(sig) || sig || msg`
+    (fixed-width lengths: no two distinct rows share an encoding).
+    Where `libedhost` and libcrypto's SHA-256 load
+    (`crypto/sigcache_native`), a submit's key pass is one C call over
+    its three columns into a `uint8[n, 32]` array, and the LRU of keys
+    lives in native code: the key pass, the probe and the put make no
+    Python object per row.  Elsewhere the same keys come from `hashlib`
+    and the store is an `OrderedDict` keyed by their bytes — chosen by
+    whether the library loads, as host prep's k pass is.
 
 Degradation contract (the `_DEVICE_READY` guarantee, one level up): the
 worker only dispatches to the device after crypto.batch's warmup has
@@ -57,7 +66,8 @@ Env knobs:
                         per-caller BatchVerifier instances.
   TM_TPU_LINGER_MS      coalescing window in milliseconds (default 1.0).
   TM_TPU_VERIFY_CACHE   verified-signature cache capacity in entries
-                        (default 65536; 0 disables the cache).
+                        (default 65536; 0 disables the cache), resolved
+                        at every probe and put: a shrink evicts.
   TM_TPU_MESH           multi-device dispatch (crypto/mesh_dispatch):
                         unset/auto shards large flushes across the full
                         device mesh and pins small ones to one chip;
@@ -67,9 +77,11 @@ Env knobs:
   TM_TPU_MESH_MIN_SHARD flush size at/above which a flush shards
                         (default 64 rows per device).
   TM_TPU_TRACE          1 additionally records submit (with one child
-                        per bulk pass: keys, probe) and wait (caller) and
+                        per bulk pass: keys — `native` says which key
+                        pass ran —, probe) and wait (caller) and
                         coalesce/account/flush/host-prep/device-execute/
-                        resolve (worker) spans into the utils.trace ring,
+                        resolve (its child cache_put) (worker) spans
+                        into the utils.trace ring,
                         the worker's tied by a `flush` number
                         (docs/observability.md); the latency histograms
                         below are always on, observed once per flush
@@ -81,19 +93,23 @@ from __future__ import annotations
 import hashlib
 import logging
 import os
+import struct
 import threading
 import time
 from collections import OrderedDict, deque
 from concurrent.futures import Future
-from itertools import chain, compress
+from itertools import compress
+
+import numpy as np
 
 from tendermint_tpu.utils import devmon as _devmon
 from tendermint_tpu.utils import trace as _trace
-from tendermint_tpu.utils.metrics import Histogram
+from tendermint_tpu.utils.metrics import Counter, Histogram
 
 from . import ed25519 as _ed
 from . import batch as _batch
 from . import mesh_dispatch as _mesh
+from . import sigcache_native as _sn
 from .batch import _BaseBatch, _bytes_column, _pub_bytes, _split_verify
 
 _log = logging.getLogger("tendermint_tpu.crypto.async_verify")
@@ -142,6 +158,16 @@ PIPELINE_HISTOGRAMS = (
     VERIFY_E2E_SECONDS,
 )
 
+# Which key pass the cache's keys took: `native` (one C call a column,
+# crypto/sigcache_native) or `python` (hashlib, the library did not
+# load).  Bumped once a key pass with its row count; node/metrics.py
+# registers it.
+VERIFY_CACHE_ROWS_TOTAL = Counter(
+    "verify_cache_rows_total",
+    "Rows whose signature-cache key was computed, by key pass",
+    namespace="tendermint", subsystem="crypto", label_names=("pass",))
+_NATIVE_PASS, _PYTHON_PASS = {"pass": "native"}, {"pass": "python"}
+
 
 def _env_float(name: str, default: float) -> float:
     try:
@@ -159,9 +185,10 @@ def _env_int(name: str, default: int) -> int:
 
 class _Group:
     """One submit: the unit the service queues, accounts and resolves.
-    `pubs` / `msgs` / `sigs` / `keys` are its FRESH rows (the cache
-    misses; the submit's own lists when nothing hit), `pos` their
-    places in `results` (None: every row is fresh, in place).
+    `pubs` / `msgs` / `sigs` are its FRESH rows (the cache misses; the
+    submit's own lists when nothing hit), `keys` their cache keys
+    (`uint8[n, 32]`), `n` how many, `pos` their places in `results`
+    (None: every row is fresh, in place).
     `results` starts as the cache probe's answer — True at the hits,
     False at every row no verify path has answered yet — and `future`
     resolves to it once the last fresh row has landed (`single`: to its
@@ -169,19 +196,20 @@ class _Group:
     under the service lock), `left` those not yet resolved (the
     worker's alone)."""
 
-    __slots__ = ("pubs", "msgs", "sigs", "keys", "pos", "results", "single",
-                 "t_submit", "future", "taken", "left")
+    __slots__ = ("pubs", "msgs", "sigs", "keys", "n", "pos", "results",
+                 "single", "t_submit", "future", "taken", "left")
 
     def __init__(self, pubs, msgs, sigs, keys, pos, results, single,
                  t_submit):
         self.pubs, self.msgs, self.sigs, self.keys = pubs, msgs, sigs, keys
+        self.n = len(pubs)
         self.pos = pos
         self.results = results
         self.single = single
         self.t_submit = t_submit
         self.future: Future = Future()
         self.taken = 0
-        self.left = len(keys)
+        self.left = self.n
 
     def land(self, start: int, oks: list) -> None:
         """Verdicts of the fresh rows from `start` on; the last to land
@@ -213,7 +241,7 @@ class _Batch:
     def __init__(self, segs: list):
         self.segs = segs
         g, a, b = segs[0]
-        if len(segs) == 1 and a == 0 and b == len(g.keys):
+        if len(segs) == 1 and a == 0 and b == g.n:
             self.pubs, self.msgs, self.sigs = g.pubs, g.msgs, g.sigs
         else:
             self.pubs = [p for g, a, b in segs for p in g.pubs[a:b]]
@@ -224,13 +252,88 @@ class _Batch:
         return len(self.pubs)
 
 
+_u32le = struct.Struct("<I").pack
+
+
+def _row_key(pub: bytes, msg: bytes, sig: bytes) -> bytes:
+    return hashlib.sha256(b"".join(
+        (_u32le(len(pub)), pub, _u32le(len(sig)), sig, msg))).digest()
+
+
+def _row_keys(pubs, msgs, sigs) -> tuple[np.ndarray, bool]:
+    """The key of every row as `uint8[n, 32]`, and whether the native
+    pass made it (one C call) or `hashlib` did (the library did not
+    load).  The columns hold `bytes`."""
+    keys = _sn.row_keys(pubs, msgs, sigs)
+    native = keys is not None
+    if not native:
+        keys = np.frombuffer(b"".join(map(_row_key, pubs, msgs, sigs)),
+                             dtype=np.uint8).reshape(-1, 32)
+    VERIFY_CACHE_ROWS_TOTAL.inc(len(keys), **(_NATIVE_PASS if native
+                                              else _PYTHON_PASS))
+    return keys, native
+
+
+def _one_key(key: bytes) -> np.ndarray:
+    return np.frombuffer(key, dtype=np.uint8).reshape(1, 32)
+
+
+class _PyStore:
+    """The LRU store where the native one does not load: an OrderedDict
+    keyed by the 32 key bytes, least recently used first."""
+
+    def __init__(self):
+        self._d: OrderedDict = OrderedDict()
+
+    @staticmethod
+    def _rows(keys: np.ndarray) -> list[bytes]:
+        b = keys.tobytes()
+        return [b[i:i + 32] for i in range(0, len(b), 32)]
+
+    def probe(self, keys: np.ndarray, cap: int) -> tuple[np.ndarray, int]:
+        d = self._d
+        while len(d) > cap:
+            d.popitem(last=False)
+        rows = self._rows(keys)
+        found = [k in d for k in rows]
+        for k in compress(rows, found):
+            d.move_to_end(k)
+        return np.array(found, dtype=np.uint8), sum(found)
+
+    def put(self, keys: np.ndarray, mask: bytes | None, cap: int) -> int:
+        d = self._d
+        rows = self._rows(keys)
+        if mask is not None:
+            rows = list(compress(rows, mask))
+        for k in rows:
+            d[k] = True
+            d.move_to_end(k)
+        while len(d) > cap:
+            d.popitem(last=False)
+        return len(rows)
+
+    def __len__(self) -> int:
+        return len(self._d)
+
+
 class VerifiedSigCache:
-    """Bounded thread-safe LRU of (pub, sha256(msg), sig) triples proven
-    VALID.  Only True verdicts are ever stored: a rejected signature is
-    re-verified on every appearance, so a corrupted signature cannot be
-    cached as valid no matter what races occur.  Probes and puts are
-    bulk: one lock acquisition and one read of the capacity for a whole
-    submit or flush."""
+    """Bounded thread-safe LRU of the keys of rows proven VALID.  Only
+    True verdicts are ever stored: a rejected signature is re-verified
+    on every appearance, so a corrupted signature cannot be cached as
+    valid no matter what races occur.  Probes and puts are bulk: one
+    lock acquisition and one read of the capacity for a whole submit or
+    flush.
+
+    A row's key is SHA-256(u32le len(pub) || pub || u32le len(sig) ||
+    sig || msg): 32 bytes, the lengths fixed-width so that no two
+    distinct rows share an encoding — as collision-resistant as the
+    (pub, sha256(msg), sig) triple it replaced.  `keys()` computes a
+    column's in one native call (`uint8[n, 32]`), `key()` one row's with
+    `hashlib` over the same bytes.  The store is native
+    (`sigcache_native.NativeStore`) where the library loads, else an
+    OrderedDict of the same keys; the same exact LRU either way: a hit
+    becomes the most recently used, a put of a present key refreshes it,
+    the least recently used goes beyond the capacity."""
 
     def __init__(self, maxsize: int | None = None):
         # None = resolve TM_TPU_VERIFY_CACHE at every probe, so a value
@@ -239,7 +342,7 @@ class VerifiedSigCache:
         # order-dependent test_multinode flake — the pinned-threshold
         # half lives in crypto/batch.py)
         self._pinned_maxsize = maxsize
-        self._d: OrderedDict = OrderedDict()
+        self._store = _sn.NativeStore() if _sn.available() else _PyStore()
         self._lock = threading.Lock()
         self.hits = 0
         self.misses = 0
@@ -251,54 +354,51 @@ class VerifiedSigCache:
         return _env_int("TM_TPU_VERIFY_CACHE", DEFAULT_CACHE_SIZE)
 
     @staticmethod
-    def keys(pubs, msgs, sigs) -> list[tuple]:
-        sha256 = hashlib.sha256
-        return [(p, sha256(m).digest(), s)
-                for p, m, s in zip(pubs, msgs, sigs)]
+    def keys(pubs, msgs, sigs) -> np.ndarray:
+        return _row_keys(pubs, msgs, sigs)[0]
 
     @staticmethod
-    def key(pub: bytes, msg: bytes, sig: bytes) -> tuple:
-        return (pub, hashlib.sha256(msg).digest(), sig)
+    def key(pub: bytes, msg: bytes, sig: bytes) -> bytes:
+        return _row_key(pub, msg, sig)
 
-    def get_many(self, keys) -> list[bool]:
-        """Which of `keys` are cached (each hit becomes the most
-        recently used)."""
-        if self.maxsize <= 0:
-            self.misses += len(keys)  # tmsan: shared=diagnostic counter on the disabled-cache path; tolerates lost updates
-            return [False] * len(keys)
-        with self._lock:
-            d = self._d
-            found = [k in d for k in keys]
-            n_hit = sum(found)
-            if n_hit:
-                for k in compress(keys, found):
-                    d.move_to_end(k)
-            self.hits += n_hit
-            self.misses += len(keys) - n_hit
-        return found
-
-    def get(self, key) -> bool:
-        return self.get_many((key,))[0]
-
-    def put_many(self, keys) -> None:
-        """Remember `keys` — of rows PROVEN valid, the caller's duty —
-        and evict the least recently used beyond the capacity."""
+    def probe(self, keys: np.ndarray) -> tuple[np.ndarray, int]:
+        """(`found`, hits): `found[i]` 1 where row i of `keys` is cached
+        (each hit becomes the most recently used).  A capacity below the
+        store's size evicts first."""
+        n = len(keys)
         cap = self.maxsize
-        if cap <= 0 or not keys:
-            return
+        if cap <= 0:
+            self.misses += n  # tmsan: shared=diagnostic counter on the disabled-cache path; tolerates lost updates
+            return np.zeros(n, dtype=np.uint8), 0
         with self._lock:
-            d = self._d
-            for k in keys:
-                d[k] = True
-                d.move_to_end(k)
-            while len(d) > cap:
-                d.popitem(last=False)
+            found, hits = self._store.probe(keys, cap)
+            self.hits += hits
+            self.misses += n - hits
+        return found, hits
 
-    def put(self, key) -> None:
-        self.put_many((key,))
+    def get_many(self, keys: np.ndarray) -> list[bool]:
+        """Which rows of `keys` are cached, as bools."""
+        return self.probe(keys)[0].astype(bool).tolist()
+
+    def get(self, key: bytes) -> bool:
+        return self.get_many(_one_key(key))[0]
+
+    def put_many(self, keys: np.ndarray, mask: bytes | None = None) -> int:
+        """Remember the rows of `keys` whose `mask` byte is non-zero
+        (every row without a mask) — rows PROVEN valid, the caller's
+        duty — and evict the least recently used beyond the capacity.
+        Returns the rows stored."""
+        cap = self.maxsize
+        if cap <= 0 or not len(keys):
+            return 0
+        with self._lock:
+            return self._store.put(keys, mask, cap)
+
+    def put(self, key: bytes) -> None:
+        self.put_many(_one_key(key))
 
     def __len__(self) -> int:
-        return len(self._d)
+        return len(self._store)
 
 
 class VerifyService:
@@ -400,9 +500,9 @@ class VerifyService:
     def _submit(self, pubs, msgs, sigs, single: bool = False,
                 t_sub: float | None = None) -> _Group:
         """Build and queue the group of one submit: every pass is bulk —
-        the key hashes in one comprehension, the cache probe and the
-        queue append under one lock acquisition each — so a 10k commit
-        pays nothing per row but the hashing itself."""
+        the keys in one native call, the cache probe and the queue
+        append under one lock acquisition each — so a 10k commit makes
+        no Python object per row on its way to the queue."""
         if t_sub is None:
             t_sub = time.perf_counter()  # one stamp per submit
         if not len(pubs) == len(msgs) == len(sigs):
@@ -411,25 +511,29 @@ class VerifyService:
         msgs = _bytes_column(msgs)
         sigs = _bytes_column(sigs)
         t_keys = time.perf_counter()
-        keys = VerifiedSigCache.keys(pubs, msgs, sigs)
+        keys, native = _row_keys(pubs, msgs, sigs)
         t_probe = time.perf_counter()
-        found = self.cache.get_many(keys)
+        found, hits = self.cache.probe(keys)
         t_probed = time.perf_counter()
-        n, hits, pos = len(keys), sum(found), None
+        n, pos = len(pubs), None
         if hits:
             VERIFY_E2E_SECONDS.observe_n(t_probed - t_sub, hits, path="cache")
-            pos = [i for i, hit in enumerate(found) if not hit]
-            pubs, msgs, sigs, keys = ([col[i] for i in pos]
-                                      for col in (pubs, msgs, sigs, keys))
-        group = _Group(pubs, msgs, sigs, keys, pos, found, single, t_sub)
-        if keys:
+            results = found.astype(bool).tolist()
+            fresh = np.flatnonzero(found == 0)
+            keys, pos = keys[fresh], fresh.tolist()
+            pubs, msgs, sigs = ([col[i] for i in pos]
+                                for col in (pubs, msgs, sigs))
+        else:
+            results = [False] * n
+        group = _Group(pubs, msgs, sigs, keys, pos, results, single, t_sub)
+        if group.n:
             with self._cv:
                 if self._closed:
                     raise RuntimeError("verify service is closed")
                 self.stats["submits"] += 1
-                self.stats["submitted"] += len(keys)
+                self.stats["submitted"] += group.n
                 self._queue.append(group)
-                self.stats["queue_depth"] += len(keys)
+                self.stats["queue_depth"] += group.n
                 self._ensure_worker_locked()
                 self._cv.notify()
         else:
@@ -440,7 +544,7 @@ class VerifyService:
                                 n=n, fresh=n - hits, hits=hits)
             # one child per bulk pass, never per row
             _trace.record("verify.submit.keys", t_keys, t_probe - t_keys,
-                          parent=sid, n=n)
+                          parent=sid, n=n, native=int(native))
             _trace.record("verify.submit.probe", t_probe, t_probed - t_probe,
                           parent=sid, n=n, hits=hits)
         return group
@@ -522,10 +626,10 @@ class VerifyService:
             while self._queue and room:
                 group = self._queue[0]
                 start = group.taken
-                end = group.taken = min(len(group.keys), start + room)
+                end = group.taken = min(group.n, start + room)
                 segs.append((group, start, end))
                 room -= end - start
-                if end == len(group.keys):
+                if end == group.n:
                     self._queue.popleft()
             n = MAX_COALESCE - room
             self.stats["queue_depth"] -= n
@@ -797,13 +901,20 @@ class VerifyService:
             self._land(batch.segs, oks)
 
     def _land(self, segs: list, oks: list) -> None:
-        """Put the valid rows' keys with ONE bulk cache put (only True
+        """Put the valid rows' keys with ONE bulk cache put — the
+        segments' key slices and the verdicts as its mask: only True
         rows, as the cache promises; before any future resolves, so a
-        caller that wakes finds its rows cached), then hand each segment
+        caller that wakes finds its rows cached — then hand each segment
         its slice of the verdicts; a group whose last row lands
         resolves."""
-        keys = chain.from_iterable(g.keys[a:b] for g, a, b in segs)
-        self.cache.put_many(list(compress(keys, oks)))
+        with _trace.span("verify.cache_put", n=len(oks),
+                         flush=self._flush_no) as sp:
+            if len(segs) == 1:
+                g, a, b = segs[0]
+                keys = g.keys[a:b]
+            else:
+                keys = np.concatenate([g.keys[a:b] for g, a, b in segs])
+            sp.set(stored=self.cache.put_many(keys, bytes(oks)))
         at = 0
         for group, start, end in segs:
             group.land(start, oks[at:at + end - start])
@@ -969,7 +1080,7 @@ def service_stats() -> dict:
     with cache._lock:
         out["cache_hits"] = cache.hits
         out["cache_misses"] = cache.misses
-        out["cache_size"] = len(cache._d)
+        out["cache_size"] = len(cache)
     return out
 
 

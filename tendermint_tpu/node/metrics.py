@@ -523,6 +523,7 @@ class NodeMetrics:
         self.rpc_request_duration = reg.register(_rpc_hist)
         for hist in _av.PIPELINE_HISTOGRAMS:
             reg.register(hist)
+        reg.register(_av.VERIFY_CACHE_ROWS_TOTAL)
         reg.register(_rows_added)
 
         # -- transaction lifecycle (utils/txlife.py) --------------------

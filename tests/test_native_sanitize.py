@@ -269,3 +269,58 @@ def test_batch_verify_kernel_under_asan(tmp_path):
     assert ("BATCHVERIFY-OK" in proc.stdout) or ("NO-LIBCRYPTO" in proc.stdout)
     for marker in ("ERROR: AddressSanitizer", "runtime error:"):
         assert marker not in proc.stderr, proc.stderr[-3000:]
+
+
+SIGCACHE_STRESS = r"""
+import os, random
+import tendermint_tpu.crypto.sigcache_native as sn
+sn._LIB_NAME = "libedhost_asan.so"
+from tendermint_tpu.crypto import async_verify as av
+
+assert sn.available(), "sanitized kernel must load"
+rng = random.Random(3)
+c = av.VerifiedSigCache(maxsize=50)
+for rnd in range(300):
+    n = rng.randrange(0, 300)
+    pubs = [os.urandom(rng.choice([32, 33, 0, 300])) for _ in range(n)]
+    sigs = [os.urandom(rng.choice([64, 65, 0])) for _ in range(n)]
+    msgs = [os.urandom(rng.choice([0, 120, 5000])) for _ in range(n)]
+    keys = av.VerifiedSigCache.keys(pubs, msgs, sigs)
+    for i in range(n):
+        assert keys[i].tobytes() == av.VerifiedSigCache.key(pubs[i], msgs[i], sigs[i])
+    c._pinned_maxsize = rng.choice([0, 1, 5, 50, 2000])   # shrinks and growths
+    c.probe(keys)
+    c.put_many(keys, bytes(rng.random() < 0.5 for _ in range(n)))
+    assert c.maxsize == 0 or len(c) <= c.maxsize   # 0: the store untouched
+print("SIGCACHE-OK")
+"""
+
+
+@pytest.mark.slow
+def test_sigcache_kernels_under_asan(tmp_path):
+    """tmed_row_keys and tmed_sigcache_* under ASan+UBSan: rows wider than
+    the one-update buffer, empty columns, masked puts, the capacity
+    shrinking and growing between calls (evictions, table growth) — keys
+    asserted against hashlib inside the sanitized process."""
+    if shutil.which("g++") is None:
+        pytest.skip("no g++")
+    asan = _libasan()
+    if asan is None:
+        pytest.skip("libasan not found")
+    build = subprocess.run(["make", "-C", SRC, "asan"],
+                           capture_output=True, text=True, timeout=180)
+    assert build.returncode == 0, build.stderr
+
+    env = dict(os.environ)
+    env["LD_PRELOAD"] = asan
+    env["ASAN_OPTIONS"] = "detect_leaks=0:abort_on_error=1"
+    env["JAX_PLATFORMS"] = "cpu"
+    proc = subprocess.run(
+        [sys.executable, "-c", SIGCACHE_STRESS],
+        capture_output=True, text=True, timeout=300, env=env,
+        cwd=os.path.dirname(SRC.rstrip(os.sep).rsplit(os.sep, 1)[0]),
+    )
+    assert proc.returncode == 0, f"stderr:\n{proc.stderr[-3000:]}"
+    assert "SIGCACHE-OK" in proc.stdout
+    for marker in ("ERROR: AddressSanitizer", "runtime error:"):
+        assert marker not in proc.stderr, proc.stderr[-3000:]

@@ -13,13 +13,15 @@ import pytest
 
 from helpers import small_commit
 from tendermint_tpu.crypto import async_verify as av
+from tendermint_tpu.crypto import sigcache_native
 from tendermint_tpu.utils import trace
 
 SUBMIT_PARTS = {"verify.submit.keys", "verify.submit.probe"}
 CALLER = {"commit.select", "commit.sign_bytes", "commit.add", "commit.verify",
           "verify.submit", "verify.wait", "commit.tally"} | SUBMIT_PARTS
+RESOLVE_PARTS = {"verify.cache_put"}
 WORKER = {"verify.coalesce", "verify.account", "verify.flush",
-          "verify.host_verify", "verify.resolve"}
+          "verify.host_verify", "verify.resolve"} | RESOLVE_PARTS
 
 
 @pytest.fixture(autouse=True)
@@ -96,7 +98,8 @@ def test_one_call_emits_every_span_with_its_attrs_and_ties(mode, selected):
     assert by["verify.wait"]["parent"] == by["commit.verify"]["id"]
     # the submit's bulk passes: one child each, inside it, never per row
     sub = by["verify.submit"]
-    assert by["verify.submit.keys"]["attrs"] == {"n": n}
+    assert by["verify.submit.keys"]["attrs"] == {
+        "n": n, "native": int(sigcache_native.available())}
     assert by["verify.submit.probe"]["attrs"] == {"n": n, "hits": 0}
     for name in SUBMIT_PARTS:
         assert by[name]["parent"] == sub["id"], name
@@ -123,7 +126,8 @@ def test_one_call_emits_every_span_with_its_attrs_and_ties(mode, selected):
     flush = by["verify.coalesce"]["attrs"]["flush"]
     assert isinstance(flush, int) and flush >= 1
     for name in WORKER:
-        assert by[name]["tid"] != tid and by[name]["parent"] is None, name
+        assert by[name]["tid"] != tid, name
+        assert by[name]["parent"] is None or name in RESOLVE_PARTS, name
         assert by[name]["attrs"]["flush"] == flush, name
     assert by["verify.coalesce"]["attrs"]["oldest_submit_ns"] == by["verify.submit"]["t0_ns"]
     assert by["verify.coalesce"]["attrs"]["groups"] == 1
@@ -135,6 +139,12 @@ def test_one_call_emits_every_span_with_its_attrs_and_ties(mode, selected):
     # in the order of a flush, each inside the caller's wait
     def end(s):
         return s["t0_ns"] + s["dur_ns"]
+
+    # the cache put: one span inside the resolve, every row proven valid
+    put, res = by["verify.cache_put"], by["verify.resolve"]
+    assert put["parent"] == res["id"]
+    assert put["attrs"] == {"n": n, "stored": n, "flush": flush}
+    assert res["t0_ns"] <= put["t0_ns"] and end(put) <= end(res)
 
     assert end(by["verify.coalesce"]) <= by["verify.account"]["t0_ns"]
     assert end(by["verify.account"]) <= by["verify.flush"]["t0_ns"]
