@@ -17,6 +17,8 @@
 //     r32cat/pub32cat: n*32 bytes each (R rows, A rows)
 //     msgbuf + offsets: messages concatenated; offsets is uint64[n+1]
 //     out32cat: n*32 bytes, little-endian k rows
+//     returns the threads that hashed the batch: 1 = the calling thread
+//     alone, else the number of threads the call started (0: no row)
 //   tmed_sha512(data, len, out64): single hash (for tests)
 //   tmed_row_keys, tmed_sigcache_*: the verified-signature cache's key
 //     pass and LRU store (the last section)
@@ -349,16 +351,16 @@ void tmed_sha512(const uint8_t* data, uint64_t len, uint8_t out[64]) {
 // verdicts stay bit-identical to the consensus rules.
 // ---------------------------------------------------------------------------
 
-void tmed_batch_k(uint64_t n, const uint8_t* r32, const uint8_t* pub32,
-                  const uint8_t* msgbuf, const uint64_t* offsets,
-                  uint8_t* out32, int nthreads) {
-  if (n == 0) return;
+int tmed_batch_k(uint64_t n, const uint8_t* r32, const uint8_t* pub32,
+                 const uint8_t* msgbuf, const uint64_t* offsets,
+                 uint8_t* out32, int nthreads) {
+  if (n == 0) return 0;
   unsigned hw = std::thread::hardware_concurrency();
   if (nthreads <= 0) nthreads = hw ? (int)hw : 1;
   size_t per = ((size_t)n + nthreads - 1) / nthreads;
   if (nthreads == 1 || n < 256) {
     batch_range(0, (size_t)n, r32, pub32, msgbuf, offsets, out32);
-    return;
+    return 1;
   }
   std::vector<std::thread> ts;
   for (int t = 0; t < nthreads; t++) {
@@ -368,6 +370,7 @@ void tmed_batch_k(uint64_t n, const uint8_t* r32, const uint8_t* pub32,
     ts.emplace_back(batch_range, lo, hi, r32, pub32, msgbuf, offsets, out32);
   }
   for (auto& t : ts) t.join();
+  return (int)ts.size();
 }
 
 // -- libcrypto EVP surface (hand-declared; see comment above tmed_batch_k) --

@@ -772,24 +772,64 @@ class VerifyService:
         flush = self._flush_no
         impl = dev.default_impl()
         b = dev._bucket(n)
-        t_prep = time.perf_counter()
-        rows = dev.prepare_batch(batch.pubs, batch.msgs, batch.sigs)
-        padded = dev._pad_rows(n, b, *rows)
-        prep_dt = time.perf_counter() - t_prep
-        VERIFY_HOST_PREP_SECONDS.observe(prep_dt)
-        if _trace.enabled():
-            _trace.record("verify.host_prep", t_prep, prep_dt,
-                          n=n, rung=b, flush=flush)
+        padded = self._host_prep(batch, b, flush)
         if _devmon.STATS.enabled:
             _mesh.record_pinned_flush(
                 n, b, nbytes=sum(a.nbytes for a in padded))
         while len(inflight) >= 2:
             self._drain_one(inflight)
-        t_enq = time.perf_counter()
-        pending = dev._compiled(b, impl)(*padded)
+        pending, t_enq = self._launch(dev._compiled(b, impl), padded,
+                                      flush, b, chips=1)
         inflight.append((pending, batch, t_enq, b, flush))
         with self._cv:
             self.stats["device_batches"] += 1
+
+    @staticmethod
+    def _host_prep(batch: _Batch, b: int, flush: int) -> tuple:
+        """The flush's device inputs padded to rung `b`: `prepare_batch`
+        and `_pad_rows`, timed as one `verify_host_prep_seconds` observe
+        and one `verify.host_prep` span; with spans on, its steps hang
+        under it as children (`verify.host_prep.rows`, `.canon`, `.msgs`,
+        `.k`, `.pad`), each with the flush's number."""
+        from tendermint_tpu.ops import ed25519_jax as dev
+
+        n = len(batch)
+        steps = [] if _trace.enabled() else None
+        t_prep = time.perf_counter()
+        rows = dev.prepare_batch(batch.pubs, batch.msgs, batch.sigs, sink=steps)
+        t_pad = time.perf_counter() if steps is not None else 0.0
+        padded = dev._pad_rows(n, b, *rows)
+        t_end = time.perf_counter()
+        prep_dt = t_end - t_prep
+        VERIFY_HOST_PREP_SECONDS.observe(prep_dt)
+        if steps is not None:
+            sid = _trace.record("verify.host_prep", t_prep, prep_dt,
+                                n=n, rung=b, flush=flush)
+            steps.append(("verify.host_prep.pad", t_pad, t_end - t_pad,
+                          {"rung": b, "pad_rows": b - n}))
+            for name, t0, dur, attrs in steps:
+                _trace.record(name, t0, dur, parent=sid, flush=flush, **attrs)
+        return padded
+
+    @staticmethod
+    def _launch(program, args: tuple, flush: int, rung: int,
+                chips: int) -> tuple:
+        """`program(*args)` and the time it was called: the enqueue of a
+        flush, returning before the device is done.  With spans on, a
+        `verify.launch` span (`flush`, `rung`, `chips`) covers the call,
+        and the profiler's host plane holds the same call as a
+        `verify.launch` annotation with the flush's number, on its own
+        clock."""
+        t_enq = time.perf_counter()
+        if not _trace.enabled():
+            return program(*args), t_enq
+        import jax
+
+        with jax.profiler.TraceAnnotation("verify.launch", flush=flush):
+            pending = program(*args)
+        _trace.record("verify.launch", t_enq, time.perf_counter() - t_enq,
+                      flush=flush, rung=rung, chips=chips)
+        return pending, t_enq
 
     def _enqueue_sharded(self, batch: _Batch, inflight: deque,
                          m: int) -> None:
@@ -805,21 +845,14 @@ class VerifyService:
         n = len(batch)
         flush = self._flush_no
         b = _sh.sharded_bucket(n, m)
-        t_prep = time.perf_counter()
-        rows = dev.prepare_batch(batch.pubs, batch.msgs, batch.sigs)
-        padded = dev._pad_rows(n, b, *rows)
-        prep_dt = time.perf_counter() - t_prep
-        VERIFY_HOST_PREP_SECONDS.observe(prep_dt)
-        if _trace.enabled():
-            _trace.record("verify.host_prep", t_prep, prep_dt,
-                          n=n, rung=b, flush=flush)
+        padded = self._host_prep(batch, b, flush)
         if _devmon.STATS.enabled:
             _mesh.record_sharded_flush(
                 n, b, mesh, nbytes=sum(a.nbytes for a in padded))
         while len(inflight) >= 2:
             self._drain_one(inflight)
-        t_enq = time.perf_counter()
-        pending = _mesh.enqueue_sharded(mesh, padded)
+        pending, t_enq = self._launch(_mesh.enqueue_sharded, (mesh, padded),
+                                      flush, b, chips=m)
         self.last_shard_layout = tuple(  # tmsan: shared=atomic tuple rebind, last-write-wins diagnostic
             (int(s.device.id), int(s.data.shape[0]))
             for s in pending.addressable_shards)

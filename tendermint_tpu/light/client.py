@@ -92,36 +92,42 @@ class Client:
         logger=None,
         commit_verifier=None,
     ):
-        verifier.validate_trust_level(trust_level)
-        trust_options.validate_basic()
-        if mode not in (SEQUENTIAL, SKIPPING):
-            raise ValueError(f"unknown verification mode {mode!r}")
-        self.chain_id = chain_id
-        self.trusting_period_ns = trust_options.period_ns
-        self.trust_level = trust_level
-        self.mode = mode
-        self.max_clock_drift_ns = max_clock_drift_ns
-        self.pruning_size = pruning_size
-        self.primary = primary
-        self.witnesses = list(witnesses)
-        self.store = trusted_store if trusted_store is not None else LightBlockStore()
-        self.now_fn = now_fn
-        self.logger = logger
-        # commit-batch sink override (contract of batch_verify_commits):
-        # a gateway-driven client points this at the cross-client verify
-        # coalescer so N clients syncing one chain share device flushes
-        self.commit_verifier = commit_verifier
-        # what the verification under way did so far: the attributes of
-        # its `light.verify_to_height` span
-        self._walk = {"hops": 0, "refused": 0, "fetched": 0}
-        self.latest_trusted: LightBlock | None = self.store.latest_light_block()
-        self._initialize(trust_options)
+        # the client's start, one span: the option checks, then the
+        # trusted store read back (`decodes`: trusted blocks decoded from
+        # it) or the root of trust fetched and self-verified
+        with _trace.span("light.client_start", height=trust_options.height) as sp:
+            verifier.validate_trust_level(trust_level)
+            trust_options.validate_basic()
+            if mode not in (SEQUENTIAL, SKIPPING):
+                raise ValueError(f"unknown verification mode {mode!r}")
+            self.chain_id = chain_id
+            self.trusting_period_ns = trust_options.period_ns
+            self.trust_level = trust_level
+            self.mode = mode
+            self.max_clock_drift_ns = max_clock_drift_ns
+            self.pruning_size = pruning_size
+            self.primary = primary
+            self.witnesses = list(witnesses)
+            self.store = trusted_store if trusted_store is not None else LightBlockStore()
+            self.now_fn = now_fn
+            self.logger = logger
+            # commit-batch sink override (contract of batch_verify_commits):
+            # a gateway-driven client points this at the cross-client verify
+            # coalescer so N clients syncing one chain share device flushes
+            self.commit_verifier = commit_verifier
+            # what the verification under way did so far: the attributes of
+            # its `light.verify_to_height` span
+            self._walk = {"hops": 0, "refused": 0, "fetched": 0}
+            self.latest_trusted: LightBlock | None = self.store.latest_light_block()
+            decodes = int(self.latest_trusted is not None)
+            sp.set(decodes=decodes + self._initialize(trust_options))
 
     # -- initialization -------------------------------------------------
 
-    def _initialize(self, opts: TrustOptions) -> None:
+    def _initialize(self, opts: TrustOptions) -> int:
         """Fetch + self-verify the root-of-trust block
-        (reference client.go:296-361 initializeWithTrustOptions)."""
+        (reference client.go:296-361 initializeWithTrustOptions).
+        Returns the trusted blocks it decoded from the store."""
         if self.latest_trusted is not None:
             # Existing trusted state: confirm it agrees with the options
             # (reference checkTrustedHeaderUsingOptions, client.go:381-443).
@@ -132,7 +138,7 @@ class Client:
                     f"({stored.hash().hex()}) does not match trust options hash "
                     f"({opts.hash.hex()}); purge the trusted store to continue"
                 )
-            return
+            return int(stored is not None)
         lb = self._light_block_from_primary(opts.height)
         if lb.hash() != opts.hash:
             raise LightClientError(
@@ -145,6 +151,7 @@ class Client:
             self.chain_id, lb.commit.block_id, lb.height, lb.commit
         )
         self._update_trusted_light_block(lb)
+        return 0
 
     # -- public API -----------------------------------------------------
 

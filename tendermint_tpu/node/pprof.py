@@ -100,6 +100,7 @@ def _trace_dump(fmt: str) -> tuple[str, str]:
     head = (
         f"# trace ring: enabled={int(tmtrace.enabled())} "
         f"spans={len(tmtrace.spans())} capacity={tmtrace.ring_size()} "
+        f"dropped={tmtrace.dropped()} "
         f"(TM_TPU_TRACE / TM_TPU_TRACE_RING; ?fmt=chrome for Perfetto)\n"
     )
     return "text/plain", head + tmtrace.export_jsonl() + "\n"

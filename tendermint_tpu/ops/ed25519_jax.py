@@ -79,6 +79,7 @@ from __future__ import annotations
 import functools
 import hashlib
 import os
+import time
 
 import numpy as np
 
@@ -496,14 +497,33 @@ _compiled.cache_clear = _compiled_entry.cache_clear
 _L_WORDS = np.frombuffer(L.to_bytes(32, "little"), dtype="<u8").copy()
 
 
-def prepare_batch(pubs, msgs, sigs):
+def _step(sink: list, name: str, t0: float, **attrs) -> float:
+    """Hand one step of host prep that began at `t0` to `sink`; returns
+    its end, the next step's start."""
+    t1 = time.perf_counter()
+    sink.append((name, t0, t1 - t0, attrs))
+    return t1
+
+
+def prepare_batch(pubs, msgs, sigs, sink=None):
     """Parse/validate on host; returns packed device inputs
     (pub_rows, r_rows, s_rows, k_rows, valid) — all [N,32] uint8 + bool[N].
 
     Host work is only what must stay on host: the variable-length
     SHA-512 (hashlib C) and the s < L canonicality test (ZIP-215 rule 1)
     — both vectorized/batched so host prep stays a small fraction of the
-    device call."""
+    device call.
+
+    `sink` (a list, or None): each step is handed back as
+    `(name, t0, dur, attrs)` on the perf_counter clock, in order —
+    `verify.host_prep.rows` (the length tests, joins and copies of keys
+    and signatures; `well_formed`), `.canon` (s < L), `.msgs` and `.k`
+    (utils/host_prep.batch_k_native; the hashlib fallback hands back
+    `.msgs` empty and `.k` with `native` 0).  The service passes one
+    when its spans are on; without one the arrays are the same and no
+    time is taken."""
+    if sink is not None:
+        t = time.perf_counter()
     n = len(pubs)
     valid = np.ones(n, dtype=bool)
 
@@ -524,6 +544,8 @@ def prepare_batch(pubs, msgs, sigs):
             pub_rows[i] = np.frombuffer(pub, dtype=np.uint8)
             r_rows[i] = np.frombuffer(sig[:32], dtype=np.uint8)
             s_rows[i] = np.frombuffer(sig[32:], dtype=np.uint8)
+    if sink is not None:
+        t = _step(sink, "verify.host_prep.rows", t, well_formed=int(well_formed))
 
     # ZIP-215 rule 1 (s < L), vectorized: lexicographic compare on the
     # four little-endian 64-bit words, most significant first
@@ -534,6 +556,8 @@ def prepare_batch(pubs, msgs, sigs):
         lt = lt | (~gt & (sw[:, w] < _L_WORDS[w]))
         gt = gt | (~lt & (sw[:, w] > _L_WORDS[w]))
     valid &= lt  # s == L is also non-canonical
+    if sink is not None:
+        _step(sink, "verify.host_prep.canon", t)
 
     # k = SHA-512(R || A || M) mod L per row.  The native kernel
     # (src/native/edhost.cpp via ops.host_prep) does the whole batch in
@@ -542,8 +566,11 @@ def prepare_batch(pubs, msgs, sigs):
     # would blow the 2ms BASELINE target).
     from . import host_prep
 
-    k_rows = host_prep.batch_k_native(r_rows, pub_rows, msgs)
+    k_rows = host_prep.batch_k_native(r_rows, pub_rows, msgs, sink=sink)
     if k_rows is None:
+        if sink is not None:
+            t = time.perf_counter()
+            sink.append(("verify.host_prep.msgs", t, 0.0, {"bytes": 0}))
         sha512 = hashlib.sha512
         from_bytes = int.from_bytes
         ks = bytearray(32 * n)
@@ -554,6 +581,8 @@ def prepare_batch(pubs, msgs, sigs):
             k = from_bytes(sha512(sig[:32] + pub + msgs[i]).digest(), "little") % L
             ks[32 * i : 32 * (i + 1)] = k.to_bytes(32, "little")
         k_rows = np.frombuffer(bytes(ks), dtype=np.uint8).reshape(n, 32).copy()
+        if sink is not None:
+            _step(sink, "verify.host_prep.k", t, native=0, threads=1)
     return pub_rows, r_rows, s_rows, k_rows, valid
 
 

@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import ctypes
 import threading
+import time
 
 import numpy as np
 
@@ -44,7 +45,7 @@ def load_lib():
             ctypes.POINTER(ctypes.c_uint8),
             ctypes.c_int,
         ]
-        lib.tmed_batch_k.restype = None
+        lib.tmed_batch_k.restype = ctypes.c_int
         if hasattr(lib, "tmed_batch_verify"):
             lib.tmed_batch_verify.argtypes = [
                 ctypes.c_uint64,
@@ -61,12 +62,22 @@ def load_lib():
 
 
 def batch_k_native(r_rows: np.ndarray, pub_rows: np.ndarray,
-                   msgs, n_threads: int = 0) -> np.ndarray | None:
+                   msgs, n_threads: int = 0, sink=None) -> np.ndarray | None:
     """k rows [N,32] (little-endian scalars mod L), or None when the
-    native kernel is unavailable.  r_rows/pub_rows: [N,32] uint8."""
+    native kernel is unavailable.  r_rows/pub_rows: [N,32] uint8.
+
+    `sink` (a list, or None): where the two steps are handed back as
+    `(name, t0, dur, attrs)` on the perf_counter clock —
+    `verify.host_prep.msgs` (the lengths, offsets and join of the
+    messages; `bytes`) and `verify.host_prep.k` (the native call alone;
+    `native` 1, `threads` = what the call returns: 1 when it hashed on
+    the calling thread, else the threads it started).  Without one no
+    time is taken."""
     lib = load_lib()
     if lib is None:
         return None
+    if sink is not None:
+        t0 = time.perf_counter()
     n = len(msgs)
     lens = np.fromiter((len(m) for m in msgs), dtype=np.uint64, count=n)
     offsets = np.zeros(n + 1, dtype=np.uint64)
@@ -75,7 +86,11 @@ def batch_k_native(r_rows: np.ndarray, pub_rows: np.ndarray,
     out = np.zeros((n, 32), dtype=np.uint8)
     r_c = np.ascontiguousarray(r_rows)
     pub_c = np.ascontiguousarray(pub_rows)
-    lib.tmed_batch_k(
+    if sink is not None:
+        t1 = time.perf_counter()
+        sink.append(("verify.host_prep.msgs", t0, t1 - t0,
+                     {"bytes": len(msg_buf)}))
+    threads = lib.tmed_batch_k(
         ctypes.c_uint64(n),
         ctypes.cast(r_c.ctypes.data, ctypes.c_char_p),
         ctypes.cast(pub_c.ctypes.data, ctypes.c_char_p),
@@ -84,6 +99,9 @@ def batch_k_native(r_rows: np.ndarray, pub_rows: np.ndarray,
         out.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
         ctypes.c_int(n_threads),
     )
+    if sink is not None:
+        sink.append(("verify.host_prep.k", t1, time.perf_counter() - t1,
+                     {"native": 1, "threads": threads}))
     return out
 
 

@@ -23,8 +23,10 @@ Env knobs:
                       set_enabled(), long-lived CLIs re-read with
                       reload_env().
   TM_TPU_TRACE_RING   ring-buffer capacity in spans (default 4096).
-                      Oldest spans are dropped first.  Applied when the
-                      enable flag first resolves true, or explicitly via
+                      Oldest spans are dropped first, and counted:
+                      dropped() is the number pushed out since the last
+                      clear().  Applied when the enable flag first
+                      resolves true, or explicitly via
                       set_ring_size()/reload_env().
 
 All timestamps come from time.perf_counter_ns() — perf_counter() floats
@@ -72,6 +74,7 @@ def _env_ring_size() -> int:
 _enabled: bool | None = None
 _lock = threading.Lock()
 _ring: deque = deque(maxlen=DEFAULT_RING_SIZE)
+_dropped = 0   # spans pushed out of the ring since the last clear()
 _ids = itertools.count(1)
 _tls = threading.local()
 
@@ -110,19 +113,29 @@ reload_env = refresh_from_env
 
 
 def set_ring_size(n: int) -> None:
-    """Resize the ring, keeping the most recent spans that still fit."""
-    global _ring
+    """Resize the ring, keeping the most recent spans that still fit;
+    those that do not are counted as dropped."""
+    global _ring, _dropped
+    n = max(1, int(n))
     with _lock:
-        _ring = deque(_ring, maxlen=max(1, int(n)))
+        _dropped += max(0, len(_ring) - n)
+        _ring = deque(_ring, maxlen=n)
 
 
 def ring_size() -> int:
     return _ring.maxlen or DEFAULT_RING_SIZE
 
 
+def dropped() -> int:
+    """Spans a full ring pushed out since the last clear()."""
+    return _dropped
+
+
 def clear() -> None:
+    global _dropped
     with _lock:
         _ring.clear()
+        _dropped = 0
 
 
 def _stack() -> list:
@@ -134,7 +147,8 @@ def _stack() -> list:
 
 def _append(name: str, span_id: int, parent_id, t0_ns: int, dur_ns: int,
             attrs: dict) -> None:
-    _ring.append({
+    global _dropped
+    span = {
         "name": name,
         "id": span_id,
         "parent": parent_id,
@@ -142,7 +156,11 @@ def _append(name: str, span_id: int, parent_id, t0_ns: int, dur_ns: int,
         "dur_ns": dur_ns,
         "tid": threading.get_ident(),
         "attrs": attrs,
-    })
+    }
+    with _lock:
+        if len(_ring) == _ring.maxlen:
+            _dropped += 1
+        _ring.append(span)
 
 
 class _SpanCtx:
@@ -217,9 +235,12 @@ def record(name: str, t0: float, dur: float, parent: int | None = None,
     if not (en if en is not None else _resolve_enabled()):
         return None
     span_id = next(_ids)
+    # both ends truncated to the ns: a part that ends where its parent
+    # ends (the same float) ends on the same ns, never one past it
+    t0_ns = int(t0 * 1e9)
     _append(name, span_id,
             parent if parent is not None else current_span_id(),
-            int(t0 * 1e9), max(0, int(dur * 1e9)), attrs)
+            t0_ns, max(0, int((t0 + dur) * 1e9) - t0_ns), attrs)
     return span_id
 
 

@@ -53,9 +53,19 @@ def race_sanitized():
     """And under the lockset race sanitizer (utils/racecheck): the
     service's worker-thread/caller handoffs are exactly where an
     unguarded shared field would hide (last_route was the live
-    example — now allowlisted as a deliberate last-write-wins)."""
+    example — now allowlisted as a deliberate last-write-wins).
+
+    A singleton that an earlier test file left running is closed and its
+    worker joined first, and held until the test ends: the sanitizer
+    keys a field by `id()`, so that service's last reads, freed during
+    the test, would pass to whatever object took its address (the next
+    service, whose constructor then "races" them)."""
     from tendermint_tpu.utils import racecheck
 
+    left = av._SERVICE
+    av.clear_service()
+    if left is not None and left._worker is not None:
+        left._worker.join(timeout=10.0)
     racecheck.install()
     racecheck.reset()
     racecheck.instrument_defaults()

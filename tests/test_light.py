@@ -297,6 +297,32 @@ def test_client_skipping_bisects_through_rotation():
     assert lb.hash() == c.blocks[12].hash()
 
 
+def test_client_start_is_one_span_a_client(chain):
+    """`light.client_start`: once a client, the root of trust fetched
+    (no trusted block decoded), or read back from a store that holds it
+    (decoded twice: the latest block, then the one at the option's
+    height)."""
+    from tendermint_tpu.utils import trace
+
+    was = trace.enabled()
+    trace.clear()
+    trace.set_enabled(True)
+    try:
+        store = LightBlockStore()
+        _client(chain, height=2, store=store)
+        _client(chain, height=2, store=store)
+    finally:
+        trace.set_enabled(was)
+    spans = trace.spans()
+    trace.clear()
+    starts = [s for s in spans if s["name"] == "light.client_start"]
+    assert [s["attrs"] for s in starts] == [{"height": 2, "decodes": 0},
+                                           {"height": 2, "decodes": 2}]
+    fetch = [s for s in spans if s["name"] == "light.fetch"]
+    assert len(fetch) == 1 and fetch[0]["parent"] == starts[0]["id"]
+    assert all(s["parent"] is None for s in starts)
+
+
 def test_client_init_bad_hash(chain):
     with pytest.raises(LightClientError, match="hash"):
         Client(

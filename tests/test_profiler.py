@@ -182,14 +182,24 @@ def test_capture_returns_delta_and_feeds_cumulative():
     assert ev["ph"] == "X" and ev["cat"] in cap["by_subsystem"]
 
 
-def test_report_names_top_subsystem_and_function():
+def test_report_names_top_subsystem_and_function(monkeypatch):
+    """One sweep that sees only the thread the test started: threads
+    that earlier tests of the same worker left behind cannot outvote it."""
+    import sys
+    import types
+
     p = pf.Profiler(node="n0")
-    evt, _ = _busy_thread("tm-verify-service-0")
+    evt, t = _busy_thread("tm-verify-service-0")
+    frames = sys._current_frames
+
+    monkeypatch.setattr(pf, "sys", types.SimpleNamespace(
+        _current_frames=lambda: {t.ident: frames()[t.ident]}))
     try:
         p.sample()
     finally:
         evt.set()
     rep = p.report()
+    assert rep["by_subsystem"] == {"verify-service": 1}
     assert rep["top_subsystem"] == "verify-service"
     assert rep["top"] and rep["top"][0]["self"] >= 1
 

@@ -124,6 +124,38 @@ def test_ring_buffer_is_bounded_dropping_oldest():
     assert [s["attrs"]["i"] for s in trace.spans()] == list(range(28, 32))
 
 
+def test_ring_counts_the_spans_it_drops():
+    """A full ring's pushes are counted; a shrink counts what no longer
+    fits; clear() starts the count again; the pprof dump prints it."""
+    from tendermint_tpu.node import pprof
+
+    trace.set_enabled(True)
+    trace.set_ring_size(4)
+    for i in range(4):
+        trace.instant("tick", i=i)
+    assert trace.dropped() == 0
+    for i in range(4, 10):
+        trace.record("tock", float(i), 0.5, i=i)
+    assert trace.dropped() == 6 and len(trace.spans()) == 4
+    trace.set_ring_size(3)
+    assert trace.dropped() == 7
+    trace.set_ring_size(8)
+    assert trace.dropped() == 7 and len(trace.spans()) == 3
+    assert "dropped=7 " in pprof._trace_dump("")[1].splitlines()[0]
+    trace.clear()
+    assert trace.dropped() == 0 and trace.spans() == []
+
+
+def test_a_part_that_ends_with_its_parent_ends_on_the_same_ns():
+    trace.set_enabled(True)
+    t0, t_part, t_end = 12345.000000123, 12345.000400987, 12345.001234567
+    whole = trace.record("whole", t0, t_end - t0)
+    trace.record("part", t_part, t_end - t_part, parent=whole)
+    by = {s["name"]: s for s in trace.spans()}
+    assert (by["part"]["t0_ns"] + by["part"]["dur_ns"]
+            == by["whole"]["t0_ns"] + by["whole"]["dur_ns"])
+
+
 def test_exports_round_trip_and_summary():
     trace.set_enabled(True)
     with trace.span("verify.flush", path="host", n=64):
