@@ -1,12 +1,12 @@
 """Native host-side batch preprocessing for the Ed25519 verifier.
 
 ctypes binding for src/native/edhost.cpp: one C call computes
-k = SHA-512(R || A || M) mod L for the whole batch, threaded across
-cores.  The Python fallback (hashlib + bigint per row) costs ~4.7us/row
-— ~50ms for a 10k-validator commit, 25x the BASELINE.md 2ms end-to-end
-target — so the native path is what keeps host prep out of the latency
-budget.  Built by `make -C src/native` (attempted automatically, same
-pattern as store/native_db.py).
+k = SHA-512(R || A || M) mod L for the whole batch, on as many threads
+as its rows pay for.  The Python fallback (hashlib + bigint per row)
+costs ~4.7us/row — ~50ms for a 10k-validator commit, 25x the BASELINE.md
+2ms end-to-end target — so the native path is what keeps host prep out
+of the latency budget.  Built by `make -C src/native` (attempted
+automatically, same pattern as store/native_db.py).
 """
 
 from __future__ import annotations
@@ -66,12 +66,18 @@ def batch_k_native(r_rows: np.ndarray, pub_rows: np.ndarray,
     """k rows [N,32] (little-endian scalars mod L), or None when the
     native kernel is unavailable.  r_rows/pub_rows: [N,32] uint8.
 
+    `n_threads` <= 0 lets the rows decide (`tmed_batch_k`): one thread
+    for each 2,000 rows begun, at most 8 and at most the CPUs this
+    process may run on, so a batch of up to 2,000 rows is hashed on the
+    calling thread alone; a positive count is used as given, at most one
+    thread a row.  The rows come out the same at every count.
+
     `sink` (a list, or None): where the two steps are handed back as
     `(name, t0, dur, attrs)` on the perf_counter clock —
     `verify.host_prep.msgs` (the lengths, offsets and join of the
     messages; `bytes`) and `verify.host_prep.k` (the native call alone;
-    `native` 1, `threads` = what the call returns: 1 when it hashed on
-    the calling thread, else the threads it started).  Without one no
+    `native` 1, `threads` = the threads that hashed, the calling thread
+    counted: 1 when it hashed alone and started none).  Without one no
     time is taken."""
     lib = load_lib()
     if lib is None:

@@ -4,6 +4,7 @@ Barrett reduction mod the Ed25519 group order.
 """
 
 import hashlib
+import os
 import secrets
 
 import hypothesis.strategies as st
@@ -39,13 +40,53 @@ def test_batch_k_matches_python_reference(msgs):
 
 
 def test_batch_k_large_batch_multithreaded():
-    n = 3000  # crosses the single-thread cutoff in tmed_batch_k
+    n = 3000  # four threads given: the caller and three it starts
     r_rows = np.frombuffer(secrets.token_bytes(32 * n), dtype=np.uint8).reshape(n, 32)
     pub_rows = np.frombuffer(secrets.token_bytes(32 * n), dtype=np.uint8).reshape(n, 32)
     msgs = [b"m%d" % i * (i % 9 + 1) for i in range(n)]
     out = host_prep.batch_k_native(r_rows, pub_rows, msgs, n_threads=4)
     spot = [0, 1, n // 2, n - 2, n - 1, 701, 1499, 2250]
     for i in spot:
+        want = _ref_k(r_rows[i].tobytes(), pub_rows[i].tobytes(), msgs[i])
+        assert out[i].tobytes() == want, i
+
+
+ROWS_PER_THREAD = 2000  # edhost.cpp kRowsPerThread
+MAX_THREADS = 8  # edhost.cpp kMaxThreads
+
+
+def _k_and_threads(r_rows, pub_rows, msgs, n_threads):
+    sink = []
+    out = host_prep.batch_k_native(r_rows, pub_rows, msgs, n_threads=n_threads,
+                                   sink=sink)
+    (k,) = [attrs for name, _, _, attrs in sink if name == "verify.host_prep.k"]
+    return out, k["threads"]
+
+
+@pytest.mark.parametrize("n_threads", [0, 1, 3, 8])
+@pytest.mark.parametrize("n", [1, 255, 256, 334, 667, 2049, 4002, 10000])
+def test_batch_k_threads_follow_rows(n, n_threads):
+    """Every thread count hashes the same rows; with none given the rows
+    decide: one thread for each 2,000 rows begun, at most 8 and at most
+    the CPUs this process may run on — so a batch of up to 2,000 rows
+    starts no thread.  The count returned includes the calling thread."""
+    rng = np.random.default_rng(n)
+    r_rows = rng.integers(0, 256, (n, 32), dtype=np.uint8)
+    pub_rows = rng.integers(0, 256, (n, 32), dtype=np.uint8)
+    msgs = [rng.bytes(110 + i % 7) for i in range(n)]
+    one, one_threads = _k_and_threads(r_rows, pub_rows, msgs, 1)
+    out, threads = _k_and_threads(r_rows, pub_rows, msgs, n_threads)
+    assert one_threads == 1
+    assert out.tobytes() == one.tobytes()
+    if n_threads:
+        assert threads == min(n_threads, n)
+        return
+    cpus = len(os.sched_getaffinity(0))
+    assert threads == min(-(-n // ROWS_PER_THREAD), MAX_THREADS, cpus)
+    assert 1 <= threads <= min(MAX_THREADS, cpus)
+    if n <= ROWS_PER_THREAD:
+        assert threads == 1
+    for i in (0, n // 2, n - 1):
         want = _ref_k(r_rows[i].tobytes(), pub_rows[i].tobytes(), msgs[i])
         assert out[i].tobytes() == want, i
 
