@@ -12,10 +12,12 @@ through receiveRoutine's channel select, this class is a single-task
 async actor — `receive_loop` selects over (peer queue, internal queue,
 timeout tock) and dispatches into the same synchronous transition
 functions the reference has, so the FSM itself is deterministic and
-directly unit-testable without a running loop.  Vote verification runs
-through VoteSet.add_votes → BatchVerifier, so every vote slice a
-scheduler tick delivers becomes ONE device call (reference verifies one
-signature inline per addVote, types/vote_set.go:203).
+directly unit-testable without a running loop.  The votes a scheduler
+tick delivers are signature-verified as ONE batched call to the verify
+service (`_precheck_vote_sigs`): one device flush where the tick holds
+at least the measured host/device threshold, the host below it; each
+vote then carries its verdict into VoteSet.add_vote (reference verifies
+one signature inline per addVote, types/vote_set.go:203).
 """
 
 from __future__ import annotations
@@ -27,7 +29,7 @@ from tendermint_tpu.state.state import State
 from tendermint_tpu.utils import clock as _clock
 from tendermint_tpu.utils import trace as _trace
 from tendermint_tpu.utils import txlife as _txlife
-from tendermint_tpu.utils.metrics import Histogram
+from tendermint_tpu.utils.metrics import Counter, Histogram
 from tendermint_tpu.types import (
     Block,
     BlockID,
@@ -37,6 +39,7 @@ from tendermint_tpu.types import (
 )
 from tendermint_tpu.types.basic import PartSetHeader, SignedMsgType, now_ns
 from tendermint_tpu.types.part_set import Part, PartSet
+from tendermint_tpu.types.vote import SINGLE_VERIFIES_TOTAL
 from tendermint_tpu.types.vote_set import ConflictingVoteError, VoteSet
 from tendermint_tpu.utils.fail import fail_point
 from tendermint_tpu.utils.log import Logger, nop_logger
@@ -71,6 +74,23 @@ STEP_DURATION_SECONDS = Histogram(
     buckets=(0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,
              1.0, 2.5, 5.0, 10.0, 30.0),
 )
+
+
+# The receive loop's tick precheck, beside the one-at-a-time vote
+# verifications it leaves (types/vote.py): rows = PRECHECK_ROWS_TOTAL,
+# and every row prechecked is verified once — SINGLE_VERIFIES_TOTAL
+# counts only votes no precheck gave a verdict.  Bumped once a tick;
+# node/metrics.py registers them.
+PRECHECK_ROWS_TOTAL = Counter(
+    "precheck_rows_total",
+    "Vote signatures the receive loop's tick precheck verified as one batch",
+    namespace="tendermint", subsystem="consensus")
+PRECHECK_REFUSED_TOTAL = Counter(
+    "precheck_refused_total",
+    "Vote signatures the tick precheck's batch refused",
+    namespace="tendermint", subsystem="consensus")
+VOTE_VERIFY_COUNTERS = (PRECHECK_ROWS_TOTAL, PRECHECK_REFUSED_TOTAL,
+                        SINGLE_VERIFIES_TOTAL)
 
 
 class ConsensusFailureError(Exception):
@@ -221,30 +241,7 @@ class ConsensusState:
                         # from addProposalBlockPart)
                         self.handle_msg(item)
                     else:
-                        # drain everything else that arrived this tick and
-                        # batch-verify all vote signatures in it as one
-                        # device call (SURVEY §7 stage 6); each message is
-                        # then processed in arrival order exactly as the
-                        # sequential path would
-                        batch = [item]
-                        while len(batch) < 256:
-                            try:
-                                batch.append(self.peer_msg_queue.get_nowait())
-                            except asyncio.QueueEmpty:
-                                break
-                        if len(batch) > 1:
-                            self._precheck_vote_sigs(batch)
-                        for mi in batch:
-                            try:
-                                self.wal.write(mi)
-                                self.handle_msg(mi)
-                            except (ConsensusFailureError, OSError):
-                                raise
-                            except Exception as e:
-                                # one bad peer message must not drop the
-                                # rest of the tick's batch
-                                self.logger.error("consensus msg error",
-                                                  err=repr(e))
+                        self._handle_tick(item)
                 except (ConsensusFailureError, OSError):
                     # safety failures (broken commit path, WAL/disk errors)
                     # halt the node — continuing could double-sign or fork
@@ -256,24 +253,57 @@ class ConsensusState:
                     # bad peer input must not kill consensus: log and go on
                     self.logger.error("consensus msg error", err=repr(e))
 
+    def _handle_tick(self, first: MsgInfo) -> None:
+        """Drain everything else that arrived this tick, batch-verify all
+        vote signatures in it (SURVEY §7 stage 6), then process each
+        message in arrival order exactly as the sequential path would,
+        its WAL record first."""
+        with _trace.span("consensus.tick") as tick:
+            batch = [first]
+            while len(batch) < 256:
+                try:
+                    batch.append(self.peer_msg_queue.get_nowait())
+                except asyncio.QueueEmpty:
+                    break
+            if _trace.enabled():
+                tick.set(msgs=len(batch), votes=sum(
+                    isinstance(mi.msg, VoteMessage) for mi in batch))
+            if len(batch) > 1:
+                self._precheck_vote_sigs(batch)
+            with _trace.span("consensus.handle", msgs=len(batch)):
+                for mi in batch:
+                    try:
+                        self.wal.write(mi)
+                        self.handle_msg(mi)
+                    except (ConsensusFailureError, OSError):
+                        raise
+                    except Exception as e:
+                        # one bad peer message must not drop the rest of
+                        # the tick's batch
+                        self.logger.error("consensus msg error", err=repr(e))
+
     def _precheck_vote_sigs(self, batch: list[MsgInfo]) -> None:
         """Verify the signatures of every vote in this tick's peer
-        messages as ONE batched call (SURVEY §7 stage 6: amortize device
-        dispatch across the scheduler tick).  Valid signatures are marked
-        on the vote so the per-vote verify in VoteSet.add_vote
-        short-circuits; invalid ones are NOT marked and fail identically
-        in the sequential path.  Pure crypto — no consensus state is
-        touched, so WAL-before-act ordering is unaffected.  Never raises:
-        any backend failure just means no markers, and every message
-        still flows through the per-vote path."""
+        messages as ONE batched call to the verify service (SURVEY §7
+        stage 6: amortize dispatch across the scheduler tick): a tick at
+        or above the measured host/device threshold is one device flush,
+        a smaller one is verified on the host.  The verdict is recorded
+        on every vote submitted, valid or refused, so the per-vote verify
+        in VoteSet.add_vote returns or raises without verifying again.
+        Pure crypto — no consensus state is touched, so WAL-before-act
+        ordering is unaffected.  Never raises: a backend failure records
+        no verdict, and every message still flows through the per-vote
+        path."""
         from tendermint_tpu.types.vote import batch_verify_votes
 
         rs = self.rs
         jobs = []
+        votes = 0
         for mi in batch:
             m = mi.msg
             if not isinstance(m, VoteMessage):
                 continue
+            votes += 1
             v = m.vote
             if v.height == rs.height:
                 vals = rs.validators
@@ -300,16 +330,24 @@ class ConsensusState:
         if len(jobs) < 2:
             return  # nothing to amortize
         chain_id = self.state.chain_id
-        try:
-            oks = batch_verify_votes(chain_id, jobs)
+        with _trace.span("consensus.precheck", rows=len(jobs),
+                         skipped=votes - len(jobs)) as sp:
+            try:
+                oks = batch_verify_votes(chain_id, jobs)
+            except Exception as e:
+                # a transient backend failure (device OOM, a failed
+                # compile) must not drop the drained tick: with no verdict
+                # recorded every vote simply re-verifies individually
+                self.logger.error("vote precheck batch failed", err=repr(e))
+                return
+            refused = 0
             for (v, pk), ok in zip(jobs, oks):
-                if ok:
-                    v.mark_sig_verified(chain_id, pk)
-        except Exception as e:
-            # a transient backend failure (device OOM, a failed compile)
-            # must not drop the drained tick: without markers every vote simply
-            # re-verifies individually
-            self.logger.error("vote precheck batch failed", err=repr(e))
+                v.mark_sig_verdict(chain_id, pk, ok)
+                refused += not ok
+            sp.set(valid=len(jobs) - refused, refused=refused)
+        PRECHECK_ROWS_TOTAL.inc(len(jobs))
+        if refused:
+            PRECHECK_REFUSED_TOTAL.inc(refused)
 
     def handle_msg(self, mi: MsgInfo) -> None:
         msg, peer_id = mi.msg, mi.peer_id
@@ -873,60 +911,63 @@ class ConsensusState:
             return
         block_id = rs.votes.precommits(rs.commit_round).two_thirds_majority()
         block, block_parts = rs.proposal_block, rs.proposal_block_parts
-        block.validate_basic()
-        self.block_exec.validate_block(self.state, block)
+        # the apply, the saves and the advance to the next height
+        with _trace.span("consensus.finalize", height=height,
+                         txs=len(block.data.txs)):
+            block.validate_basic()
+            self.block_exec.validate_block(self.state, block)
 
-        # from here on, failure is a safety violation: +2/3 precommitted
-        # this block, so an error storing/applying it must halt the node
-        try:
-            fail_point("commit-before-save")  # reference state.go:1524 (before save)
-            if self.block_store.height() < block.header.height:
-                seen_commit = rs.votes.precommits(rs.commit_round).make_commit()
-                self.block_store.save_block(block, block_parts, seen_commit)
-            fail_point("commit-after-save")  # reference state.go:1538 (saved, before WAL barrier)
-
-            # crash barrier: replay resumes AFTER this record (reference
-            # state.go:1540-1557)
-            self.wal.write_sync(EndHeightMessage(height))
-            fail_point("commit-after-barrier")  # reference state.go:1559 (barrier written, before apply)
-
-            state_copy, retain_height = self.block_exec.apply_block(
-                self.state.copy(), block_id, block
-            )
-            fail_point("commit-after-apply")  # reference state.go:1577 (applied, before state save/advance)
-        except ConsensusFailureError:
-            raise
-        except Exception as e:
-            raise ConsensusFailureError(
-                f"failed to commit block {height}: {e}"
-            ) from e
-        if self.journal.enabled and not self.replay_mode:
-            self.journal.log("commit", h=height, r=rs.commit_round,
-                             block=block_id.hash[:8].hex(),
-                             txs=len(block.data.txs))
-        if self.lifecycle.enabled and not self.replay_mode:
-            # committed-and-applied: both milestones stamp here, after
-            # the critical section (a lifecycle/journal I/O error must
-            # never read as a consensus-safety failure).  `commit` closes
-            # the mempool-residency window, `apply` the time-to-finality
-            # one and retires the tx from the live store.
-            self._stamp_block_txs(block, "commit")
-            self._stamp_block_txs(block, "apply")
-        if retain_height > 0:
+            # from here on, failure is a safety violation: +2/3 precommitted
+            # this block, so an error storing/applying it must halt the node
             try:
-                pruned = self.block_store.prune_blocks(retain_height)
-                self.block_exec.store.prune_states(
-                    self.block_store.base(), retain_height
-                )
-                self.logger.info("pruned blocks", count=pruned)
-            except Exception as e:
-                self.logger.error("prune failed", err=str(e))
+                fail_point("commit-before-save")  # reference state.go:1524 (before save)
+                if self.block_store.height() < block.header.height:
+                    seen_commit = rs.votes.precommits(rs.commit_round).make_commit()
+                    self.block_store.save_block(block, block_parts, seen_commit)
+                fail_point("commit-after-save")  # reference state.go:1538 (saved, before WAL barrier)
 
-        self.update_to_state(state_copy)
-        ev = self.done_height
-        self.done_height = asyncio.Event()
-        ev.set()
-        self.schedule_round_0()
+                # crash barrier: replay resumes AFTER this record (reference
+                # state.go:1540-1557)
+                self.wal.write_sync(EndHeightMessage(height))
+                fail_point("commit-after-barrier")  # reference state.go:1559 (barrier written, before apply)
+
+                state_copy, retain_height = self.block_exec.apply_block(
+                    self.state.copy(), block_id, block
+                )
+                fail_point("commit-after-apply")  # reference state.go:1577 (applied, before state save/advance)
+            except ConsensusFailureError:
+                raise
+            except Exception as e:
+                raise ConsensusFailureError(
+                    f"failed to commit block {height}: {e}"
+                ) from e
+            if self.journal.enabled and not self.replay_mode:
+                self.journal.log("commit", h=height, r=rs.commit_round,
+                                 block=block_id.hash[:8].hex(),
+                                 txs=len(block.data.txs))
+            if self.lifecycle.enabled and not self.replay_mode:
+                # committed-and-applied: both milestones stamp here, after
+                # the critical section (a lifecycle/journal I/O error must
+                # never read as a consensus-safety failure).  `commit` closes
+                # the mempool-residency window, `apply` the time-to-finality
+                # one and retires the tx from the live store.
+                self._stamp_block_txs(block, "commit")
+                self._stamp_block_txs(block, "apply")
+            if retain_height > 0:
+                try:
+                    pruned = self.block_store.prune_blocks(retain_height)
+                    self.block_exec.store.prune_states(
+                        self.block_store.base(), retain_height
+                    )
+                    self.logger.info("pruned blocks", count=pruned)
+                except Exception as e:
+                    self.logger.error("prune failed", err=str(e))
+
+            self.update_to_state(state_copy)
+            ev = self.done_height
+            self.done_height = asyncio.Event()
+            ev.set()
+            self.schedule_round_0()
 
     # ------------------------------------------------------------------
     # message ingestion

@@ -508,7 +508,10 @@ class NodeMetrics:
         from tendermint_tpu.blocksync.reactor import (
             WINDOW_COUNTERS as _bsync_window_counters,
         )
-        from tendermint_tpu.consensus.state import STEP_DURATION_SECONDS
+        from tendermint_tpu.consensus.state import (
+            STEP_DURATION_SECONDS,
+            VOTE_VERIFY_COUNTERS,
+        )
         from tendermint_tpu.crypto.batch import ROWS_ADDED_TOTAL as _rows_added
         from tendermint_tpu.gateway.coalescer import GATEWAY_SERIES as _gw_series
         from tendermint_tpu.light.client import LIGHT_COUNTERS as _light_counters
@@ -517,6 +520,8 @@ class NodeMetrics:
         )
 
         self.step_duration = reg.register(STEP_DURATION_SECONDS)
+        for series in VOTE_VERIFY_COUNTERS:
+            reg.register(series)
         self.blocksync_request_duration = reg.register(_bsync_hist)
         for series in _bsync_window_counters + _light_counters + _gw_series:
             reg.register(series)

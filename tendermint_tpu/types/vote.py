@@ -9,6 +9,7 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 
 from tendermint_tpu.crypto.keys import PubKey
+from tendermint_tpu.utils.metrics import Counter
 from tendermint_tpu.wire.proto import ProtoWriter, fields_to_dict
 
 from .basic import (
@@ -22,6 +23,15 @@ from .basic import (
 from .canonical import vote_sign_bytes_raw
 
 MAX_VOTE_BYTES = 223  # reference types/vote.go MaxVoteBytes
+
+# Vote signatures verified one at a time (`Vote.verify` reaching
+# `verify_one`): a vote the consensus tick precheck gave a verdict is
+# never among them.  node/metrics.py registers it beside the precheck's
+# own counters (consensus/state.py VOTE_VERIFY_COUNTERS).
+SINGLE_VERIFIES_TOTAL = Counter(
+    "vote_single_verifies_total",
+    "Vote signatures verified one at a time, outside the tick precheck",
+    namespace="tendermint", subsystem="consensus")
 
 
 @dataclass
@@ -70,25 +80,31 @@ class Vote:
         if pub_key.address() != self.validator_address:
             raise ValueError("invalid validator address")
         marker = getattr(self, "_sig_prechecked", None)
-        if marker is not None and marker == self._precheck_digest(chain_id, pub_key):
-            return  # this exact content+signature was batch-verified
+        if marker is not None and marker[1] == self._precheck_digest(chain_id, pub_key):
+            # this exact content+signature was batch-verified: its
+            # verdict stands, valid or refused, and is not computed again
+            if marker[0]:
+                return
+            raise ValueError("invalid signature")
         # probe + fill the shared verified-sig cache around the
         # scalar-mult: N callers re-checking one wire vote (every node
         # of an in-process net) become lookups (crypto/async_verify)
         from tendermint_tpu.crypto.async_verify import verify_one
 
+        SINGLE_VERIFIES_TOTAL.inc()
         if not verify_one(pub_key, self.sign_bytes(chain_id),
                           self.signature):
             raise ValueError("invalid signature")
 
-    def mark_sig_verified(self, chain_id: str, pub_key: PubKey) -> None:
-        """Record that a batched precheck verified the signature
+    def mark_sig_verdict(self, chain_id: str, pub_key: PubKey, ok: bool) -> None:
+        """Record a batched precheck's verdict on the signature
         (consensus tick batching, SURVEY §7 stage 6) — verify() then
-        skips the redundant per-vote device/CPU call.  The marker binds
-        the FULL verified content (chain, key, sign-bytes, signature), so
-        mutating the vote after marking can never validate unchecked
-        bytes — it just falls back to a real verification."""
-        self._sig_prechecked = self._precheck_digest(chain_id, pub_key)
+        returns (valid) or raises (refused) without verifying again.  The
+        marker binds the FULL verified content (chain, key, sign-bytes,
+        signature), so mutating the vote after marking can never carry a
+        verdict over to unchecked bytes — it just falls back to a real
+        verification."""
+        self._sig_prechecked = (bool(ok), self._precheck_digest(chain_id, pub_key))
 
     def is_nil(self) -> bool:
         return self.block_id.is_zero()

@@ -258,10 +258,10 @@ def test_tick_batched_vote_precheck():
             assert pv.get_by_index(h.val_index(2)) is not None
             assert pv.get_by_index(h.val_index(3)) is None  # forged sig refused
             # prove the batched precheck actually ran (not the fallback):
-            # the good votes carry the marker, the forged one must not
-            assert getattr(good1, "_sig_prechecked", None) is not None
-            assert getattr(good2, "_sig_prechecked", None) is not None
-            assert getattr(forged, "_sig_prechecked", None) is None
+            # every vote carries the batch's verdict, the forged one refused
+            assert getattr(good1, "_sig_prechecked", None)[0] is True
+            assert getattr(good2, "_sig_prechecked", None)[0] is True
+            assert getattr(forged, "_sig_prechecked", None)[0] is False
         finally:
             await cs.stop()
 
